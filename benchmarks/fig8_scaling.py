@@ -8,8 +8,8 @@ Two parts:
    points from the dmp swap declarations — the quantities that drive the
    paper's strong-scaling curves.
 
-2. **Modeled TPU step time** from roofline constants (197 TFLOP/s bf16,
-   819 GB/s HBM, 50 GB/s ICI link): compute term (memory-bound stencils:
+2. **Modeled TPU v5e step time** from the v5e entry of
+   ``launch.roofline.PEAKS``: compute term (memory-bound stencils:
    bytes-limited) vs collective term (halo bytes / link bw), reported
    with and without comm/compute overlap — the paper's Devito-vs-xDSL
    gap is exactly the no-overlap penalty, and our beyond-paper overlap
@@ -24,12 +24,10 @@ from repro.core.dialects import dmp, stencil
 from repro.core.passes import decompose_stencil, eliminate_redundant_swaps
 from repro.core.passes.decompose import make_strategy_3d
 from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+from repro.launch.roofline import LINK_LATENCY, V5E, device_peaks
 
-# TPU v5e constants
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
-LINK_LATENCY = 2e-6  # per-message launch latency (matches launch/roofline)
+# this script models a v5e chip wherever it runs
+PEAKS = device_peaks(V5E)
 
 GLOBAL = (512, 512, 512)
 RANK_GRIDS = {
@@ -86,7 +84,7 @@ def _tiling_sweep(record: dict, ranks: list, exchange_every: tuple) -> list:
             local = tuple(G // r for G, r in zip(GLOBAL, RANK_GRIDS[R]))
             w = 2  # so4 taps reach ±2
             t_comp = st["t_comp"]
-            t_bytes = st["halo_bytes"] / LINK_BW
+            t_bytes = st["halo_bytes"] / PEAKS.link_bytes_s
             n_msgs = 2 * len(local)  # one send/recv pair per face
             row = [kind, R]
             for k in exchange_every:
@@ -130,6 +128,7 @@ def _tune_rows(record: dict, ranks: list) -> list:
                 messages_per_epoch=2 * len(local),
                 step_halo=(2,) * len(local),  # so4 taps reach ±2
                 local_shape=local,
+                device_kind=V5E,
             )
             ranked = terms.ranked_exchange_every(max_k=8)
             best_k, best_t = ranked[0]
@@ -161,10 +160,10 @@ def run(fast: bool = False, overlap: str = "both",
             # (1 read + 1 write + reuse-miss) × 4B; use 3 streams as the
             # classic Jacobi estimate
             t_comp = max(
-                st["local_points"] * st["flops_per_point"] / PEAK_FLOPS,
-                st["local_points"] * 12 / HBM_BW,
+                st["local_points"] * st["flops_per_point"] / PEAKS.flops,
+                st["local_points"] * 12 / PEAKS.hbm_bytes_s,
             )
-            t_comm = st["halo_bytes"] / LINK_BW
+            t_comm = st["halo_bytes"] / PEAKS.link_bytes_s
             t_nooverlap = t_comp + t_comm
             t_overlap = max(t_comp, t_comm)
             gpts_no = st["local_points"] * R / t_nooverlap / 1e9

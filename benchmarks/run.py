@@ -31,6 +31,9 @@ def main() -> int:
                          "in benches that support them")
     args = ap.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         backend_compare,
         fig7_heat,

@@ -9,6 +9,8 @@ the memory/cost/collective analysis — the stencil-side §Dry-run.
 """
 import os
 
+# a CPU-only tool: virtual CPU devices, never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
@@ -23,9 +25,11 @@ def main() -> None:
     import jax
     from jax.sharding import Mesh
 
-    from repro import api
+    from repro import api, compile_cache
     from repro.core.passes.decompose import SlicingStrategy
     from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+
+    compile_cache.enable()
 
     assert len(jax.devices()) == 512, len(jax.devices())
     mesh = Mesh(

@@ -30,11 +30,12 @@ def pw_advection(u, v, w, su, sv, sw):
 def main() -> None:
     import jax.numpy as jnp
 
-    from repro import api
+    from repro import api, compile_cache
     from repro.core.dialects import stencil
     from repro.core.passes import cse_apply_bodies, dce, fuse_applies
     from repro.frontends.psyclone_like import build_stencil_func
 
+    compile_cache.enable()
     shape = (64, 64, 32)
     func = build_stencil_func(pw_advection, shape)
     n_raw = sum(1 for op in func.body.ops if isinstance(op, stencil.ApplyOp))

@@ -28,8 +28,11 @@ def main() -> None:
     args = ap.parse_args()
 
     import repro
+    from repro import compile_cache
     from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
     from repro.resilience import FaultPlan, ResilientLoop, SimulatedFault, resume
+
+    compile_cache.enable()
 
     # -- the simulation: 2-D heat, depth-4 epochs --------------------------
     grid = Grid(shape=(args.size, args.size), extent=(1.0, 1.0))

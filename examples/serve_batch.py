@@ -22,10 +22,13 @@ def main() -> None:
     import jax
     import numpy as np
 
+    from repro import compile_cache
     from repro.configs import get_config
     from repro.configs.base import reduced_config
     from repro.models import lm
     from repro.serve import Engine, EngineConfig
+
+    compile_cache.enable()
 
     cfg = dataclasses.replace(
         reduced_config(get_config(args.arch)), dtype="float32"

@@ -43,6 +43,7 @@ def main() -> None:
 
     import jax
 
+    from repro import compile_cache
     from repro.data.pipeline import DataConfig
     from repro.train import optimizer as opt_mod
     from repro.train.train_step import (
@@ -51,6 +52,8 @@ def main() -> None:
         make_train_step,
     )
     from repro.train.trainer import Trainer, TrainerConfig
+
+    compile_cache.enable()
 
     cfg = build_100m(args.arch)
     n_params = cfg.param_count()

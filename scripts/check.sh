@@ -150,13 +150,14 @@ os.environ["REPRO_TUNE_CACHE"] = tempfile.mkdtemp(prefix="repro-tune-smoke-")
 from repro import api
 from repro.tune import cache_stats, tune
 from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+from repro.launch.roofline import V5E
 
 grid = Grid(shape=(64, 64), extent=(1.0, 1.0))
 u = TimeFunction(name="u", grid=grid, space_order=2)
 dt = 0.8 * grid.spacing[0] ** 2 / (4 * 0.5)
 prog = Operator(Eq(u.dt, 0.5 * u.laplace), dt=dt, boundary="zero").program
 
-r1 = tune(prog, measure=False)
+r1 = tune(prog, measure=False, device_kind=V5E)  # the CPU models a v5e
 assert not r1.from_cache, "first search must be a cache miss"
 assert cache_stats().misses == 1 and cache_stats().stores == 1, (
     cache_stats().as_dict()
@@ -167,7 +168,7 @@ assert unpruned and all(
     r1.winner.modeled_s <= c.modeled_s for c in unpruned
 ), "winner must have the minimal modeled step time among unpruned candidates"
 
-r2 = tune(prog, measure=False)
+r2 = tune(prog, measure=False, device_kind=V5E)
 assert r2.from_cache, "second search must hit the persistent cache"
 assert cache_stats().hits == 1, cache_stats().as_dict()
 assert r2.target.fingerprint == r1.target.fingerprint
@@ -412,6 +413,7 @@ import numpy as np
 from repro import api, obs
 
 from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+from repro.launch.roofline import V5E
 
 grid = Grid(shape=(64, 64), extent=(1.0, 1.0))
 u = TimeFunction(name="u", grid=grid, space_order=2)
@@ -428,7 +430,8 @@ obs.enable()
 obs.clear()
 got = step.time_loop((u0,), 8)
 got = np.asarray(got[0] if isinstance(got, tuple) else got)
-rep = obs.drift_report(terms=step.cost(), exchange_every=4)
+# measured on this host against the modelled v5e
+rep = obs.drift_report(terms=step.cost(device_kind=V5E), exchange_every=4)
 obs.disable()
 assert np.allclose(got, want, rtol=1e-6, atol=1e-6), (
     f"traced time_loop diverged: max abs diff {np.abs(got - want).max()}"

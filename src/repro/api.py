@@ -29,8 +29,6 @@ Operator-as-cached-artifact design:
   target they have already compiled.  ``cache_stats()`` reports
   hits/misses; ``clear_cache()`` resets.
 
-``repro.core.program.StencilComputation`` remains as a thin deprecated
-shim over this surface (see DESIGN.md §1 for the migration table).
 """
 from __future__ import annotations
 
@@ -186,9 +184,9 @@ class Target:
     # the serve engine dispatches a whole distributed slot pool as one
     # pooled call (DESIGN.md §9).
     slot_axis: Optional[str] = None
-    # None resolves via kernels.default_interpret(): interpret mode on
-    # CPU-only hosts (the correctness oracle), native Pallas when an
-    # accelerator is present; REPRO_PALLAS_INTERPRET overrides.
+    # None resolves via kernels.default_interpret(): native Pallas on a
+    # TPU, interpret mode (the CPU correctness oracle) elsewhere.  Only an
+    # explicit True runs interpret mode on a TPU.
     pallas_interpret: Optional[bool] = None
     pallas_tile: Optional[tuple] = None
     # Donate every field buffer to jit (classic double-buffer rotation:
@@ -693,22 +691,34 @@ class CompiledStencil:
             )
         return jax.jit(self._raw_fn).lower(*args)
 
-    def cost(self, dtype=jnp.float32):
+    def cost(self, dtype=jnp.float32, device_kind: Optional[str] = None):
         """Roofline terms of the compiled executable (launch/roofline):
         per-device FLOPs / HBM bytes / collective bytes → seconds per
         term, dominant bottleneck, overlapped/serial step time — plus the
         temporal-tiling tradeoff terms (message count per epoch, per-step
         halo widths, shard extents) so ``.cost().recommend_exchange_every()``
         can pick the epoch depth that balances amortized exchange latency
-        against redundant boundary compute."""
-        from repro.core.dialects import comm
-        from repro.launch.roofline import RooflineTerms, collective_bytes_from_hlo
+        against redundant boundary compute.
 
+        ``device_kind`` names the chip whose peaks the terms use (a key of
+        ``launch.roofline.PEAKS``); by default the kind of the target's
+        first device.  A caller on the CPU that models a TPU names it
+        (``launch.roofline.V5E``): the CPU has no peaks entry."""
+        from repro.core.dialects import comm
+        from repro.core.passes.temporal import TemporalTilingError, epoch_halo
+        from repro.launch.roofline import (
+            RooflineTerms,
+            collective_bytes_from_hlo,
+            device_peaks,
+        )
+
+        if device_kind is None:
+            mesh = self.target.mesh
+            device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+            device_kind = device.device_kind
+        device_peaks(device_kind)  # unknown kinds fail before compiling
         compiled = self.lower(dtype).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-            cost = cost[0] if cost else {}
-        from repro.core.passes.temporal import TemporalTilingError, epoch_halo
 
         step_halo: tuple = ()
         try:
@@ -734,6 +744,7 @@ class CompiledStencil:
             messages_per_epoch=messages,
             step_halo=step_halo,
             local_shape=local_shape,
+            device_kind=device_kind,
         )
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
@@ -919,11 +930,13 @@ def _validate_for_program(program: Program, target: Target) -> None:
 
 
 def _validate_pallas_tile(program: Program, target: Target) -> None:
-    """A user tile must divide the *local shard* shape the kernel will
-    see — caught here with a named error, not by the divisibility assert
-    deep inside ``core/lowering``.  Split-overlapped and epoch-tiled
-    applies re-tile automatically (their per-part shapes vary), so only
-    their tile *rank* is checked."""
+    """A user tile must obey the kernels' (8, 128) tile rule on the *local
+    shard* shape the kernel will see — caught here with a named error, not
+    deep inside the kernel.  Split-overlapped and epoch-tiled applies
+    re-tile automatically (their per-part shapes vary), so only their
+    tile *rank* is checked."""
+    from repro.kernels.stencil_apply import is_legal_tile
+
     tile = target.pallas_tile
     if not program.field_args:
         return
@@ -947,21 +960,20 @@ def _validate_pallas_tile(program: Program, target: Target) -> None:
     local = tuple(
         shape[d] // grid_of_dim.get(d, (1, None))[0] for d in range(rank)
     )
-    for d in range(rank):
-        if local[d] % tile[d] != 0:
-            g, ax = grid_of_dim.get(d, (1, None))
-            where = (
-                f"decomposed over mesh axis {ax!r} (grid {g})"
-                if ax is not None and g > 1
-                else "undecomposed"
-            )
-            raise TargetError(
-                f"pallas_tile {tile} does not divide the local shard "
-                f"shape {local} of program {program.name!r}: dim {d} "
-                f"extent {local[d]} is not a multiple of tile {tile[d]} "
-                f"({where}); pick a tile dividing the shard or drop "
-                f"pallas_tile for auto-tiling"
-            )
+    if not is_legal_tile(local, tile):
+        axes = [
+            f"dim {d} decomposed over mesh axis {ax!r} (grid {g})"
+            for d, (g, ax) in sorted(grid_of_dim.items())
+            if ax is not None and g > 1
+        ]
+        where = "; ".join(axes) if axes else "undecomposed"
+        raise TargetError(
+            f"pallas_tile {tile} is illegal for the local shard shape "
+            f"{local} of program {program.name!r} ({where}): each extent "
+            f"must be the shard's extent or, along the last two dims, a "
+            f"multiple of 8 (sublanes) and 128 (lanes) below it; pick such "
+            f"a tile or drop pallas_tile for auto-tiling"
+        )
 
 
 def _validate_exchange_every(program: Program, target: Target) -> None:
@@ -1082,6 +1094,13 @@ def _build_inner(program: Program, target: Target) -> CompiledStencil:
         pallas_interpret=target.pallas_interpret,
         pallas_tile=target.pallas_tile,
     )
+    if target.backend == "pallas":
+        from repro.kernels import KernelPlanError
+
+        try:
+            interp.plan_kernels()
+        except KernelPlanError as e:
+            raise TargetError(f"program {program.name!r}: {e}") from e
     specs = partition_specs(program, strategy)
     # return arity/order comes from the LOCAL IR (first-store order):
     # an epoched carried-state program (wave, p > q) stores — and returns
@@ -1095,8 +1114,6 @@ def _build_inner(program: Program, target: Target) -> CompiledStencil:
 
     raw: Callable = interp
     if distributed:
-        from repro.dist.sharding import shard_map  # version-portable
-
         body: Callable = interp
         if target.slot_axis is not None:
             # slot-axis calling convention: every field carries a leading
@@ -1109,7 +1126,7 @@ def _build_inner(program: Program, target: Target) -> CompiledStencil:
             body = jax.vmap(interp)
             specs = [P(target.slot_axis, *tuple(s)) for s in specs]
         out_specs = tuple(specs[i] for i in ret_indices)
-        raw = shard_map(
+        raw = jax.shard_map(
             body,
             mesh=target.mesh,
             in_specs=tuple(specs),
@@ -1117,9 +1134,8 @@ def _build_inner(program: Program, target: Target) -> CompiledStencil:
             check_vma=False,  # pallas_call outputs carry no vma info
         )
     fn = raw
-    # the old StencilComputation computed this tuple but never passed it
-    # to jax.jit; donation is now honored (all field buffers — output
-    # buffers alias outputs, dead input time-buffers free their storage)
+    # all field buffers: output buffers alias outputs, dead input
+    # time-buffers free their storage
     donate = (
         tuple(range(len(program.field_args)))
         if (target.donate and target.jit)
@@ -1184,7 +1200,7 @@ def cached_callable(key: tuple, build: Callable[[], Callable]) -> Callable:
 
 
 # --------------------------------------------------------------------------
-# Shared helpers (also used by the StencilComputation shim)
+# Shared helpers
 # --------------------------------------------------------------------------
 
 

@@ -43,9 +43,6 @@ from repro.core import ir
 from repro.core.dialects import comm, dmp, stencil
 from repro.obs import trace as _obs
 
-# Backwards-compatible re-export: the lowering pass moved to core/passes.
-from repro.core.passes.lower_comm import lower_dmp_to_comm  # noqa: F401
-
 # --------------------------------------------------------------------------
 # Shared point-function evaluator
 # --------------------------------------------------------------------------
@@ -168,10 +165,15 @@ class StencilInterpreter:
         axis_sizes: dict[str, int],
         distributed: bool,
         backend: str = "jnp",
-        pallas_interpret: bool = True,
+        pallas_interpret: Optional[bool] = None,
         pallas_tile: Optional[tuple] = None,
     ) -> None:
         assert backend in ("jnp", "pallas")
+        if backend == "pallas" and pallas_interpret is None:
+            raise ValueError(
+                "backend='pallas' needs pallas_interpret resolved by the "
+                "Target (True only for the CPU interpret oracle)"
+            )
         self.func = func
         self.axis_sizes = dict(axis_sizes)
         self.distributed = distributed
@@ -293,35 +295,56 @@ class StencilInterpreter:
             raise NotImplementedError(f"function-level op {op.name}")
 
     # -- apply backends -------------------------------------------------
-    def _apply_backend(self, op, arrays, origins, rb):
+    def _runs_pallas(self, op: stencil.ApplyOp) -> bool:
+        # thin boundary frames go through the jnp evaluator: identical
+        # elementwise arithmetic, no per-slab kernel launch
         part = op.attributes.get("part")
-        if self.backend == "pallas" and (
+        return self.backend == "pallas" and (
             part is None or part.value == "interior"
+        )
+
+    def _apply_tile(self, op: stencil.ApplyOp) -> Optional[tuple]:
+        """The user tile for one pallas apply, or ``None`` (auto-tile): a
+        split interior (or an epoch-tiled apply, whose grown frame changes
+        the shape per step) may not fit the user tile; unsplit applies
+        keep the kernel's loud tile check so a misconfigured pallas_tile
+        stays diagnosable."""
+        from repro.kernels.stencil_apply import is_legal_tile
+
+        tile = self.pallas_tile
+        if (
+            tile is not None
+            and ("part" in op.attributes or "epoch_step" in op.attributes)
+            and not is_legal_tile(op.result_bounds.shape, tile)
         ):
+            return None
+        return tile
+
+    def plan_kernels(self) -> None:
+        """Lay out every Pallas kernel of the function without tracing:
+        raises ``KernelPlanError`` (naming the sizes) for a tile or VMEM
+        budget the TPU cannot take — at compile time, not first call."""
+        from repro.kernels.epoch_kernel import plan_epoch
+        from repro.kernels.stencil_apply import plan_apply
+
+        for op in self.func.body.ops:
+            if isinstance(op, stencil.ApplyOp) and self._runs_pallas(op):
+                plan_apply(op, op.result_bounds, self._apply_tile(op))
+            elif isinstance(op, stencil.FusedEpochOp) and op.results:
+                plan_epoch(op, self.pallas_tile)
+
+    def _apply_backend(self, op, arrays, origins, rb):
+        if self._runs_pallas(op):
             from repro.kernels.stencil_apply import run_apply_pallas
 
-            tile = self.pallas_tile
-            # a split interior (or an epoch-tiled apply, whose grown frame
-            # changes the shape per step) may not fit the user tile —
-            # auto-tile it; unsplit applies keep run_apply_pallas's loud
-            # divisibility assert so a misconfigured pallas_tile stays
-            # diagnosable
-            if (
-                (part is not None or "epoch_step" in op.attributes)
-                and tile is not None
-                and any(s % t != 0 for s, t in zip(rb.shape, tile))
-            ):
-                tile = None
             return run_apply_pallas(
                 op,
                 arrays,
                 origins,
                 rb,
-                tile=tile,
+                tile=self._apply_tile(op),
                 interpret=self.pallas_interpret,
             )
-        # thin boundary frames go through the jnp evaluator: identical
-        # elementwise arithmetic, no per-slab kernel launch
         return eval_apply_body(op, arrays, origins, rb)
 
     def _exec_combine(self, op: stencil.CombineOp, env):
@@ -349,79 +372,52 @@ class StencilInterpreter:
         # local emulation: every grid axis has size 1
         return patch if periodic else jnp.zeros_like(patch)
 
-    def _boundary_keep(self, op: comm.BoundaryMaskOp, shape: tuple):
-        """Boolean keep-mask over ``shape`` for a boundary_mask op (True =
-        inside the physical global domain), or ``None`` when every point
-        is inside.  Rank-position-aware (lax.axis_index) but
-        communication-free — shared by the inline interpreter path and the
-        fused-epoch kernel, which precomputes the mask outside the kernel
-        (axis_index is unavailable in a Pallas body)."""
-        vb: stencil.Bounds = op.temp.type.bounds
-        core: stencil.Bounds = op.core
-        grid: dmp.GridAttr = op.grid
-        keep = None
-        for d in range(vb.rank):
-            if core.lb[d] <= vb.lb[d] and vb.ub[d] <= core.ub[d]:
-                continue  # no points outside this shard's core along d
+    def _grid_coords(self, grid: dmp.GridAttr, rank: int) -> list:
+        """This rank's coordinate along the grid axis of every array dim
+        (0 where the dim is undecomposed or the program runs locally)."""
+        coords = []
+        for d in range(rank):
             gax = grid.axis_of_dim(d)
-            n = core.ub[d] - core.lb[d]
-            grid_extent = grid.shape[gax] if gax is not None else 1
-            if self.distributed and gax is not None and grid_extent > 1:
-                coord = lax.axis_index(grid.axis_names[gax])
+            if self.distributed and gax is not None and grid.shape[gax] > 1:
+                coords.append(lax.axis_index(grid.axis_names[gax]))
             else:
-                coord = 0
-            pos = lax.broadcasted_iota(jnp.int32, shape, d) + jnp.int32(
-                vb.lb[d] - core.lb[d]
-            )
-            glob = coord * n + pos
-            k = (glob >= 0) & (glob < grid_extent * n)
-            keep = k if keep is None else keep & k
-        return keep
+                coords.append(0)
+        return coords
 
     def _exec_boundary_mask(self, op: comm.BoundaryMaskOp, x):
         """Zero every point outside the physical (global) domain — the
         temporal-tiling analogue of the zero-BC halo_pad, applied to
         redundantly-computed epoch intermediates."""
-        keep = self._boundary_keep(op, tuple(x.shape))
+        rank = x.ndim
+        keep = boundary_keep(
+            op, tuple(x.shape), self._grid_coords(op.grid, rank), (0,) * rank
+        )
         if keep is None:
             return x
         return jnp.where(keep, x, jnp.zeros_like(x))
 
     def _exec_fused_epoch(self, op: stencil.FusedEpochOp, env) -> None:
-        """Route a fused epoch through the megakernel (pallas backend) or
-        evaluate its region inline (jnp reference).  Boundary keep-masks
-        are materialized as 0/1 arrays here — outside the kernel — and
-        passed in as extra inputs."""
-        arrays = [env[o] for o in op.operands]
-        masks = []
-        for inner in op.body.ops:
-            if isinstance(inner, comm.BoundaryMaskOp):
-                shape = inner.temp.type.bounds.shape
-                keep = self._boundary_keep(inner, shape)
-                masks.append(
-                    jnp.ones(shape, jnp.float32)
-                    if keep is None
-                    else keep.astype(jnp.float32)
-                )
-        if self.backend == "pallas":
-            from repro.kernels.epoch_kernel import run_epoch_pallas
+        """Route a fused epoch through the megakernel.  The rank's grid
+        coordinates are read here — outside the kernel, where
+        ``lax.axis_index`` exists — and the kernel rebuilds each
+        boundary keep-mask from them."""
+        from repro.kernels.epoch_kernel import run_epoch_pallas
 
-            outs = run_epoch_pallas(
-                op,
-                arrays,
-                masks,
-                tile=self.pallas_tile,
-                interpret=self.pallas_interpret,
+        if self.backend != "pallas":
+            raise NotImplementedError(
+                "stencil.fused_epoch lowers only to the pallas backend"
             )
-        else:
-            from repro.kernels.epoch_kernel import _emit_region
-
-            outs = _emit_region(
-                op,
-                [jnp.asarray(a, jnp.float32) for a in arrays],
-                masks,
-                lambda v: v.type.bounds,
-            )
+        rank = len(op.operands[0].type.bounds.lb)
+        masks = [i for i in op.body.ops if isinstance(i, comm.BoundaryMaskOp)]
+        coords = self._grid_coords(masks[0].grid, rank) if masks else [0] * rank
+        outs = run_epoch_pallas(
+            op,
+            [env[o] for o in op.operands],
+            coords,
+            boundary_keep,
+            tile=self.pallas_tile,
+            interpret=self.pallas_interpret,
+        )
         for res, arr in zip(op.results, outs):
             env[res] = arr
 
@@ -434,6 +430,32 @@ class StencilInterpreter:
             idx = tuple(o - g for o, g in zip(rect.lb, origin))
             x = lax.dynamic_update_slice(x, patch, idx)
         env[op.results[0]] = x
+
+
+def boundary_keep(op: comm.BoundaryMaskOp, shape: tuple, coords, shift):
+    """Boolean keep-mask (True = inside the physical global domain) for a
+    boundary_mask op over an array of ``shape`` whose first point sits
+    ``shift`` points past the op's value bounds origin, or ``None`` when
+    every point is inside.  ``coords[d]`` is the rank's coordinate along
+    the grid axis of dim ``d``.  Communication-free, so it runs both in
+    the interpreter and inside the fused-epoch kernel."""
+    vb: stencil.Bounds = op.temp.type.bounds
+    core: stencil.Bounds = op.core
+    grid: dmp.GridAttr = op.grid
+    keep = None
+    for d in range(vb.rank):
+        if core.lb[d] <= vb.lb[d] and vb.ub[d] <= core.ub[d]:
+            continue  # no points outside this shard's core along d
+        gax = grid.axis_of_dim(d)
+        n = core.ub[d] - core.lb[d]
+        grid_extent = grid.shape[gax] if gax is not None else 1
+        pos = lax.broadcasted_iota(jnp.int32, shape, d) + (
+            shift[d] + jnp.int32(vb.lb[d] - core.lb[d])
+        )
+        glob = coords[d] * n + pos
+        k = (glob >= 0) & (glob < grid_extent * n)
+        keep = k if keep is None else keep & k
+    return keep
 
 
 def _exec_halo_pad(op: comm.HaloPadOp, x):
@@ -465,6 +487,3 @@ def run_func_dataflow(
         interp._exec(op, env, {})
     raise AssertionError(f"{func.sym_name}: missing func.return")
 
-
-# Backwards-compatible alias: HaloPadOp moved into the comm dialect.
-HaloPadOp = comm.HaloPadOp

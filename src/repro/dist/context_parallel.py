@@ -38,7 +38,6 @@ from repro.core import ir
 from repro.core.dialects import dmp, stencil
 from repro.core.lowering import run_func_dataflow
 from repro.core.passes.decompose import make_strategy_1d
-from repro.dist.sharding import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,7 +185,7 @@ def context_parallel(
             return P(*entries)
 
         out_specs = jax.tree.map(out_spec_of, out_shape)
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(x_spec,) + tuple(P() for _ in rest),
@@ -275,7 +274,7 @@ def sliding_window_attention_cp(q, k, v, window: int, mesh: Mesh, axis: str):
         start = jax.lax.axis_index(axis) * (S // n)
         return local(kv_h, start, q_loc)
 
-    return shard_map(
+    return jax.shard_map(
         shard_local,
         mesh=mesh,
         in_specs=(P(None, None, axis), P(None, axis)),

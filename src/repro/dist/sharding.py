@@ -158,28 +158,6 @@ def _valid_spec(mesh: Mesh, spec: P, shape: tuple) -> P:
     return P(*out)
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; older
-    releases ship ``jax.experimental.shard_map`` with the ``check_rep``
-    spelling.  Every manual-SPMD call site in the repo (flash-decode,
-    MoE expert parallelism, context parallelism) routes through here so
-    the version split lives in exactly one place.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def factor_slot_mesh(
     mesh: Mesh,
     slots: int = 1,

@@ -27,7 +27,6 @@ from repro.api import Program, Target
 from repro.core import fd, ir
 from repro.core.builder import ApplyArgHandle, Expr, IRBuilder, build_apply
 from repro.core.dialects import stencil
-from repro.core.program import CompileOptions, time_loop  # noqa: F401  (re-export)
 from repro.core.passes.decompose import SlicingStrategy
 
 
@@ -319,49 +318,31 @@ class Operator:
         return n
 
     # -- execution --------------------------------------------------------
-    @property
-    def computation(self):
-        """DEPRECATED: the old StencilComputation shim over ``.program``
-        (built lazily, once — its last_local/last_timings state persists
-        across accesses like the old stored attribute did)."""
-        if getattr(self, "_computation", None) is None:
-            from repro.core.program import StencilComputation
-
-            self._computation = StencilComputation(
-                self.func, boundary=self.boundary
-            )
-        return self._computation
-
     def _target(
         self,
         mesh=None,
         strategy: Optional[SlicingStrategy] = None,
-        options: Optional[CompileOptions] = None,
         target: Optional[Target] = None,
     ) -> Target:
         if target is not None:
-            if mesh is not None or strategy is not None or options is not None:
+            if mesh is not None or strategy is not None:
                 raise ValueError(
-                    "pass either target= or the legacy mesh/strategy/options, "
-                    "not both"
+                    "pass either target= or mesh/strategy, not both"
                 )
             return target
-        opts = options or CompileOptions()
-        return opts.to_target(mesh=mesh, strategy=strategy)
+        return Target(mesh=mesh, strategy=strategy)
 
     def compile_step(
         self,
         mesh=None,
         strategy: Optional[SlicingStrategy] = None,
-        options: Optional[CompileOptions] = None,
         target: Optional[Target] = None,
     ):
         """Step over the *input* time buffers only; output buffers (fully
-        overwritten every step) are supplied internally.  Prefer
-        ``target=``; mesh/strategy/options are the legacy spelling."""
-        artifact = api.compile(
-            self.program, self._target(mesh, strategy, options, target)
-        )
+        overwritten every step) are supplied internally.  ``mesh`` and
+        ``strategy`` are shorthand for ``target=Target(mesh=...,
+        strategy=...)``."""
+        artifact = api.compile(self.program, self._target(mesh, strategy, target))
         return artifact.step()
 
     def zero_state(self, dtype=jnp.float32) -> list:
@@ -375,7 +356,6 @@ class Operator:
         timesteps: int,
         mesh=None,
         strategy: Optional[SlicingStrategy] = None,
-        options: Optional[CompileOptions] = None,
         target: Optional[Target] = None,
     ):
         """Run ``timesteps`` with time-buffer rotation (oldest→newest).
@@ -383,9 +363,7 @@ class Operator:
         ``timesteps`` counts single time steps; a
         ``Target(exchange_every=k)`` artifact advances k steps per call,
         so the loop runs in epochs (``CompiledStencil.time_loop``)."""
-        artifact = api.compile(
-            self.program, self._target(mesh, strategy, options, target)
-        )
+        artifact = api.compile(self.program, self._target(mesh, strategy, target))
         return artifact.time_loop(tuple(state), timesteps)
 
 

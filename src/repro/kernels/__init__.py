@@ -2,11 +2,13 @@
 
 Shared here (imported by ``api``, ``tune`` and the kernels themselves):
 
-- :func:`has_accelerator` / :func:`default_interpret` — the one source of
-  truth for whether Pallas kernels run in interpret mode.  Interpret is
-  the CPU-container default and the correctness oracle; on a real GPU/TPU
-  the default flips to the native (non-interpret) path.  Overridable with
-  ``REPRO_PALLAS_INTERPRET=0|1``.
+- :func:`default_interpret` — what ``Target(pallas_interpret=None)``
+  resolves to: interpret mode exactly when JAX's default backend is not a
+  TPU (the CPU test oracle), native Mosaic kernels on a TPU.  Every
+  kernel entry point takes the resolved value explicitly; none defaults
+  to interpret mode.
+- :class:`KernelPlanError` — a kernel that cannot be planned for the
+  chip (illegal tile, VMEM budget exceeded); raised at ``api.compile``.
 - :func:`dispatch_stats` — trace-time kernel-dispatch counters.  Every
   ``pl.pallas_call`` the backend traces bumps a counter, so a test can
   assert "one epoch == ONE kernel dispatch" by resetting, tracing one
@@ -17,27 +19,20 @@ Shared here (imported by ``api``, ``tune`` and the kernels themselves):
 from __future__ import annotations
 
 import dataclasses
-import os
 
 
-def has_accelerator() -> bool:
-    """True when JAX sees a GPU/TPU device."""
-    import jax
-
-    try:
-        return any(d.platform in ("gpu", "tpu") for d in jax.devices())
-    except Exception:  # noqa: BLE001 - no backend at all counts as "no"
-        return False
+class KernelPlanError(ValueError):
+    """A Pallas kernel that cannot be laid out for the TPU: a tile that
+    breaks the (8, 128) rule or a working set over the VMEM budget.  The
+    message names the sizes."""
 
 
 def default_interpret() -> bool:
-    """Resolved default for ``Target.pallas_interpret=None``: interpret on
-    CPU-only hosts, native Pallas when an accelerator is present.
-    ``REPRO_PALLAS_INTERPRET`` (0/1) overrides the device probe."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off")
-    return not has_accelerator()
+    """Resolved default for ``Target.pallas_interpret=None``: native
+    Pallas on a TPU, interpret mode on any other backend."""
+    import jax
+
+    return jax.default_backend() != "tpu"
 
 
 @dataclasses.dataclass

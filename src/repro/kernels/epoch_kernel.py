@@ -5,40 +5,53 @@ k-times-unrolled apply chain ``temporal-tile{k}`` produces, plus its
 ``comm.boundary_mask`` re-zeroing — into a single Pallas kernel body
 (DESIGN.md §10).  Where ``kernels/stencil_apply.py`` dispatches one
 kernel per apply (k HBM round-trips per epoch), here the k sub-steps'
-intermediates are values *inside* the kernel: XLA/Mosaic keeps them in
+intermediates are values *inside* the kernel: Mosaic keeps them in
 VMEM/registers, time-buffer rotation is value rebinding, and the
 shrinking redundant-boundary frames are just each sub-step's (smaller)
 result bounds.
 
-Two kernel modes, selected per call:
+Two kernel modes, chosen from the region:
 
-- **whole-shard** (default): a grid-free ``pallas_call`` whose refs are
-  the full shard arrays; every sub-step computes its full grown frame.
-  Always applicable — this is the mode the CPU interpret oracle runs.
-- **tiled**: when every escaping value shares one core bounds ``C`` and
-  the tile divides ``C``, the kernel runs on a grid over ``C`` with
-  overlapping element-indexed input windows sized by the *accumulated*
-  epoch halo demand (window = tile + (value bounds − C) per value); each
-  tile redundantly recomputes its neighbours' frame overlap — the
-  standard overlapped-tiling time-tile trade.
+- **tiled** (whenever every escaping value shares one core bounds ``C``
+  and no apply reads ``stencil.index``): a grid over ``C`` whose input
+  windows carry the *accumulated* epoch halo, laid out by the same
+  aligned-window rules as the per-apply kernel
+  (``stencil_apply.window_shape``/``window_source``); each tile
+  redundantly recomputes its neighbours' frame overlap — the standard
+  overlapped-tiling time-tile trade.
+- **whole-shard**: a grid-free call whose blocks are the full shard
+  arrays.  Used only where that fits the VMEM budget; otherwise planning
+  raises a ``KernelPlanError`` naming the sizes.
 
-Boundary masks are precomputed OUTSIDE the kernel (they need the rank's
-grid position via ``lax.axis_index``, unavailable in a kernel body) and
-passed in as 0/1 float arrays; inside, masking is a ``jnp.where`` —
-bitwise-identical to the interpreter's ``_exec_boundary_mask``.
+Boundary masks are computed *inside* the kernel from iotas, the tile
+origin and the rank's grid coordinates, which are read outside the
+kernel (``lax.axis_index`` is unavailable in a kernel body) and passed
+as a small int32 vector in SMEM.  Masking is the interpreter's own
+``boundary_keep`` + ``jnp.where``, so masked points match it exactly.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dialects import comm, stencil
-from repro.kernels import _DISPATCH
+from repro.kernels import _DISPATCH, KernelPlanError
+from repro.kernels.stencil_apply import (
+    VMEM_BUDGET_BYTES,
+    check_tile,
+    choose_tile,
+    compiler_params,
+    grid_shape,
+    window_shape,
+    window_source,
+    window_spec,
+)
 from repro.obs import trace as _obs
-from repro.kernels.stencil_apply import choose_tile
 
 
 def _region_values(fused_op: stencil.FusedEpochOp) -> list:
@@ -58,24 +71,14 @@ def _uses_index(fused_op: stencil.FusedEpochOp) -> bool:
     )
 
 
-def _emit_region(fused_op, inputs, mask_blocks, bounds_of) -> list:
-    """Evaluate the fused region over arrays/blocks.  ``bounds_of`` maps a
+def _emit_region(fused_op, inputs, bounds_of, keep_of) -> list:
+    """Evaluate the fused region over VMEM values.  ``bounds_of`` maps a
     region value to the bounds its array covers — actual logical bounds in
-    whole-shard mode, tile-relative bounds in tiled mode.  The same code
-    runs on jnp arrays (interpreter fallback) and on VMEM blocks.
-
-    Bitwise caveat: under ``jit`` the fused kernel is exactly the k
-    inlined per-step bodies the unfused path traces, so results are
-    bitwise-identical.  *Eagerly* (``Target(jit=False)``) the unfused
-    path compiles one XLA module per step while the fused kernel is one
-    module for all k — XLA CPU's per-module codegen (FMA contraction)
-    then drifts ~1ulp on non-power-of-two coefficients, and an
-    ``optimization_barrier`` between sub-steps does not stop it.  The
-    bitwise oracle therefore compares jitted targets."""
+    whole-shard mode, tile-relative bounds in tiled mode; ``keep_of(op,
+    shape)`` is a boundary_mask op's keep-mask (``None``: keep all)."""
     from repro.core.lowering import eval_apply_body
 
     env = dict(zip(fused_op.body.args, inputs))
-    mask_idx = 0
     for op in fused_op.body.ops:
         if isinstance(op, stencil.ApplyOp):
             arrays = [env[o] for o in op.operands]
@@ -84,10 +87,11 @@ def _emit_region(fused_op, inputs, mask_blocks, bounds_of) -> list:
             for res, val in zip(op.results, outs):
                 env[res] = val
         elif isinstance(op, comm.BoundaryMaskOp):
-            mask = mask_blocks[mask_idx]
-            mask_idx += 1
             x = env[op.temp]
-            env[op.results[0]] = jnp.where(mask != 0, x, jnp.zeros_like(x))
+            keep = keep_of(op, tuple(x.shape))
+            env[op.results[0]] = (
+                x if keep is None else jnp.where(keep, x, jnp.zeros_like(x))
+            )
         elif isinstance(op, stencil.FusedYieldOp):
             return [env[o] for o in op.operands]
         else:  # pragma: no cover - FusedEpochOp.verify_ rejects these
@@ -106,117 +110,124 @@ def _rel_bounds(b: stencil.Bounds, core: stencil.Bounds, tile: tuple):
     )
 
 
-def _window_spec(window: tuple, index_map):
-    # overlapping element-indexed windows: newer jax spells this
-    # pl.Element block dims, older jax an Unblocked indexing mode
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(tuple(pl.Element(w) for w in window), index_map)
-    return pl.BlockSpec(window, index_map, indexing_mode=pl.unblocked)
+def _span(b: stencil.Bounds, core: stencil.Bounds) -> tuple:
+    return tuple(bs - cs for bs, cs in zip(b.shape, core.shape))
+
+
+def plan_epoch(
+    fused_op: stencil.FusedEpochOp, tile: Optional[Sequence[int]] = None
+) -> Optional[tuple]:
+    """The tile of the tiled mode, or ``None`` for whole-shard mode (only
+    when it fits VMEM).  Raises ``KernelPlanError`` naming the sizes."""
+    escapes = [r.type.bounds for r in fused_op.results]
+    core = escapes[0]
+    if all(b == core for b in escapes) and not _uses_index(fused_op):
+        # windows: the externals, double-buffered; the intermediates
+        # (shrinking frames) count among the chooser's tile temporaries
+        spans = [_span(a.type.bounds, core) for a in fused_op.body.args]
+        if tile is not None:
+            return check_tile(core.shape, tile)
+        return choose_tile(core.shape, spans, n_out=len(escapes))
+    # whole-shard: every region value resident at once, no double-buffering
+    numel = sum(
+        math.prod(v.type.bounds.shape)
+        for v in _region_values(fused_op)
+        if isinstance(v.type, stencil.TempType)
+    )
+    need = 4 * numel
+    if need > VMEM_BUDGET_BYTES:
+        shapes = [tuple(a.type.bounds.shape) for a in fused_op.body.args]
+        raise KernelPlanError(
+            f"fused epoch cannot be tiled (escapes {[tuple(b.shape) for b in escapes]} "
+            f"differ or it reads stencil.index) and its whole shard needs "
+            f"{need} B of VMEM over the {VMEM_BUDGET_BYTES} B budget "
+            f"(inputs {shapes})"
+        )
+    return None
 
 
 def build_epoch_kernel(
     fused_op: stencil.FusedEpochOp,
-    mask_shapes: Sequence[tuple],
-    tile: Optional[tuple] = None,
-    interpret: bool = True,
+    keep_fn,
+    tile: Optional[tuple],
+    *,
+    interpret: bool,
 ):
     """Code-generate one pallas_call for a whole fused epoch.
 
-    Returns a callable taking ``(*external_arrays, *mask_arrays)`` (the
-    op's operands in order, then one 0/1 keep-mask per boundary_mask op in
-    region order) and returning the escape arrays (the op's results)."""
-    mask_ops = [
-        op for op in fused_op.body.ops if isinstance(op, comm.BoundaryMaskOp)
-    ]
-    assert len(mask_shapes) == len(mask_ops)
-    n_in = len(fused_op.operands)
-    n_mask = len(mask_ops)
+    Returns a callable taking ``(coords, *sources)``: the rank's int32
+    grid coordinate per dim, then one array per op operand (re-based with
+    ``window_source`` in tiled mode).  ``keep_fn(op, shape, coords,
+    shift)`` builds a boundary_mask keep-mask for a value whose first
+    point sits ``shift`` points past its own bounds' origin."""
     escape_bounds = [r.type.bounds for r in fused_op.results]
+    rank = escape_bounds[0].rank
+    n_in = len(fused_op.operands)
+    n_out = len(escape_bounds)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    core = escape_bounds[0] if escape_bounds else None
-    tiled_ok = (
-        core is not None
-        and all(b == core for b in escape_bounds)
-        and not _uses_index(fused_op)  # stencil.index needs logical coords
-    )
-    if tiled_ok:
-        # VMEM working set: every region value's window (externals carry
-        # the accumulated epoch halo; intermediates the shrinking frames)
-        spans = [
-            (
-                tuple(vl - cl for vl, cl in zip(v.type.bounds.lb, core.lb)),
-                tuple(vu - cu for vu, cu in zip(v.type.bounds.ub, core.ub)),
-            )
-            for v in _region_values(fused_op)
-            if isinstance(v.type, stencil.TempType)
-        ]
-        if tile is None:
-            tile = choose_tile(core.shape, spans)
-        tile = tuple(tile)
-        if len(tile) != core.rank or any(
-            t < 1 or s % t for s, t in zip(core.shape, tile)
-        ):
-            tiled_ok = False  # fall back rather than mis-tile an epoch
-        elif tile == tuple(core.shape):
-            tiled_ok = False  # one tile == whole shard: skip the windows
-
-    if not tiled_ok:
-        # -- whole-shard mode: grid-free, refs are the full arrays ------
-        def bounds_of(v):
-            return v.type.bounds
-
-        def kernel(*refs):
+    if tile is None:
+        # -- whole-shard mode: grid-free, blocks are the full arrays -----
+        def kernel(coords_ref, *refs):
+            coords = [coords_ref[d] for d in range(rank)]
             inputs = [r[...] for r in refs[:n_in]]
-            masks = [r[...] for r in refs[n_in : n_in + n_mask]]
-            outs = _emit_region(fused_op, inputs, masks, bounds_of)
-            for o_ref, val in zip(refs[n_in + n_mask :], outs):
+            outs = _emit_region(
+                fused_op, inputs, lambda v: v.type.bounds,
+                lambda op, shape: keep_fn(op, shape, coords, (0,) * rank),
+            )
+            for o_ref, val in zip(refs[n_in:], outs):
                 o_ref[...] = val
 
         out_shape = [
             jax.ShapeDtypeStruct(b.shape, jnp.float32) for b in escape_bounds
         ]
+        vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
         return pl.pallas_call(
             kernel,
-            out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
+            in_specs=[smem] + [vmem] * n_in,
+            out_specs=[vmem] * n_out if n_out > 1 else vmem,
+            out_shape=out_shape if n_out > 1 else out_shape[0],
+            compiler_params=compiler_params(0),
             interpret=interpret,
         )
 
-    # -- tiled mode: grid over core, overlapping epoch-halo windows -----
-    grid = tuple(s // t for s, t in zip(core.shape, tile))
+    # -- tiled mode: grid over core, aligned epoch-halo windows -------------
+    core = escape_bounds[0]
+    grid = grid_shape(core.shape, tile)
     rel = {
         v: _rel_bounds(v.type.bounds, core, tile)
         for v in _region_values(fused_op)
         if isinstance(v.type, stencil.TempType)
     }
-
-    def tile_origin(*ids):
-        return tuple(i * t for i, t in zip(ids, tile))
-
-    in_specs = [
-        _window_spec(rel[arg].shape, tile_origin) for arg in fused_op.body.args
-    ] + [
-        _window_spec(rel[m.results[0]].shape, tile_origin) for m in mask_ops
-    ]
-    out_specs = [pl.BlockSpec(tile, lambda *ids: ids) for _ in escape_bounds]
-    out_shape = [
-        jax.ShapeDtypeStruct(core.shape, jnp.float32) for _ in escape_bounds
+    in_specs = [smem] + [
+        window_spec(grid, tile, window_shape(tile, _span(a.type.bounds, core)))
+        for a in fused_op.body.args
     ]
 
-    def kernel(*refs):
+    def kernel(coords_ref, *refs):
+        coords = [coords_ref[d] for d in range(rank)]
+        origin = [pl.program_id(d) * t for d, t in enumerate(tile)]
         inputs = [r[...] for r in refs[:n_in]]
-        masks = [r[...] for r in refs[n_in : n_in + n_mask]]
         # escapes all have bounds == core, so rel(escape) == [0, tile):
         # each yielded value IS exactly this tile's output block
-        outs = _emit_region(fused_op, inputs, masks, lambda v: rel[v])
-        for o_ref, val in zip(refs[n_in + n_mask :], outs):
+        outs = _emit_region(
+            fused_op, inputs, lambda v: rel[v],
+            lambda op, shape: keep_fn(op, shape, coords, origin),
+        )
+        for o_ref, val in zip(refs[n_in:], outs):
             o_ref[...] = val
 
+    out_specs = [pl.BlockSpec(tile, lambda *ids: ids) for _ in range(n_out)]
+    out_shape = [
+        jax.ShapeDtypeStruct(core.shape, jnp.float32) for _ in range(n_out)
+    ]
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
-        out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
+        out_specs=out_specs if n_out > 1 else out_specs[0],
+        out_shape=out_shape if n_out > 1 else out_shape[0],
+        compiler_params=compiler_params(rank),
         interpret=interpret,
     )
 
@@ -224,25 +235,32 @@ def build_epoch_kernel(
 def run_epoch_pallas(
     fused_op: stencil.FusedEpochOp,
     arrays: Sequence,
-    masks: Sequence,
+    coords,
+    keep_fn,
     tile: Optional[tuple] = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> list:
     """Entry point used by the lowering's pallas backend: one traced
-    pallas_call per fused epoch (counted in ``kernels.dispatch_stats``)."""
+    pallas_call per fused epoch (counted in ``kernels.dispatch_stats``).
+    ``arrays[k]`` covers operand ``k``'s bounds; ``coords`` is the rank's
+    int32 grid coordinate per dim."""
     if not fused_op.results:
         return []
     with _obs.span("pallas:fused_epoch", cat="kernel", rank=None,
                    interpret=interpret):
-        call = build_epoch_kernel(
-            fused_op,
-            [tuple(m.shape) for m in masks],
-            tile=tile,
-            interpret=interpret,
-        )
+        tile = plan_epoch(fused_op, tile)
+        sources = [a.astype(jnp.float32) for a in arrays]
+        if tile is not None:
+            core = fused_op.results[0].type.bounds
+            sources = [
+                window_source(
+                    s, (0,) * core.rank, core.shape, tile,
+                    window_shape(tile, _span(a.type.bounds, core)),
+                )
+                for s, a in zip(sources, fused_op.body.args)
+            ]
+        call = build_epoch_kernel(fused_op, keep_fn, tile, interpret=interpret)
         _DISPATCH.fused_epoch_calls += 1
-        out = call(
-            *[a.astype(jnp.float32) for a in arrays],
-            *[m.astype(jnp.float32) for m in masks],
-        )
+        out = call(jnp.asarray(coords, jnp.int32), *sources)
     return list(out) if isinstance(out, (tuple, list)) else [out]

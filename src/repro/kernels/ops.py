@@ -7,8 +7,8 @@ upstream).
 ``interpret`` defaults to ``None`` — resolved through the same
 :func:`repro.kernels.default_interpret` the compile surface uses for
 ``Target.pallas_interpret``, so ops-level callers and compiled programs
-agree on one flag source (interpret on CPU hosts, native Pallas on
-GPU/TPU, ``REPRO_PALLAS_INTERPRET`` overriding both).
+agree on one flag source (native Pallas on a TPU, the interpreter on any
+other backend).
 """
 from __future__ import annotations
 

@@ -3,167 +3,296 @@
 The paper lowers stencil kernels to GPU (CUDA via MLIR) and FPGA (HLS);
 the TPU-native analogue is a Pallas kernel with explicit BlockSpec VMEM
 tiling.  Rather than hand-writing one kernel per stencil, the apply op's
-*point function is code-generated into the kernel body*: operand blocks
-are fetched to VMEM as overlapping windows (``pl.Element`` block dims —
-window = tile + access extent), accesses become static slices of the
-resident block, and the arithmetic DAG is emitted verbatim — the same
-"domain information drives the lowering" story the paper tells for GPUs,
-retargeted at the MXU/VPU memory hierarchy:
+*point function is code-generated into the kernel body*: each grid step
+fetches one overlapping window per operand into VMEM, accesses become
+static slices of the resident window, and the arithmetic DAG is emitted
+verbatim — the same "domain information drives the lowering" story the
+paper tells for GPUs, retargeted at the TPU memory hierarchy:
 
-    HBM --(BlockSpec window, overlapping)--> VMEM block --(slices)--> VPU
+    HBM --(aligned overlapping window, double-buffered)--> VMEM --(slices)--> VPU
 
-Tiles keep the minor (lane) dimension contiguous and whole where it fits
-(it maps to the 128-wide vector lanes), and split the leading dimensions
-to bound the VMEM working set; hardware-aligned sizes (multiples of 8 /
-128) are preferred.
-
-Validated against ``repro.kernels.ref`` in ``interpret=True`` mode (this
-container is CPU-only; TPU is the target).
+Windows obey the TPU's (8, 128) block rule for f32: a window is the
+output tile grown by the operand's access extent and rounded up to whole
+(sublane, lane) tiles along the last two dims, and every window starts
+at a multiple of the tile, so each element-indexed block (``pl.Element``)
+has an aligned shape and offset.  A tile need not divide the result: the
+grid rounds up and Pallas drops the last tile's overhang (epoch-grown
+applies have extents like 16384 + 24 that no aligned tile divides).  The
+operand is sliced and zero-padded in XLA beforehand (``window_source``)
+so every window lies inside the array; the padding feeds only the
+dropped overhang or rounding slack that is never read.  The tile is chosen
+for the fewest HBM bytes fetched per output point whose *whole-kernel*
+VMEM footprint — every window and output block double-buffered, plus
+value temporaries — fits ``VMEM_BUDGET_BYTES``.
 """
 from __future__ import annotations
 
+import itertools
 import math
-from functools import partial
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dialects import stencil
-from repro.kernels import _DISPATCH
+from repro.kernels import _DISPATCH, KernelPlanError
 from repro.obs import trace as _obs
 
+SUBLANES, LANES = 8, 128  # f32 (sublane, lane) tile of the last two dims
+# Whole-kernel working set the tile chooser targets, and the scoped VMEM
+# limit handed to Mosaic (a v5e core has 128 MiB of VMEM; the limit
+# leaves the compiler room beyond the estimate).
+VMEM_BUDGET_BYTES = 24 * 2**20
+VMEM_LIMIT_BYTES = 96 * 2**20
+# value temporaries a kernel body keeps live, counted in output tiles
+TEMP_TILES = 4
+# Mosaic unrolls a kernel body over the vector registers of its tile, so
+# compile time grows with tile area: 128K points (128 f32 vregs) keeps a
+# per-apply kernel near a second and a fused epoch near ten.
+MAX_TILE_POINTS = 128 * 1024
 
-VMEM_BUDGET_BYTES = 4 * 1024 * 1024  # per-operand working-set target
+
+def _align(rank: int, d: int) -> int:
+    if d == rank - 1:
+        return LANES
+    if d == rank - 2:
+        return SUBLANES
+    return 1
 
 
-def _divisors_desc(n: int) -> list:
-    out = [d for d in range(1, n + 1) if n % d == 0]
-    return sorted(out, reverse=True)
+def _round_up(n: int, a: int) -> int:
+    return -(-n // a) * a
+
+
+def _vmem_numel(shape: Sequence[int]) -> int:
+    """Elements a VMEM buffer of ``shape`` occupies (the last two dims
+    padded to the (8, 128) tile)."""
+    rank = len(shape)
+    return math.prod(_round_up(s, _align(rank, d)) for d, s in enumerate(shape))
+
+
+def _legal_extent(n: int, t: int, align: int) -> bool:
+    return t == n or (0 < t < n and t % align == 0)
+
+
+def tile_extents(n: int, align: int) -> list:
+    """The tile extents the chooser tries along a dim of extent ``n``:
+    ``align * 2**j`` below ``n``, and ``n`` itself."""
+    out, t = [], align
+    while t < n:
+        out.append(t)
+        t *= 2
+    return out + [n]
+
+
+def is_legal_tile(shape: Sequence[int], tile: Sequence[int]) -> bool:
+    """Every extent is the dim's whole extent or a multiple of its
+    alignment below it."""
+    rank = len(shape)
+    return len(tile) == rank and all(
+        _legal_extent(n, t, _align(rank, d))
+        for d, (n, t) in enumerate(zip(shape, tile))
+    )
+
+
+def grid_shape(shape: Sequence[int], tile: Sequence[int]) -> tuple:
+    return tuple(-(-n // t) for n, t in zip(shape, tile))
+
+
+def check_tile(shape: Sequence[int], tile: Sequence[int]) -> tuple:
+    """``tile`` as a tuple, or a ``KernelPlanError`` naming the rule it
+    breaks."""
+    tile = tuple(int(t) for t in tile)
+    rank = len(shape)
+    if len(tile) != rank:
+        raise KernelPlanError(
+            f"tile {tile} has {len(tile)} dims, shape {tuple(shape)} has {rank}"
+        )
+    for d, (n, t) in enumerate(zip(shape, tile)):
+        if not _legal_extent(n, t, _align(rank, d)):
+            raise KernelPlanError(
+                f"tile {tile} is illegal for shape {tuple(shape)}: dim {d} "
+                f"extent {t} must be a multiple of {_align(rank, d)} below "
+                f"{n}, or {n} itself"
+            )
+    return tile
+
+
+def window_shape(tile: Sequence[int], span: Sequence[int]) -> tuple:
+    """VMEM window of an operand read ``span`` points beyond the tile."""
+    rank = len(tile)
+    return tuple(
+        _round_up(t + s, _align(rank, d))
+        for d, (t, s) in enumerate(zip(tile, span))
+    )
+
+
+def vmem_bytes(tile, spans, n_out: int, double_buffered: bool = True) -> int:
+    """Whole-kernel VMEM estimate for one grid step: every operand window
+    and output block (twice when Pallas double-buffers them) plus
+    ``TEMP_TILES`` tile-sized value temporaries, in f32 bytes."""
+    blocks = sum(_vmem_numel(window_shape(tile, s)) for s in spans)
+    blocks += n_out * _vmem_numel(tile)
+    copies = 2 if double_buffered else 1
+    return 4 * (copies * blocks + TEMP_TILES * _vmem_numel(tile))
 
 
 def choose_tile(
-    shape: tuple, spans: Sequence[tuple], budget: int = VMEM_BUDGET_BYTES
+    shape: Sequence[int],
+    spans: Sequence[Sequence[int]],
+    n_out: int = 1,
+    budget: int = VMEM_BUDGET_BYTES,
 ) -> tuple:
-    """Pick a tile: minor dim whole (lane alignment), leading dims split
-    until every operand window fits the VMEM budget."""
+    """The legal tile of ``shape`` fetching the fewest window bytes per
+    output point whose whole-kernel VMEM fits ``budget`` and whose area is
+    at most ``MAX_TILE_POINTS`` (ties: the larger tile, i.e. fewer grid
+    steps).  ``spans[k]`` is how far operand ``k`` reads beyond the tile
+    along each dim."""
+    shape = tuple(shape)
     rank = len(shape)
-    tile = list(shape)
+    options = [tile_extents(n, _align(rank, d)) for d, n in enumerate(shape)]
+    best, best_key = None, None
+    for tile in itertools.product(*options):
+        if (
+            math.prod(tile) > MAX_TILE_POINTS
+            or vmem_bytes(tile, spans, n_out) > budget
+        ):
+            continue
+        fetched = sum(math.prod(window_shape(tile, s)) for s in spans)
+        fetched *= math.prod(grid_shape(shape, tile))
+        key = (fetched / math.prod(shape), -math.prod(tile))
+        if best_key is None or key < best_key:
+            best, best_key = tile, key
+    if best is None:
+        smallest = tuple(o[0] for o in options)
+        raise KernelPlanError(
+            f"no tile of shape {shape} fits the {budget} B VMEM budget (or "
+            f"{MAX_TILE_POINTS} points): the "
+            f"smallest legal tile {smallest} needs "
+            f"{vmem_bytes(smallest, spans, n_out)} B (windows "
+            f"{[window_shape(smallest, s) for s in spans]}, "
+            f"{n_out} output(s))"
+        )
+    return best
 
-    def worst_window_bytes() -> int:
-        w = 0
-        for lo, hi in spans:
-            numel = 1
-            for d in range(rank):
-                numel *= tile[d] + (hi[d] - lo[d])
-            w = max(w, numel * 4)
-        return w
 
-    # split leading dims first; never split the minor dim unless huge
-    for d in range(rank - 1):
-        for div in _divisors_desc(shape[d]):
-            tile[d] = div
-            if worst_window_bytes() <= budget:
-                break
-        if worst_window_bytes() <= budget:
-            break
-    if worst_window_bytes() > budget and rank >= 1:
-        d = rank - 1
-        for div in _divisors_desc(shape[d]):
-            if div % 128 == 0 or div == 1 or div == shape[d]:
-                tile[d] = div
-                if worst_window_bytes() <= budget:
-                    break
-    return tuple(tile)
+def window_source(arr, base, shape, tile, window):
+    """``arr`` re-based for element-indexed windows: window ``i`` along
+    each dim starts at ``i * tile`` and the last one (for an output of
+    ``shape``) ends inside the array.  Slices off ``base`` leading points,
+    then trims or zero-pads the high end; the padding feeds only rounding
+    slack and the last tile's dropped overhang."""
+    need = tuple(
+        (g - 1) * t + w
+        for g, t, w in zip(grid_shape(shape, tile), tile, window)
+    )
+    avail = tuple(min(s - b, m) for s, b, m in zip(arr.shape, base, need))
+    if any(base) or avail != tuple(arr.shape):
+        arr = lax.slice(
+            arr, tuple(base), tuple(b + a for b, a in zip(base, avail))
+        )
+    pad = [(0, m - a) for m, a in zip(need, avail)]
+    if any(hi for _, hi in pad):
+        arr = jnp.pad(arr, pad)
+    return arr
+
+
+def window_spec(
+    grid: Sequence[int], tile: Sequence[int], window: Sequence[int]
+) -> pl.BlockSpec:
+    """Element-indexed block of shape ``window`` starting at tile ``i``'s
+    origin: an aligned offset, or the constant 0 along a dim the grid does
+    not split (Mosaic must prove each offset aligned, and a whole-extent
+    tile such as 2054 is not)."""
+    steps = tuple((t if g > 1 else 0) for g, t in zip(grid, tile))
+
+    def index_map(*ids):
+        return tuple(i * s for i, s in zip(ids, steps))
+
+    return pl.BlockSpec(tuple(pl.Element(w) for w in window), index_map)
+
+
+def compiler_params(rank: int) -> pltpu.CompilerParams:
+    # every grid step writes its own output tile: steps are independent
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * rank,
+        vmem_limit_bytes=VMEM_LIMIT_BYTES,
+    )
+
+
+def apply_spans(apply_op: stencil.ApplyOp) -> list:
+    """(lo, hi) access extent of every operand (zeros if never read)."""
+    rank = apply_op.result_bounds.rank
+    exts = apply_op.access_extents()
+    zero = (tuple([0] * rank), tuple([0] * rank))
+    return [exts.get(k, zero) for k in range(len(apply_op.operands))]
+
+
+def plan_apply(
+    apply_op: stencil.ApplyOp,
+    result_bounds: stencil.Bounds,
+    tile: Optional[Sequence[int]] = None,
+) -> tuple:
+    """The tile one apply kernel runs with: ``tile`` checked against the
+    (8, 128) rule, or the chooser's pick.  Raises ``KernelPlanError``."""
+    shape = result_bounds.shape
+    spans = [
+        tuple(h - l for l, h in zip(lo, hi)) for lo, hi in apply_spans(apply_op)
+    ]
+    if tile is not None:
+        return check_tile(shape, tile)
+    return choose_tile(shape, spans, n_out=len(apply_op.results))
 
 
 def build_apply_kernel(
     apply_op: stencil.ApplyOp,
-    operand_shapes: Sequence[tuple],
-    operand_origins: Sequence[tuple],
     result_bounds: stencil.Bounds,
-    tile: Optional[tuple] = None,
-    interpret: bool = True,
+    tile: tuple,
+    *,
+    interpret: bool,
 ):
-    """Code-generate a pallas_call for one stencil.apply.
+    """Code-generate a pallas_call for one stencil.apply over ``tile``.
 
-    ``operand_origins[k]`` is the logical coordinate of ``arrays[k][0…0]``
-    (post-swap temps have origin = core.lb - halo_lo).
-    """
+    The call takes one re-based window source per operand
+    (``window_source``) and returns the result arrays."""
     from repro.core.lowering import eval_apply_body  # shared evaluator
 
-    rb = result_bounds
-    rank = rb.rank
-    shape = rb.shape
-    exts = apply_op.access_extents()
-    n_in = len(apply_op.operands)
-    zero = (tuple([0] * rank), tuple([0] * rank))
-    spans = [exts.get(k, zero) for k in range(n_in)]
-
-    tile = tuple(tile) if tile else choose_tile(shape, spans)
-    assert all(s % t == 0 for s, t in zip(shape, tile)), (
-        f"tile {tile} must divide result shape {shape}"
-    )
-    grid = tuple(s // t for s, t in zip(shape, tile))
-
-    in_specs = []
-    window_origins = []
-    for k in range(n_in):
-        lo, hi = spans[k]
-        base = tuple(
-            rl + l - og
-            for rl, l, og in zip(rb.lb, lo, operand_origins[k])
+    shape = result_bounds.shape
+    rank = len(shape)
+    spans = apply_spans(apply_op)
+    grid = grid_shape(shape, tile)
+    in_specs = [
+        window_spec(
+            grid, tile, window_shape(tile, [h - l for l, h in zip(lo, hi)])
         )
-        window = tuple(t + (h - l) for t, l, h in zip(tile, lo, hi))
-        assert all(b >= 0 for b in base), (
-            f"operand {k} window starts at {base} before array origin "
-            f"(halo missing — run the decompose pass first)"
-        )
-
-        def index_map(*ids, _base=base):
-            return tuple(
-                i * t + b for i, t, b in zip(ids, tile, _base)
-            )
-
-        # overlapping element-indexed windows: newer jax spells this
-        # pl.Element block dims, older jax an Unblocked indexing mode
-        if hasattr(pl, "Element"):
-            spec = pl.BlockSpec(
-                tuple(pl.Element(w) for w in window), index_map
-            )
-        else:
-            spec = pl.BlockSpec(
-                window, index_map, indexing_mode=pl.unblocked
-            )
-        in_specs.append(spec)
-        window_origins.append(tuple(lo))
-
-    out_specs = [
-        pl.BlockSpec(tile, lambda *ids: ids) for _ in apply_op.results
+        for lo, hi in spans
     ]
-    out_shape = [
-        jax.ShapeDtypeStruct(shape, jnp.float32) for _ in apply_op.results
-    ]
+    # window k's point 0 sits at tile-relative coordinate lo_k
+    window_origins = [tuple(lo) for lo, _ in spans]
     tile_bounds = stencil.Bounds.from_shape(tile)
+    n_in = len(spans)
 
     def kernel(*refs):
-        in_refs = refs[:n_in]
-        out_refs = refs[n_in:]
-        blocks = [r[...] for r in in_refs]
+        blocks = [r[...] for r in refs[:n_in]]
         outs = eval_apply_body(apply_op, blocks, window_origins, tile_bounds)
-        for o_ref, val in zip(out_refs, outs):
+        for o_ref, val in zip(refs[n_in:], outs):
             o_ref[...] = val
 
-    call = pl.pallas_call(
+    n_out = len(apply_op.results)
+    out_specs = [pl.BlockSpec(tile, lambda *ids: ids) for _ in range(n_out)]
+    out_shape = [jax.ShapeDtypeStruct(shape, jnp.float32) for _ in range(n_out)]
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
-        out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
+        out_specs=out_specs if n_out > 1 else out_specs[0],
+        out_shape=out_shape if n_out > 1 else out_shape[0],
+        compiler_params=compiler_params(rank),
         interpret=interpret,
     )
-    return call
 
 
 def run_apply_pallas(
@@ -172,20 +301,30 @@ def run_apply_pallas(
     origins: Sequence[tuple],
     result_bounds: stencil.Bounds,
     tile: Optional[tuple] = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> list:
-    """Entry point used by the lowering's pallas backend.  Each call is
-    one traced pallas_call (counted in ``kernels.dispatch_stats``)."""
+    """Entry point used by the lowering's pallas backend: ``arrays[k]``
+    holds logical points from ``origins[k]`` on.  Each call is one traced
+    pallas_call (counted in ``kernels.dispatch_stats``)."""
     with _obs.span("pallas:apply", cat="kernel", rank=None,
                    interpret=interpret):
-        call = build_apply_kernel(
-            apply_op,
-            [tuple(a.shape) for a in arrays],
-            origins,
-            result_bounds,
-            tile=tile,
-            interpret=interpret,
-        )
+        tile = plan_apply(apply_op, result_bounds, tile)
+        rb = result_bounds
+        sources = []
+        for arr, og, (lo, hi) in zip(arrays, origins, apply_spans(apply_op)):
+            base = tuple(r + l - o for r, l, o in zip(rb.lb, lo, og))
+            if any(b < 0 for b in base):
+                raise ValueError(
+                    f"operand window starts at {base} before the array "
+                    "origin (halo missing — run the decompose pass first)"
+                )
+            window = window_shape(tile, [h - l for l, h in zip(lo, hi)])
+            sources.append(
+                window_source(arr.astype(jnp.float32), base, rb.shape, tile,
+                              window)
+            )
+        call = build_apply_kernel(apply_op, rb, tile, interpret=interpret)
         _DISPATCH.apply_calls += 1
-        out = call(*[a.astype(jnp.float32) for a in arrays])
+        out = call(*sources)
     return list(out) if isinstance(out, (tuple, list)) else [out]

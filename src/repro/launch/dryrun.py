@@ -1,5 +1,7 @@
 import os
 
+# a CPU-only tool: virtual CPU devices, never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")

@@ -10,15 +10,12 @@ import jax
 
 
 def _make(shape, axes):
-    # newer jax takes axis_types (Auto = sharding propagation decides);
-    # older jax has no AxisType and make_mesh defaults to the same
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape),
-            tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    # Auto axes: sharding propagation decides the layout
+    return jax.make_mesh(
+        tuple(shape),
+        tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

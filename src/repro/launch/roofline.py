@@ -3,7 +3,7 @@
     PYTHONPATH=src python -m repro.launch.roofline [--outdir results/dryrun]
                                                    [--markdown]
 
-Terms (TPU v5e per chip: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI):
+Terms, with the per-chip peaks of ``PEAKS[device_kind]``:
 
     compute    = HLO_FLOPs_per_device   / peak_FLOPs
     memory     = HLO_bytes_per_device   / HBM_bw
@@ -30,10 +30,43 @@ import re
 from dataclasses import dataclass, field
 from typing import Optional
 
-PEAK_FLOPS = 197e12   # bf16 / chip
-HBM_BW = 819e9        # bytes/s / chip
-LINK_BW = 50e9        # bytes/s / ICI link
-LINK_LATENCY = 2e-6   # per-message launch latency (collective-permute hop)
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one device kind."""
+
+    flops: float           # bf16 FLOP/s
+    hbm_bytes_s: float     # HBM bytes/s
+    link_bytes_s: float    # ICI bytes/s per link
+    source: str
+
+
+V5E = "TPU v5 lite"  # jax Device.device_kind of a TPU v5e chip
+
+# Keyed by ``jax.Device.device_kind``.  A kind missing here is an error,
+# never a default: a model of the wrong chip is worse than none.
+PEAKS = {
+    V5E: DevicePeaks(
+        flops=197e12,
+        hbm_bytes_s=819e9,
+        link_bytes_s=50e9,  # 1,600 Gbit/s per chip over 4 ICI links
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+
+LINK_LATENCY = 2e-6   # modelled per-message launch latency (not published)
+
+
+def device_peaks(device_kind: Optional[str]) -> DevicePeaks:
+    """The peaks of ``device_kind``; ``ValueError`` for any kind not in
+    ``PEAKS`` (the CPU included: a CPU caller that models a chip names
+    it, e.g. ``V5E``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: "
+            f"{sorted(PEAKS)}); name the modelled chip explicitly"
+        ) from None
 
 COLLECTIVE_RE = re.compile(
     r"\b(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
@@ -97,6 +130,9 @@ class RooflineTerms:
     messages_per_epoch: int = 0
     step_halo: tuple = ()     # per-dim per-step halo width (max of lo/hi)
     local_shape: tuple = ()   # local shard core extents
+    # the chip whose peaks turn flops/bytes into seconds (a PEAKS key);
+    # the structural terms (feasibility, redundancy) need none
+    device_kind: Optional[str] = None
 
     def __post_init__(self) -> None:
         self.flops = float(self.flops)
@@ -112,16 +148,20 @@ class RooflineTerms:
         return float(sum(self.collectives.values()))
 
     @property
+    def peaks(self) -> DevicePeaks:
+        return device_peaks(self.device_kind)
+
+    @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / self.peaks.hbm_bytes_s
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / LINK_BW
+        return self.collective_bytes / self.peaks.link_bytes_s
 
     @property
     def dominant(self) -> str:
@@ -235,6 +275,7 @@ class RooflineTerms:
             "messages_per_epoch": self.messages_per_epoch,
             "redundant_compute_factor": self.redundant_compute_factor(),
             "recommended_exchange_every": self.recommend_exchange_every(),
+            "device_kind": self.device_kind,
         }
 
 
@@ -262,6 +303,10 @@ def _arch_dims(arch: str) -> tuple:
     return _DIMS_CACHE[arch]
 
 
+# the LM dry-run compiles on CPU for a v5e production mesh
+_CELL_PEAKS = device_peaks(V5E)
+
+
 @dataclass
 class Cell:
     arch: str
@@ -278,11 +323,11 @@ class Cell:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / _CELL_PEAKS.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / _CELL_PEAKS.hbm_bytes_s
 
     @property
     def t_memory_analytic(self) -> float:
@@ -305,16 +350,16 @@ class Cell:
             # params spread by FSDP(data)×TP(model): the whole mesh shares one copy
             param_traffic = self.active_params * 24.0 / self.n_devices
             act_traffic = 4.0 * toks * 2.0 * d_model * n_layers
-            return (param_traffic + act_traffic) / HBM_BW
+            return (param_traffic + act_traffic) / _CELL_PEAKS.hbm_bytes_s
         if self.shape.startswith("prefill"):
             p_dev = 2.0 * self.active_params / 16  # bf16, TP-sharded; DP replicates
             act_traffic = 4.0 * toks * 2.0 * d_model * n_layers
-            return (p_dev + act_traffic) / HBM_BW
-        return max(self.arg_bytes, 1.0) / HBM_BW
+            return (p_dev + act_traffic) / _CELL_PEAKS.hbm_bytes_s
+        return max(self.arg_bytes, 1.0) / _CELL_PEAKS.hbm_bytes_s
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / LINK_BW
+        return self.collective_bytes / _CELL_PEAKS.link_bytes_s
 
     @property
     def dominant(self) -> str:
@@ -368,10 +413,10 @@ class Cell:
             # ideal = one read of the resident state; score against the
             # HLO-memory-based modeled time (conservative: the CPU
             # backend inflates HLO bytes — see §Roofline bytes-fidelity)
-            t_ideal = self.arg_bytes / HBM_BW
+            t_ideal = self.arg_bytes / _CELL_PEAKS.hbm_bytes_s
             t_model = max(self.t_compute, self.t_memory, self.t_collective)
         else:
-            t_ideal = self.model_flops / self.n_devices / PEAK_FLOPS
+            t_ideal = self.model_flops / self.n_devices / _CELL_PEAKS.flops
             t_model = self.t_overlapped
         return min(1.0, t_ideal / t_model)
 

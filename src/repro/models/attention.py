@@ -22,7 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.dist.sharding import active_mesh, kv_cache_layout, shard, shard_map
+from repro.dist.sharding import active_mesh, kv_cache_layout, shard
 from repro.models.layers import apply_rope, dense_init, matmul, softcap
 from repro.models.flags import scan_unroll_arg
 
@@ -366,7 +366,7 @@ def _flash_decode_sharded(qg, cache_k, cache_v, valid, cfg, dtype, mesh, layout)
         acc = jax.lax.psum(acc * r[..., None], ax)
         return acc / jnp.maximum(l, 1e-30)[..., None]
 
-    return shard_map(
+    return jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, v_spec),

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.sharding import active_mesh, active_rules, shard, shard_map
+from repro.dist.sharding import active_mesh, active_rules, shard
 from repro.models.layers import dense_init
 
 
@@ -184,7 +184,7 @@ def moe_apply(p, x, cfg, dtype, ep_axis: str = "model"):
             "wu": P(ep_axis, None, None),
             "wo": P(ep_axis, None, None),
         }
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             ep_block_small if small else ep_block,
             mesh=mesh,
             in_specs=(param_specs, x_spec),

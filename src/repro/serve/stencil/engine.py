@@ -33,10 +33,10 @@ per ``tune.space.slot_width_candidates``) and dispatches the whole pool
 as ONE ``shard_map`` over ``(slot, *spatial)`` per engine step.  Halo
 collectives bind the spatial axis names and vmap batches through them,
 so the pooled dispatch stays bitwise-equal to per-slot solo dispatches —
-the ``dist_worker`` harness asserts it.  When the sibling cannot compile
-(exotic backend, inventory too small) the bucket falls back to the solo
-loop, now with a single batched row-commit per step instead of a
-full-pool rewrite per slot.
+the ``dist_worker`` harness asserts it.  Only when the device inventory
+cannot hold one slot block of the spatial mesh (checked explicitly) does
+the bucket run the solo loop, with a single batched row-commit per step;
+a pooled compile or dispatch that fails raises.
 
 Buckets are *elastic*: an optional ``PoolSizer`` (``config.autoscale``)
 resizes capacities between steps from queue-depth/utilization EWMAs —
@@ -231,34 +231,22 @@ class StencilEngine:
                     pooled_fn = self._pooled_fn(group)
             else:
                 pooled_fn = self._pool_fn(group)
-            dispatched = False
             if pooled_fn is not None:
-                try:
-                    with _obs.span("dispatch:pooled", cat="serve",
-                                   bucket=bucket, live=len(live)):
-                        t0 = time.perf_counter()
-                        outs = pooled_fn(*group.state)
-                        outs = outs if isinstance(outs, tuple) else (outs,)
-                        jax.block_until_ready(outs)
-                except Exception:
-                    if not group.compiled.target.distributed:
-                        raise
-                    # the slot-axis sibling traced but cannot execute on
-                    # this inventory — remember and fall back to solo
-                    group.pooled = (group.capacity, None)
+                with _obs.span("dispatch:pooled", cat="serve",
+                               bucket=bucket, live=len(live)):
+                    t0 = time.perf_counter()
+                    outs = pooled_fn(*group.state)
+                    outs = outs if isinstance(outs, tuple) else (outs,)
+                    jax.block_until_ready(outs)
+                self.metrics.record_dispatch(bucket, time.perf_counter() - t0)
+                group.rotate(outs)
+                if len(live) >= 2:
+                    batched += 1
+                    self.metrics.record_bucket_dispatch(bucket, True)
                 else:
-                    self.metrics.record_dispatch(
-                        bucket, time.perf_counter() - t0
-                    )
-                    group.rotate(outs)
-                    dispatched = True
-                    if len(live) >= 2:
-                        batched += 1
-                        self.metrics.record_bucket_dispatch(bucket, True)
-                    else:
-                        solo += 1
-                        self.metrics.record_bucket_dispatch(bucket, False)
-            if not dispatched:
+                    solo += 1
+                    self.metrics.record_bucket_dispatch(bucket, False)
+            else:
                 # solo fallback: one shard_map call per live slot, rows
                 # buffered and committed in ONE batched write per buffer
                 rows = {}
@@ -419,24 +407,23 @@ class StencilEngine:
         slot width is the widest feasible for this inventory
         (``tune.space.slot_width_candidates``; width 1 still pools — the
         inner vmap batches within each spatial shard).  Memoized on the
-        group per pool width; ``None`` when the sibling cannot compile,
-        which routes the bucket to the solo fallback loop."""
-        if group.pooled is not None and group.pooled[0] == group.capacity:
-            compiled = group.pooled[1]
-            return None if compiled is None else compiled.step()
-        from repro.tune.space import slot_width_candidates
+        group per pool width; ``None`` only when the inventory cannot hold
+        one slot block of the spatial mesh, which routes the bucket to the
+        solo loop.  A sibling that fails to compile raises."""
+        if group.pooled is None or group.pooled[0] != group.capacity:
+            from repro.tune.space import slot_width_candidates
 
-        target = group.compiled.target
-        compiled = None
-        try:
-            width = slot_width_candidates(
-                len(jax.devices()), target.spatial_ranks, group.capacity
-            )[0]
-            pooled = api.pooled_target(target, slots=width)
-            compiled = api.compile(group.compiled.program, pooled)
-        except Exception:
+            target = group.compiled.target
+            n_devices = len(jax.devices())
             compiled = None
-        group.pooled = (group.capacity, compiled)
+            if n_devices >= target.spatial_ranks:
+                width = slot_width_candidates(
+                    n_devices, target.spatial_ranks, group.capacity
+                )[0]
+                pooled = api.pooled_target(target, slots=width)
+                compiled = api.compile(group.compiled.program, pooled)
+            group.pooled = (group.capacity, compiled)
+        compiled = group.pooled[1]
         return None if compiled is None else compiled.step()
 
     def _stream_frames(self, group: SlotPool, req: StencilRequest) -> None:

@@ -218,7 +218,9 @@ def target_from_dict(d: dict, devices: Optional[Sequence] = None):
         exchange_every=int(d.get("exchange_every", 1)),
         slot_axis=d.get("slot_axis"),
         fused_epoch=bool(d.get("fused_epoch", False)),
-        pallas_interpret=bool(d.get("pallas_interpret", True)),
+        # interpret mode is resolved for THIS device, never read back: an
+        # entry tuned on the CPU must not put a chip in interpret mode
+        # (the fingerprint check then turns such an entry into a miss)
         pallas_tile=tuple(tile) if tile else None,
         donate=bool(d.get("donate", False)),
         jit=bool(d.get("jit", True)),

@@ -51,6 +51,16 @@ class TuneResult:
     def target(self):
         return self.winner.target
 
+    @property
+    def dropped(self) -> list:
+        """Every candidate whose model or measurement raised, with the
+        error: ``[{"candidate": describe(), "error": "..."}]``."""
+        return [
+            {"candidate": c.describe(), "error": c.error}
+            for c in self.candidates
+            if c.error
+        ]
+
     def summary(self) -> list:
         if self.candidates:
             return [c.as_dict() for c in self.candidates]
@@ -112,10 +122,13 @@ def _group_representative(target):
     )
 
 
-def score_candidates(program, candidates: Sequence[Candidate]) -> None:
-    """Fill ``modeled_s`` in place via the shared roofline model.  A
-    group whose representative fails to compile poisons only that group
-    (score = inf, note carries the error)."""
+def score_candidates(
+    program, candidates: Sequence[Candidate], device_kind: str
+) -> None:
+    """Fill ``modeled_s`` in place via the shared roofline model of
+    ``device_kind`` (a key of ``launch.roofline.PEAKS``).  A group whose
+    representative fails to compile poisons only that group (score =
+    inf, ``error`` carries the exception)."""
     from repro import api
 
     terms_of: dict = {}
@@ -124,14 +137,16 @@ def score_candidates(program, candidates: Sequence[Candidate]) -> None:
         key = rep.fingerprint
         if key not in terms_of:
             try:
-                terms_of[key] = api.compile(program, rep).cost()
-            except Exception as e:  # noqa: BLE001 - score, don't crash
+                terms_of[key] = api.compile(program, rep).cost(
+                    device_kind=device_kind
+                )
+            except Exception as e:  # noqa: BLE001 - recorded in the result
                 terms_of[key] = e
         terms = terms_of[key]
         if isinstance(terms, Exception):
             cand.modeled_s = float("inf")
             cand.pruned = True
-            cand.note = f"model failed: {terms}"
+            cand.error = f"model: {type(terms).__name__}: {terms}"
             continue
         if not cand.target.distributed:
             # a single-device artifact's exchange ops lower to local
@@ -190,6 +205,7 @@ def tune(
     overlap: Sequence[bool] = (False, True),
     fused_epoch: Sequence[bool] = (False, True),
     verbose: bool = False,
+    device_kind: Optional[str] = None,
 ) -> TuneResult:
     """Search the ``Target`` space for ``program`` on this machine.
 
@@ -204,10 +220,18 @@ def tune(
     validates here.  It counts as a ``transfer_hit`` (never a ``hit``),
     the winner's ``origin`` is ``"transfer"``, and nothing is stored
     under this machine's key — run a measured search to earn that entry.
+
+    ``device_kind`` names the chip whose peaks the model uses; by default
+    the kind of ``devices[0]``.  A search on the CPU that models a TPU
+    names it (``launch.roofline.V5E``): the CPU has no entry.
     """
     import jax
 
+    from repro.launch.roofline import device_peaks
+
     devices = list(devices) if devices is not None else jax.devices()
+    device_kind = device_kind or devices[0].device_kind
+    device_peaks(device_kind)  # an unknown kind fails here, not per group
     n_ranks = len(devices) if ranks is None else int(ranks)
     hardware = tune_cache.hardware_signature(devices[:n_ranks] or devices)
     digest = tune_cache.options_digest(
@@ -216,6 +240,7 @@ def tune(
         exchange_every=sorted(int(k) for k in exchange_every),
         overlap=sorted(bool(o) for o in overlap),
         fused_epoch=sorted(bool(f) for f in fused_epoch),
+        device_kind=device_kind,
         keep_quantile=float(keep_quantile),
         min_keep=int(min_keep),
         # measurement protocol changes the winner's fidelity: a
@@ -250,12 +275,12 @@ def tune(
         overlap=overlap,
         fused_epoch=fused_epoch,
     )
-    score_candidates(program, candidates)
+    score_candidates(program, candidates, device_kind)
     survivors = prune_candidates(
         candidates, keep_quantile=keep_quantile, min_keep=min_keep
     )
     if not survivors:
-        notes = "; ".join(sorted({c.note for c in candidates if c.note}))
+        notes = "; ".join(sorted({c.error for c in candidates if c.error}))
         raise RuntimeError(
             f"tune: no candidate for program {program.fingerprint} could "
             "be modeled" + (f" ({notes})" if notes else "")
@@ -330,8 +355,8 @@ def _measure_survivors(
                     compiled, steps=steps, trials=trials, warmup=warmup
                 )
             )
-        except Exception as e:  # noqa: BLE001 - rank, don't crash
-            cand.note = f"measurement failed: {e}"
+        except Exception as e:  # noqa: BLE001 - recorded in the result
+            cand.error = f"measurement: {type(e).__name__}: {e}"
             times.append(None)
         if verbose:  # pragma: no cover - CLI chatter
             print(f"  measured {cand.describe()}: {_fmt(times[-1])}/step")

@@ -43,6 +43,9 @@ class Candidate:
     measured_s: Optional[float] = None
     pruned: bool = False
     note: str = ""
+    # why the candidate was dropped: the error its model or measurement
+    # raised ("" when it was scored)
+    error: str = ""
 
     @property
     def fingerprint(self) -> str:
@@ -78,6 +81,7 @@ class Candidate:
             "measured_s": self.measured_s,
             "pruned": self.pruned,
             "note": self.note,
+            "error": self.error,
         }
 
 
@@ -199,14 +203,18 @@ def exchange_every_candidates(
 def pallas_tile_candidates(program, strategy) -> list:
     """Tiles derived from the local shard shape: ``None`` (auto), the
     whole shard, and the shard with its leading extent halved — each
-    kept only when it divides the shard."""
+    kept only when it divides the shard and obeys the kernels' (8, 128)
+    tile rule."""
+    from repro.kernels.stencil_apply import is_legal_tile
+
     local = _local_shape(program, strategy)
     out: list = [None]
     if not local or any(n <= 0 for n in local):
         return out
     out.append(tuple(local))
-    if local[0] % 2 == 0 and local[0] >= 16:
-        out.append((local[0] // 2,) + tuple(local[1:]))
+    half = (local[0] // 2,) + tuple(local[1:])
+    if local[0] % 2 == 0 and local[0] >= 16 and is_legal_tile(local, half):
+        out.append(half)
     # dedupe, preserve order
     seen: set = set()
     uniq = []

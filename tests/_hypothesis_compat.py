@@ -8,7 +8,7 @@ of seeded samples (enough to keep the sweeps meaningful, not a full
 shrinking engine).
 """
 try:  # pragma: no cover - exercised only where hypothesis exists
-    from hypothesis import given, settings, strategies  # noqa: F401
+    from hypothesis import example, given, settings, strategies  # noqa: F401
 
     HAVE_HYPOTHESIS = True
 except ImportError:  # deterministic fallback
@@ -54,11 +54,22 @@ except ImportError:  # deterministic fallback
 
         return deco
 
+    def example(**kwargs):
+        """Pin one explicit case; ``given`` runs pinned cases first."""
+
+        def deco(fn):
+            fn._examples = [kwargs] + list(getattr(fn, "_examples", []))
+            return fn
+
+        return deco
+
     def given(**strategy_kwargs):
         def deco(inner):
             # no functools.wraps: pytest must see the zero-arg signature,
             # not the property's drawn parameters
             def runner():
+                for pinned in getattr(inner, "_examples", []):
+                    inner(**pinned)
                 n = getattr(runner, "_max_examples", 10)
                 rng = random.Random(0)
                 for _ in range(n):

@@ -12,6 +12,8 @@ programs.  Exit 0 = all assertions passed.
 import os
 import sys
 
+# a CPU-only tool: virtual CPU devices, never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
 )
@@ -29,7 +31,6 @@ from repro.dist.context_parallel import (  # noqa: E402
     seq_halo_exchange,
     sliding_window_attention_cp,
 )
-from repro.dist.sharding import shard_map  # noqa: E402
 
 
 def _mesh(n, axis="seq"):
@@ -63,7 +64,7 @@ def scenario_exchange(boundary):
         return seq_halo_exchange(x_loc, spec, distributed=True)
 
     got = jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh, in_specs=P(None, "seq"),
             out_specs=P(None, "seq"), check_vma=False,
         )

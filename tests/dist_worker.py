@@ -13,6 +13,8 @@ swap machinery is correct by test, not by construction.
 import os
 import sys
 
+# a CPU-only tool: virtual CPU devices, never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
 )
@@ -327,6 +329,7 @@ def scenario_tune_4rank():
     kwargs = dict(
         ranks=4, measure=True, steps=4, trials=2, warmup=1,
         backends=("jnp",), exchange_every=(1, 2, 4), overlap=(False, True),
+        device_kind="TPU v5 lite",  # the CPU models a v5e
     )
     res = tune(prog, **kwargs)
     assert not res.from_cache and cache_stats().stores == 1
@@ -508,6 +511,7 @@ def scenario_tune_transfer():
     kwargs = dict(
         measure=False, backends=("jnp",), exchange_every=(1, 2),
         overlap=(False,), fused_epoch=(False,),
+        device_kind="TPU v5 lite",  # the CPU models a v5e
     )
     reset_cache_stats()  # counters are process-wide; earlier scenarios tune
     res2 = tune(prog, ranks=2, **kwargs)

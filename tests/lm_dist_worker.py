@@ -7,6 +7,8 @@ specs equals single-device execution.
 import os
 import sys
 
+# a CPU-only tool: virtual CPU devices, never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
 )

@@ -4,8 +4,8 @@ Covers Target validation (construction-time rejection), IR fingerprint
 stability, the process-wide fingerprint-keyed compile cache (hit/miss
 counters + pass pipeline not re-running), buffer donation, and the
 acceptance property that all three frontends compile through
-``repro.api.compile`` with one shared Target — with the deprecated
-``StencilComputation`` shim staying bitwise-equivalent.
+``repro.api.compile`` with one shared Target, and the persistent
+compile-cache location.
 """
 import numpy as np
 import pytest
@@ -17,6 +17,7 @@ from repro.core import ir
 from repro.core.passes import PassManager
 from repro.core.passes.decompose import SlicingStrategy, make_strategy_1d
 from repro.frontends.oec_like import ProgramBuilder
+from repro.launch.roofline import V5E
 
 
 def _jacobi_prog(shape=(16, 16), boundary="periodic", name="jacobi"):
@@ -221,8 +222,8 @@ def test_top_level_reexport():
 
 
 def test_buffers_are_donated():
-    """The old StencilComputation computed donate_argnums but never passed
-    them to jax.jit; a donate=True Target must actually donate."""
+    """A donate=True Target passes its donate_argnums to jax.jit and the
+    buffers are actually donated."""
     import jax
     import jax.numpy as jnp
 
@@ -293,24 +294,6 @@ def test_three_frontends_share_one_target():
     np.testing.assert_array_equal(r_oec, r_dev)
 
 
-def test_stencil_computation_shim_is_bitwise_equivalent():
-    from repro.core.program import CompileOptions, StencilComputation
-
-    prog = _jacobi_prog(name="shim_probe")
-    rng = np.random.default_rng(9)
-    u0 = rng.standard_normal((16, 16)).astype(np.float32)
-
-    new = api.compile(prog, Target())(u0, np.zeros_like(u0))
-    with pytest.deprecated_call(match="StencilComputation"):
-        comp = StencilComputation(_jacobi_prog(name="shim_probe").func,
-                                  boundary="periodic")
-    old = comp.compile(options=CompileOptions())(u0, np.zeros_like(u0))
-    np.testing.assert_array_equal(np.asarray(new[0]), np.asarray(old[0]))
-    # the shim went through the same cache + pipeline
-    assert comp.last_pipeline == Target().pipeline_spec()
-    assert [n for n, _ in comp.last_timings] == comp.last_pipeline.split(",")
-
-
 # -------------------------------------------------------------------------
 # artifact surface: local_ir / pipeline_report / specs / lower / cost
 # -------------------------------------------------------------------------
@@ -330,7 +313,7 @@ def test_artifact_inspection_surface():
     # partition specs: one per field arg (trivial strategy → all None)
     assert len(step.partition_specs) == 2
     # AOT lower + roofline cost
-    cost = step.cost()
+    cost = step.cost(device_kind=V5E)
     assert cost.flops > 0
     assert cost.dominant in ("compute", "memory", "collective")
     assert cost.t_serial >= cost.t_overlapped
@@ -364,3 +347,37 @@ def test_lower_ir_cache_for_generated_exchanges():
     f2 = _comm_func.__wrapped__((2, 8, 4), spec)
     assert f2 is f1
     assert api.cache_stats().hits == stats0["hits"] + 1
+
+
+# -------------------------------------------------------------------------
+# persistent compilation cache location (repro.compile_cache)
+# -------------------------------------------------------------------------
+
+
+def test_compile_cache_uses_env_dir_and_sets_nothing(monkeypatch, tmp_path):
+    import jax
+
+    from repro import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_checkout_path(monkeypatch):
+    import pathlib
+
+    import jax
+
+    from repro import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        first, second = compile_cache.enable(), compile_cache.enable()
+        checkout = pathlib.Path(repro.__file__).resolve().parents[2]
+        assert first == second == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -123,18 +123,19 @@ def test_box_stencil_3d(shape, seed):
 # -------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("tile", [(8, 64), (16, 32), (32, 16)])
+@pytest.mark.parametrize("tile", [(8, 256), (16, 128), (32, 128)])
 def test_explicit_tiles_agree(tile):
-    """Different VMEM tilings must not change results (overlap windows)."""
-    x = _rand((64 + 2, 64 + 2), seed=11)
+    """Different VMEM tilings must not change results (overlap windows);
+    every tile here obeys the (8, 128) rule."""
+    x = _rand((64 + 2, 256 + 2), seed=11)
     star = laplacian_star(2, 2)
     from repro.kernels.stencil_apply import run_apply_pallas
     from repro.kernels.ops import _star_apply_ir
 
-    apply_op, ob = _star_apply_ir(star, (64, 64), (1, 1))
+    apply_op, ob = _star_apply_ir(star, (64, 256), (1, 1))
     from repro.core.dialects import stencil
 
-    rb = stencil.Bounds.from_shape((64, 64))
+    rb = stencil.Bounds.from_shape((64, 256))
     (got,) = run_apply_pallas(
         apply_op, [jnp.asarray(x)], [ob.lb], rb, tile=tile, interpret=True
     )
@@ -143,14 +144,21 @@ def test_explicit_tiles_agree(tile):
 
 
 def test_choose_tile_respects_budget_and_divisibility():
+    """The chosen tile divides the shape, obeys the (8, 128) rule, and its
+    whole-kernel VMEM (windows + outputs double-buffered + temporaries)
+    fits the budget; a budget nothing fits raises naming the sizes."""
+    from repro.kernels import KernelPlanError
+    from repro.kernels.stencil_apply import is_legal_tile, vmem_bytes
+
     shape = (512, 1024)
-    spans = [((-4, -4), (4, 4))]
-    tile = choose_tile(shape, spans, budget=256 * 1024)
-    assert all(s % t == 0 for s, t in zip(shape, tile))
-    numel = (tile[0] + 8) * (tile[1] + 8)
-    assert numel * 4 <= 256 * 1024
-    # minor dim kept whole (lane alignment) when possible
-    assert tile[1] == 1024 or tile[1] % 128 == 0
+    spans = [(8, 8)]
+    budget = 2 * 1024 * 1024
+    tile = choose_tile(shape, spans, budget=budget)
+    assert is_legal_tile(shape, tile)
+    assert tile[0] % 8 == 0 and tile[1] % 128 == 0
+    assert vmem_bytes(tile, spans, 1) <= budget
+    with pytest.raises(KernelPlanError, match="VMEM budget"):
+        choose_tile(shape, spans, budget=16 * 1024)
 
 
 def test_kernel_backend_equals_jnp_backend_end_to_end():

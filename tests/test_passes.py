@@ -20,7 +20,8 @@ from repro.core.passes.decompose import (
     make_strategy_2d,
     make_strategy_3d,
 )
-from repro.core.program import StencilComputation
+from repro import api
+from repro.api import Program, Target
 from repro.frontends.oec_like import ProgramBuilder
 
 
@@ -172,20 +173,15 @@ def test_swap_count_after_elimination():
 
 
 def test_elimination_preserves_results():
-    func = _two_apply_prog((16, 16))
-    comp_raw = StencilComputation(_two_apply_prog((16, 16)), boundary="periodic")
+    prog = Program(_two_apply_prog((16, 16)), boundary="periodic")
 
     rng = np.random.default_rng(3)
     u0 = rng.standard_normal((16, 16)).astype(np.float32)
     out0 = np.zeros((16, 16), np.float32)
 
-    from repro.core.program import CompileOptions
-
     # single-rank periodic reference
-    ref = comp_raw.compile(options=CompileOptions(fuse=False, cse=False))(u0, out0)
-    got = StencilComputation(func, boundary="periodic").compile(
-        options=CompileOptions(fuse=True, cse=True)
-    )(u0, out0)
+    ref = api.compile(prog, Target(fuse=False, cse=False))(u0, out0)
+    got = api.compile(prog, Target(fuse=True, cse=True))(u0, out0)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got), rtol=1e-6)
 
 
@@ -221,14 +217,10 @@ def test_fusion_preserves_semantics():
     rng = np.random.default_rng(1)
     u0 = rng.standard_normal((24, 24)).astype(np.float32)
     out0 = np.zeros_like(u0)
-    from repro.core.program import CompileOptions
+    prog = Program(_three_stencil_prog(), boundary="periodic")
 
-    r_unfused = StencilComputation(_three_stencil_prog(), boundary="periodic").compile(
-        options=CompileOptions(fuse=False, cse=False)
-    )(u0, out0)
-    r_fused = StencilComputation(_three_stencil_prog(), boundary="periodic").compile(
-        options=CompileOptions(fuse=True, cse=False)
-    )(u0, out0)
+    r_unfused = api.compile(prog, Target(fuse=False, cse=False))(u0, out0)
+    r_fused = api.compile(prog, Target(fuse=True, cse=False))(u0, out0)
     np.testing.assert_allclose(np.asarray(r_unfused), np.asarray(r_fused), rtol=1e-6)
 
 
@@ -292,16 +284,11 @@ def test_cse_dedupes_accesses():
     ids=["overlap", "diagonal", "pipeline"],
 )
 def test_beyond_paper_rewrites_preserve_semantics(kw):
-    from repro.core.program import CompileOptions
-
     rng = np.random.default_rng(7)
     u0 = rng.standard_normal((16, 16)).astype(np.float32)
     out0 = np.zeros_like(u0)
+    prog = Program(_jacobi_prog((16, 16)), boundary="periodic")
 
-    base = StencilComputation(_jacobi_prog((16, 16)), boundary="periodic").compile(
-        options=CompileOptions()
-    )(u0, out0)
-    opt_result = StencilComputation(_jacobi_prog((16, 16)), boundary="periodic").compile(
-        options=CompileOptions(**kw)
-    )(u0, out0)
+    base = api.compile(prog, Target())(u0, out0)
+    opt_result = api.compile(prog, Target(**kw))(u0, out0)
     np.testing.assert_allclose(np.asarray(base), np.asarray(opt_result), rtol=1e-6)

@@ -21,7 +21,8 @@ from repro.core.passes import (
     use_diagonal_exchanges,
 )
 from repro.core.passes.decompose import make_strategy_2d
-from repro.core.program import CompileOptions, StencilComputation, default_pipeline
+from repro import api
+from repro.api import Program, Target
 from repro.frontends.oec_like import ProgramBuilder
 
 
@@ -97,18 +98,24 @@ def test_grid_spec_with_axis_names():
 
 
 def test_default_pipeline_always_lowers_comm():
-    assert default_pipeline(CompileOptions()).endswith("lower-comm")
-    spec = default_pipeline(CompileOptions(overlap=True, diagonal=True))
+    assert Target().pipeline_spec().endswith("lower-comm")
+    spec = Target(overlap=True, diagonal=True).pipeline_spec()
     assert "diagonal" in spec and "overlap" in spec
     assert spec.index("diagonal") < spec.index("overlap")
 
 
+def _prepare_local(spec, strategy=None):
+    # a decomposed strategy without a mesh: IR-only inspection
+    ctx = PipelineContext(strategy=strategy, boundary="periodic")
+    return run_pipeline(_jacobi_prog(), spec, ctx)
+
+
 def test_pipeline_timings_recorded():
-    comp = StencilComputation(_jacobi_prog(), boundary="periodic")
-    comp.prepare_local(make_strategy_2d((2, 2)), CompileOptions(overlap=True))
-    names = [n for n, _ in comp.last_timings]
-    assert names == comp.last_pipeline.split(",")
-    assert all(sec >= 0 for _, sec in comp.last_timings)
+    spec = Target(overlap=True).pipeline_spec()
+    _, timings = _prepare_local(spec, make_strategy_2d((2, 2)))
+    names = [n for n, _ in timings]
+    assert names == spec.split(",")
+    assert all(sec >= 0 for _, sec in timings)
 
 
 # -------------------------------------------------------------------------
@@ -124,10 +131,11 @@ def test_lower_dmp_to_comm_preserves_sym_name():
 
 
 def test_prepare_local_emits_comm_only():
-    comp = StencilComputation(_jacobi_prog(), boundary="periodic")
-    for opts in (CompileOptions(), CompileOptions(overlap=True),
-                 CompileOptions(diagonal=True, overlap=True)):
-        local = comp.prepare_local(make_strategy_2d((2, 2)), opts)
+    for target in (Target(), Target(overlap=True),
+                   Target(diagonal=True, overlap=True)):
+        local, _ = _prepare_local(
+            target.pipeline_spec(), make_strategy_2d((2, 2))
+        )
         assert not any(isinstance(op, dmp.SwapOp) for op in local.body.ops)
         assert any(isinstance(op, comm.ExchangeStartOp) for op in local.body.ops)
 
@@ -137,15 +145,6 @@ def test_interpreter_rejects_dmp_swap():
     interp = StencilInterpreter(local, axis_sizes={}, distributed=False)
     with pytest.raises(NotImplementedError, match="dmp.swap"):
         interp(np.zeros((16, 16), np.float32), np.zeros((16, 16), np.float32))
-
-
-def test_comm_dialect_option_is_deprecated_noop():
-    comp = StencilComputation(_jacobi_prog(), boundary="periodic")
-    a = comp.prepare_local(make_strategy_2d((2, 2)), CompileOptions())
-    with pytest.deprecated_call(match="comm_dialect"):
-        opts = CompileOptions(comm_dialect=True)
-    b = comp.prepare_local(make_strategy_2d((2, 2)), opts)
-    assert [op.name for op in a.body.ops] == [op.name for op in b.body.ops]
 
 
 def test_permute_pairs_shared_helper():
@@ -423,12 +422,10 @@ def test_pipeline_overlap_semantics_single_device():
     rng = np.random.default_rng(11)
     u0 = rng.standard_normal((24, 24)).astype(np.float32)
     out0 = np.zeros_like(u0)
-    base = StencilComputation(_box_prog((24, 24)), boundary="periodic").compile(
-        options=CompileOptions()
-    )(u0, out0)
-    via_spec = StencilComputation(_box_prog((24, 24)), boundary="periodic").compile(
-        options=CompileOptions(
-            pipeline="fuse,cse,dce,decompose,swap-elim,overlap,lower-comm"
-        )
+    prog = Program(_box_prog((24, 24)), boundary="periodic")
+    base = api.compile(prog, Target())(u0, out0)
+    via_spec = api.compile(
+        prog,
+        Target(pipeline="fuse,cse,dce,decompose,swap-elim,overlap,lower-comm"),
     )(u0, out0)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(via_spec))

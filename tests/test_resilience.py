@@ -17,6 +17,7 @@ from repro import api
 from repro.api import Target
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.frontends.oec_like import ProgramBuilder
+from repro.launch.roofline import V5E
 from repro.resilience import (
     FaultPlan,
     ResilientLoop,
@@ -381,7 +382,7 @@ def test_submit_start_step_is_validated():
 def _tune_kwargs():
     return dict(
         measure=False, backends=("jnp",), exchange_every=(1, 2),
-        overlap=(False,), fused_epoch=(False,),
+        overlap=(False,), fused_epoch=(False,), device_kind=V5E,
     )
 
 

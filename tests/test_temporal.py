@@ -295,10 +295,10 @@ def test_time_loop_iterates_in_epochs():
 
 
 def test_cost_carries_tiling_terms_and_recommends():
-    from repro.launch.roofline import RooflineTerms
+    from repro.launch.roofline import V5E, RooflineTerms
 
     prog = _jacobi(name="cost_probe")
-    terms = api.compile(prog, Target()).cost()
+    terms = api.compile(prog, Target()).cost(device_kind=V5E)
     assert terms.exchange_every == 1
     assert terms.messages_per_epoch == 4  # 4 faces on the trivial 2-d grid
     assert terms.step_halo == (1, 1)
@@ -312,14 +312,14 @@ def test_cost_carries_tiling_terms_and_recommends():
     lat = RooflineTerms(
         flops=1e6, bytes_accessed=1e5, collectives={},
         exchange_every=1, messages_per_epoch=8,
-        step_halo=(1, 1), local_shape=(32, 32),
+        step_halo=(1, 1), local_shape=(32, 32), device_kind=V5E,
     )
     assert lat.recommend_exchange_every(max_k=8) > 1
     # compute-dominated regime (huge shard FLOPs): stay at k=1
     comp = RooflineTerms(
         flops=1e13, bytes_accessed=1e5, collectives={},
         exchange_every=1, messages_per_epoch=2,
-        step_halo=(4, 4), local_shape=(8, 8),
+        step_halo=(4, 4), local_shape=(8, 8), device_kind=V5E,
     )
     assert comp.recommend_exchange_every(max_k=8) == 1
     # infeasible depths (deep halo > shard) are never recommended

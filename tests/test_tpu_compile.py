@@ -1,0 +1,112 @@
+"""Compile rehearsals for a TPU v5e: the main path's kernels at the real
+grid sizes, compiled for a described ``v5e:2x2`` topology with no chip
+attached.  Nothing runs; what the chip's compiler refuses (a block that
+breaks the (8, 128) rule, VMEM over its limit, a program over HBM)
+fails here first.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may hold the TPU library, and every
+test worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro import api
+from repro.api import Target
+from repro.core.passes.decompose import make_strategy_2d
+from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _heat(n: int, order: int):
+    grid = Grid(shape=(n, n))
+    u = TimeFunction(name="u", grid=grid, space_order=order)
+    return Operator(Eq(u.dt, u.laplace), dt=0.1, boundary="zero").program
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+
+
+def _compile_one_chip(program, target, one_chip, n: int):
+    step = api.compile(program, target).step()
+    x = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    return jax.jit(step).lower(x).compile()
+
+
+def test_jnp_step_16384_so8(one_chip):
+    compiled = _compile_one_chip(_heat(16384, 8), Target(), one_chip, 16384)
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("n,order", [(2048, 2), (16384, 8)])
+@pytest.mark.parametrize(
+    "knobs",
+    [
+        {},
+        {"exchange_every": 4},
+        {"exchange_every": 4, "fused_epoch": True},
+    ],
+    ids=["per-apply", "per-apply-k4", "fused-epoch-k4"],
+)
+def test_pallas_step(one_chip, n, order, knobs):
+    """Per-apply kernels (one step, and the grown applies of an unfused
+    k=4 epoch) and the fused-epoch megakernel compile natively."""
+    target = Target(backend="pallas", pallas_interpret=False, **knobs)
+    compiled = _compile_one_chip(_heat(n, order), target, one_chip, n)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_whole_shard_epoch_step(one_chip):
+    """Wave's carried escape keeps a fused epoch untiled: at 1024² its
+    whole shard fits the VMEM budget and compiles as one block."""
+    w = TimeFunction(name="w", grid=Grid(shape=(1024, 1024)), space_order=2,
+                     time_order=2)
+    prog = Operator(Eq(w.dt2, w.laplace), dt=1e-3, boundary="zero").program
+    target = Target(backend="pallas", pallas_interpret=False, exchange_every=2,
+                    fused_epoch=True)
+    step = api.compile(prog, target).step()
+    x = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(step).lower(x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_2x2_jnp_step_k4(topo):
+    """The decomposed path on the 2x2 host: halo exchanges become
+    collective-permutes, and each device's share fits its HBM."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("x", "y"))
+    target = Target(mesh=mesh, strategy=make_strategy_2d((2, 2)), exchange_every=4)
+    step = api.compile(_heat(16384, 8), target).step()
+    x = jax.ShapeDtypeStruct(
+        (16384, 16384), jnp.float32, sharding=NamedSharding(mesh, P("x", "y"))
+    )
+    compiled = jax.jit(step).lower(x).compile()
+    assert "collective-permute" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES

@@ -16,7 +16,7 @@ import pytest
 from repro import api
 from repro.api import Target, TargetError
 from repro.frontends.oec_like import ProgramBuilder
-from repro.launch.roofline import RooflineTerms
+from repro.launch.roofline import V5E, RooflineTerms
 from repro.tune import (
     Candidate,
     cache_stats,
@@ -161,7 +161,7 @@ def test_enumerate_interpret_follows_inventory():
 
 def test_tuned_cost_model_only_winner_and_cache(tune_dir):
     prog = _jacobi_prog(name="tune_cost_only")
-    res = tune(prog, measure=False)
+    res = tune(prog, measure=False, device_kind=V5E)
     assert not res.from_cache
     assert cache_stats().misses == 1 and cache_stats().stores == 1
 
@@ -177,26 +177,26 @@ def test_tuned_cost_model_only_winner_and_cache(tune_dir):
     ), [(c.describe(), c.modeled_s) for c in unpruned]
 
     # second call: persistent-cache hit with the identical winner
-    res2 = tune(prog, measure=False)
+    res2 = tune(prog, measure=False, device_kind=V5E)
     assert res2.from_cache
     assert cache_stats().hits == 1
     assert res2.target.fingerprint == res.target.fingerprint
     assert os.path.exists(res2.cache_path)
 
     # Target.tuned surfaces the same winner (third call, second hit)
-    t = Target.tuned(prog, measure=False)
+    t = Target.tuned(prog, measure=False, device_kind=V5E)
     assert t.fingerprint == res.target.fingerprint
     assert cache_stats().hits == 2
 
 
 def test_compile_tune_kwarg(tune_dir):
     prog = _jacobi_prog(name="tune_compile_kwarg")
-    step = api.compile(prog, tune={"measure": False})
+    step = api.compile(prog, tune={"measure": False, "device_kind": V5E})
     assert isinstance(step, api.CompiledStencil)
     with pytest.raises(ValueError, match="not both"):
-        api.compile(prog, Target(), tune={"measure": False})
+        api.compile(prog, Target(), tune={"measure": False, "device_kind": V5E})
     # tuned target round-trips through the compile cache
-    again = api.compile(prog, tune={"measure": False})
+    again = api.compile(prog, tune={"measure": False, "device_kind": V5E})
     assert again is step
 
 
@@ -204,7 +204,7 @@ def test_tune_measure_single_device(tune_dir):
     prog = _jacobi_prog((16, 16), name="tune_measured")
     res = tune(
         prog, measure=True, steps=4, trials=2, warmup=1,
-        backends=("jnp",), exchange_every=(1, 2),
+        backends=("jnp",), exchange_every=(1, 2), device_kind=V5E,
     )
     measured = [c for c in res.candidates if c.measured_s is not None]
     assert measured and res.winner in measured
@@ -223,7 +223,7 @@ def test_single_device_model_has_no_phantom_latency(tune_dir):
     # latency amortization that cannot happen; the modeled winner on one
     # device keeps one exchange per step
     prog = _jacobi_prog(name="tune_no_phantom")
-    res = tune(prog, ranks=1, measure=False)
+    res = tune(prog, ranks=1, measure=False, device_kind=V5E)
     assert res.target.exchange_every == 1, res.winner.describe()
 
 
@@ -235,14 +235,15 @@ def test_tune_raises_informatively_when_nothing_models(tune_dir, monkeypatch):
 
     monkeypatch.setattr(api, "compile", boom)
     with pytest.raises(RuntimeError, match="no candidate .* could be modeled"):
-        tune(prog, measure=False, cache=False)
+        tune(prog, measure=False, cache=False, device_kind=V5E)
 
 
 def test_measurement_protocol_changes_cache_key(tune_dir):
     # steps/trials/warmup are part of the options digest: a
     # higher-fidelity search must not read back a low-fidelity entry
     prog = _jacobi_prog((16, 16), name="tune_protocol")
-    kw = dict(measure=True, backends=("jnp",), exchange_every=(1,))
+    kw = dict(measure=True, backends=("jnp",), exchange_every=(1,),
+              device_kind=V5E)
     r1 = tune(prog, steps=2, trials=1, warmup=1, **kw)
     r2 = tune(prog, steps=4, trials=2, warmup=1, **kw)
     assert r1.cache_key != r2.cache_key
@@ -251,7 +252,7 @@ def test_measurement_protocol_changes_cache_key(tune_dir):
 
 def test_tune_result_table_prints(tune_dir):
     prog = _jacobi_prog(name="tune_table")
-    res = tune(prog, measure=False)
+    res = tune(prog, measure=False, device_kind=V5E)
     text = res.table(top=5)
     assert "candidate" in text and "modeled/step" in text
     assert "baseline" in res.table()
@@ -314,7 +315,7 @@ def test_stale_cache_entry_for_other_program_misses(tune_dir):
     # an entry whose winner no longer validates for the program reads as
     # a miss (fresh search), never as a wrong answer
     prog = _jacobi_prog(name="tune_stale")
-    res = tune(prog, measure=False)
+    res = tune(prog, measure=False, device_kind=V5E)
     with open(res.cache_path) as f:
         entry = json.load(f)
     entry["winner"]["strategy"] = {"grid": [5], "axes": ["x"], "dims": [0]}
@@ -322,7 +323,7 @@ def test_stale_cache_entry_for_other_program_misses(tune_dir):
     with open(res.cache_path, "w") as f:
         json.dump(entry, f)
     reset_cache_stats()
-    res2 = tune(prog, measure=False)
+    res2 = tune(prog, measure=False, device_kind=V5E)
     assert not res2.from_cache  # fingerprint/validation rejected the entry
     # the rejected load is counted as a miss, not a hit: the search ran
     assert cache_stats().hits == 0 and cache_stats().misses == 1, (
@@ -339,7 +340,7 @@ def _terms(**kw):
     base = dict(
         flops=1e6, bytes_accessed=1e5, collectives={},
         exchange_every=1, messages_per_epoch=8,
-        step_halo=(1, 1), local_shape=(64, 64),
+        step_halo=(1, 1), local_shape=(64, 64), device_kind=V5E,
     )
     base.update(kw)
     return RooflineTerms(**base)
@@ -493,3 +494,49 @@ def test_enumerate_pool_candidates_single_device():
     fps = [c.fingerprint for c in cands]
     assert len(fps) == len(set(fps))
     assert Target().fingerprint not in fps
+
+
+# -------------------------------------------------------------------------
+# dropped candidates and the peaks table
+# -------------------------------------------------------------------------
+
+
+def test_tune_records_dropped_candidates(tune_dir, monkeypatch):
+    """A candidate whose measurement raises is dropped, and the result
+    names it with its error instead of hiding it."""
+    from repro.tune import search
+
+    real = search.tune_measure.measure_compiled
+
+    def pallas_fails(compiled, **kw):
+        if compiled.target.backend == "pallas":
+            raise RuntimeError("kernel exploded")
+        return real(compiled, **kw)
+
+    monkeypatch.setattr(search.tune_measure, "measure_compiled", pallas_fails)
+    prog = _jacobi_prog((16, 16), name="tune_dropped")
+    res = tune(
+        prog, measure=True, steps=2, trials=1, warmup=1, cache=False,
+        keep_quantile=1.0, exchange_every=(1,), fused_epoch=(False,),
+        overlap=(False,), device_kind=V5E,
+    )
+    assert res.dropped
+    for d in res.dropped:
+        assert "backend=pallas" in d["candidate"]
+        assert d["error"] == "measurement: RuntimeError: kernel exploded"
+    assert res.target.backend == "jnp"
+
+
+def test_device_peaks_keyed_by_kind():
+    from repro.launch.roofline import PEAKS, device_peaks
+
+    v5e = device_peaks(V5E)
+    assert (v5e.flops, v5e.hbm_bytes_s) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source and set(PEAKS) == {V5E}
+    with pytest.raises(ValueError, match="'cpu'"):
+        device_peaks("cpu")
+    # structural terms need no device; seconds do
+    terms = _terms(device_kind=None)
+    assert terms.feasible_exchange_every(2)
+    with pytest.raises(ValueError, match="None"):
+        terms.t_memory
