@@ -72,53 +72,12 @@ def gpts(shape: tuple, seconds: float, timesteps: int = 1) -> float:
 
 def save_record(name: str, record: dict) -> None:
     """Write ``results/bench/<name>.json``, stamped with a provenance
-    block.  When span tracing is live (``repro.obs``), the collected
-    trace is exported next to the record as ``<name>.trace.json``
-    (Chrome/Perfetto format) and the record's provenance carries its
-    path — a benchmark number always links back to the spans behind it."""
+    block."""
     os.makedirs(RESULTS_DIR, exist_ok=True)
     record = dict(record)
-    prov = provenance_block()
-    if _obs_enabled():
-        from repro import obs
-
-        if obs.spans():
-            trace_path = os.path.join(RESULTS_DIR, f"{name}.trace.json")
-            obs.write_chrome(trace_path)
-            prov["trace"] = os.path.relpath(trace_path, RESULTS_DIR)
-    record["provenance"] = prov
+    record["provenance"] = provenance_block()
     with open(os.path.join(RESULTS_DIR, f"{name}.json"), "w") as f:
         json.dump(record, f, indent=1)
-
-
-def measure_drift(compiled, state, n_steps: int, **kwargs) -> dict:
-    """Run ``n_steps`` of ``compiled`` under span tracing and compare the
-    measured per-step epoch time against the roofline model
-    (``compiled.cost().step_time(k)``) — the model-vs-measured error and
-    achieved comm/compute overlap every results record should carry.
-
-    Restores the tracer's prior enabled/collected state, so calling this
-    inside an otherwise-untraced benchmark leaves timing unperturbed.
-    """
-    from repro import obs
-
-    was_enabled = obs.enabled()
-    prior = list(obs.spans())
-    obs.enable()
-    obs.clear()
-    try:
-        compiled.time_loop(tuple(state), n_steps, **kwargs)
-        rep = obs.drift_report(
-            terms=compiled.cost(),
-            exchange_every=compiled.target.exchange_every,
-        )
-    finally:
-        obs.clear()
-        if not was_enabled:
-            obs.disable()
-        for s in prior:
-            obs.tracer()._commit(s)
-    return rep.as_dict()
 
 
 def target_record(target, provenance: str = "manual") -> dict:

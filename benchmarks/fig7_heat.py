@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import (
-    gpts, measure_drift, save_record, table, target_record, time_step,
+    gpts, save_record, table, target_record, time_step,
 )
 from repro.api import Target, time_loop
 from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
@@ -25,12 +25,10 @@ ORDERS = (2, 4, 8)
 
 
 def run(fast: bool = False, tune: bool = False,
-        fused_epoch: bool = False, drift: bool = False) -> dict:
+        fused_epoch: bool = False) -> dict:
     """``fused_epoch=True`` times the pallas epoch-megakernel target
     (k=4, one kernel dispatch per epoch) instead of the default jnp
-    path; the recorded ``target`` dict carries the axes either way.
-    ``drift=True`` additionally runs each case under span tracing and
-    records the roofline model-vs-measured error (``repro.obs.drift``)."""
+    path; the recorded ``target`` dict carries the axes either way."""
     cases = CASES if not fast else [(2, (256, 256), 4)]
     rows, record = [], {}
     for ndim, shape, steps in cases:
@@ -68,13 +66,6 @@ def run(fast: bool = False, tune: bool = False,
                 "shape": shape, "steps": steps, "sec": sec, "gpts": tp,
                 "target": target_record(target, "tuned" if tune else "manual"),
             }
-            if drift:
-                from repro.api import compile as api_compile
-
-                record[key]["drift"] = measure_drift(
-                    api_compile(op.program, target),
-                    (u0,), 2 * target.exchange_every,
-                )
             rows.append((f"{ndim}D", f"so{so}", "x".join(map(str, shape)), f"{tp:.3f}"))
     print(table("fig7a: heat diffusion throughput (GPts/s, XLA-CPU)", rows,
                 ["dims", "SDO", "grid", "GPts/s"]))
@@ -91,7 +82,5 @@ if __name__ == "__main__":
     ap.add_argument("--fused-epoch", action="store_true",
                     help="time the pallas epoch-megakernel target "
                          "(k=4, one kernel dispatch per epoch)")
-    ap.add_argument("--drift", action="store_true",
-                    help="record roofline model-vs-measured drift per case")
     a = ap.parse_args()
-    run(fast=a.fast, tune=a.tune, fused_epoch=a.fused_epoch, drift=a.drift)
+    run(fast=a.fast, tune=a.tune, fused_epoch=a.fused_epoch)

@@ -99,27 +99,34 @@ def main() -> None:
     print(f"peak: {u0.max():.3f} -> {uT.max():.3f} (diffused)")
     assert np.isfinite(uT).all()
 
-    # -- observability: trace one epoch + drift check (DESIGN.md §12) ------
-    # obs.enable() switches time_loop to a per-epoch traced path (bitwise
-    # equal, slower) so compile/dispatch/comm/compute spans land on one
-    # timeline; write_chrome exports it for Perfetto, and drift_report
-    # compares the measured epoch against the roofline model.
+    # -- observability: a profile with host spans (DESIGN.md §12) ---------
+    # obs.enable() turns on the host spans (api.compile, time_loop, ...);
+    # jax.profiler.trace records them beside the device ops, whose
+    # op_name metadata names the IR op that emitted them (stencil.apply,
+    # comm.halo_pad, ...).  Open the .xplane.pb in XProf or Perfetto.
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
     from repro import obs
 
     obs.enable()
-    obs.clear()
-    step.time_loop([jnp.asarray(u0)], 2 * k)
-    # the host's measured epoch against the modelled v5e step
-    rep = obs.drift_report(terms=step.cost(device_kind=V5E), exchange_every=k)
-    trace_path = obs.write_chrome("results/quickstart_trace.json")
+    with jax.profiler.trace("results/quickstart_trace"):
+        jax.block_until_ready(step.time_loop([jnp.asarray(u0)], 2 * k))
     obs.disable()
-    counts = {}
-    for s in obs.spans():
-        counts[s.cat] = counts.get(s.cat, 0) + 1
-    print(f"traced {sum(counts.values())} spans {counts} -> {trace_path}")
-    print(rep)
-    print(f"unified counters: { {ns: len(v) for ns, v in obs.snapshot().items()} }")
-    obs.clear()
+    path = sorted(glob.glob("results/quickstart_trace/**/*.xplane.pb",
+                            recursive=True))[-1]
+    spans = [
+        (e.name, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name == "time_loop"
+    ]
+    print(f"profile {path}: {spans}")
+    print(f"steps traced so far: {obs.snapshot()['compile']['step_traces']}")
 
     # -- serving: many tenants, one engine (DESIGN.md §9) ------------------
     # StencilEngine batches same-fingerprint requests into ONE vmapped

@@ -532,19 +532,14 @@ class CompiledStencil:
         ``time_loop`` rotation wants.  With ``Target(exchange_every=k)``
         one call advances a whole k-step epoch.  A slot-axis target takes
         (and allocates) ``[B, *field_shape]`` arrays — one pooled call
-        advances ``B`` independent simulations."""
-        return self._step_over(self._fn, dtype)
-
-    def _step_over(self, call: Callable, dtype=None) -> Callable:
-        """``step()``'s input-only calling convention wrapped around an
-        arbitrary executable of the full field signature — ``self._fn``
-        for the jitted step, ``self._raw_fn`` for the traced eager path
-        (``repro.obs``: the interpreter re-executes per epoch, so
-        exchange/apply spans land once per epoch, not once per trace)."""
+        advances ``B`` independent simulations.  Each trace of it into an
+        enclosing computation counts in ``step_traces()``."""
         outs = set(self._out_indices)
         pooled = self.target.slot_axis is not None
 
         def fn(*inputs):
+            if inputs and isinstance(inputs[0], jax.core.Tracer):
+                _count_step_trace()
             it = iter(inputs)
             dt = dtype or (inputs[0].dtype if inputs else jnp.float32)
             lead = (inputs[0].shape[0],) if (pooled and inputs) else ()
@@ -556,20 +551,9 @@ class CompiledStencil:
             ]
             rest = list(it)
             assert not rest, f"{len(rest)} extra input arrays"
-            return call(*args)
+            return self._fn(*args)
 
         return fn
-
-    @property
-    def _n_ranks(self) -> int:
-        mesh = self.target.mesh
-        if mesh is None:
-            return 1
-        n = 1
-        for name in mesh.axis_names:
-            if name != self.target.slot_axis:
-                n *= int(mesh.shape[name])
-        return n
 
     def epochs(self, n_steps: int) -> int:
         """``n_steps`` time steps as a whole number of epochs of this
@@ -592,17 +576,8 @@ class CompiledStencil:
         time steps — exactly one iteration of ``time_loop``'s body, exposed
         so epoch-granular drivers (``repro.resilience.ResilientLoop``, the
         serve engine) and the fori-loop driver share one rotation rule."""
-        if _obs.enabled():
-            with _obs.span("epoch", cat="dispatch", rank=None,
-                           program=self.program.name,
-                           k=self.target.exchange_every,
-                           ranks=self._n_ranks):
-                outs = self.step()(*state)
-                outs = outs if isinstance(outs, tuple) else (outs,)
-                jax.block_until_ready(outs)
-        else:
-            outs = self.step()(*state)
-            outs = outs if isinstance(outs, tuple) else (outs,)
+        outs = self.step()(*state)
+        outs = outs if isinstance(outs, tuple) else (outs,)
         return tuple(state[len(outs):]) + tuple(outs)
 
     def time_loop(self, state: Sequence[Any], n_steps: int, unroll: int = 1):
@@ -614,33 +589,13 @@ class CompiledStencil:
         epochs.  For a checkpointable / fault-tolerant loop with the same
         arithmetic, see ``repro.resilience.ResilientLoop``.
 
-        With tracing on (``repro.obs``) the fori-loop is replaced by a
-        host-driven epoch loop over the *eager* (unjitted) executable:
-        each epoch re-executes the interpreter, so every epoch records
-        its own exchange window and apply spans with real wall-clock
-        timestamps — the timeline `lax.fori_loop`'s single trace cannot
-        produce.  Same arithmetic, host-loop dispatch overhead applies
-        (the resilience driver proved the python-epoch loop equivalent
-        in PR 8); benchmark numbers should be taken untraced."""
-        if _obs.enabled():
-            return self._traced_time_loop(tuple(state), n_steps)
-        return time_loop(
-            self.step(), tuple(state), self.epochs(n_steps), unroll=unroll
-        )
-
-    def _traced_time_loop(self, state: tuple, n_steps: int) -> tuple:
-        n_epochs = self.epochs(n_steps)
-        k = self.target.exchange_every
-        step = self._step_over(self._raw_fn)
-        for e in range(n_epochs):
-            with _obs.span("epoch", cat="dispatch", rank=None,
-                           program=self.program.name, epoch=e,
-                           step_begin=e * k, k=k, ranks=self._n_ranks):
-                outs = step(*state)
-                outs = outs if isinstance(outs, tuple) else (outs,)
-                jax.block_until_ready(outs)
-            state = tuple(state[len(outs):]) + tuple(outs)
-        return state
+        The ``time_loop`` span (``repro.obs``) covers the host side of a
+        call: its trace, compile and dispatch, not the device's run."""
+        with _obs.span("time_loop", program=self.program.name,
+                       n_steps=n_steps, k=self.target.exchange_every):
+            return time_loop(
+                self.step(), tuple(state), self.epochs(n_steps), unroll=unroll
+            )
 
     # -- inspection ------------------------------------------------------
     @property
@@ -794,6 +749,27 @@ _LOCK = threading.RLock()
 _KEY_LOCKS: dict[tuple, threading.Lock] = {}
 
 
+# steps traced into a computation: ``compile.step_traces`` of
+# ``obs.snapshot()``
+_STEP_TRACES = 0
+
+
+def _count_step_trace() -> None:
+    global _STEP_TRACES
+    with _LOCK:
+        _STEP_TRACES += 1
+
+
+def step_traces() -> int:
+    """How many times a ``CompiledStencil.step()`` was traced into an
+    enclosing computation (a ``time_loop``'s ``fori_loop`` body, a
+    ``jax.jit``, the serve engine's vmapped pool).  Each such computation
+    is compiled anew, so a count that grows with the calls finds a step
+    that recompiles: an eager ``time_loop`` call adds one every call, a
+    ``time_loop`` under one ``jax.jit`` one in all."""
+    return _STEP_TRACES
+
+
 def cache_stats() -> CacheStats:
     """Process-wide compile-cache counters (shared by ``compile``,
     ``lower_ir`` and ``cached_callable``) — truthful hit/miss/eviction
@@ -900,7 +876,7 @@ def compile(
     if _obs.enabled():
         with _LOCK:
             hit = key in _CACHE
-        with _obs.span("api.compile", cat="compile", program=program.name,
+        with _obs.span("api.compile", program=program.name,
                        cache="hit" if hit else "miss"):
             return _cached(key, lambda: _build(program, target))
     return _cached(key, lambda: _build(program, target))
@@ -1063,7 +1039,7 @@ def partition_specs(program: Program, strategy: SlicingStrategy) -> list:
 
 
 def _build(program: Program, target: Target) -> CompiledStencil:
-    with _obs.span("api.build", cat="compile", program=program.name,
+    with _obs.span("api.build", program=program.name,
                    backend=target.backend, k=target.exchange_every):
         return _build_inner(program, target)
 
@@ -1093,6 +1069,7 @@ def _build_inner(program: Program, target: Target) -> CompiledStencil:
         backend=target.backend,
         pallas_interpret=target.pallas_interpret,
         pallas_tile=target.pallas_tile,
+        name=f"{program.name}.step",
     )
     if target.backend == "pallas":
         from repro.kernels import KernelPlanError

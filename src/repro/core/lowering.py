@@ -41,7 +41,6 @@ from jax import lax
 
 from repro.core import ir
 from repro.core.dialects import comm, dmp, stencil
-from repro.obs import trace as _obs
 
 # --------------------------------------------------------------------------
 # Shared point-function evaluator
@@ -148,6 +147,15 @@ def _pad_with_bc(x, lo: tuple, hi: tuple, grid: dmp.GridAttr, boundary: str):
 # Function interpreter — one op-dispatch level, comm ops only
 # --------------------------------------------------------------------------
 
+def scope(op: ir.Operation) -> str:
+    """The ``jax.named_scope`` an IR op executes under: its own IR name,
+    and for a split apply its part after a dot
+    (``stencil.apply.interior``).  Every device op the op emits carries
+    it in its ``op_name`` metadata, and the profiler in its ``tf_op``
+    stat; no ``:``, which ``tf_op`` uses as a separator."""
+    part = op.attributes.get("part")
+    return op.name if part is None else f"{op.name}.{part.value}"
+
 
 class StencilInterpreter:
     """Interprets a rank-local, comm-lowered stencil function into a JAX
@@ -167,6 +175,7 @@ class StencilInterpreter:
         backend: str = "jnp",
         pallas_interpret: Optional[bool] = None,
         pallas_tile: Optional[tuple] = None,
+        name: str = "step",
     ) -> None:
         assert backend in ("jnp", "pallas")
         if backend == "pallas" and pallas_interpret is None:
@@ -175,6 +184,9 @@ class StencilInterpreter:
                 "Target (True only for the CPU interpret oracle)"
             )
         self.func = func
+        # what ``jax.jit`` names the step, and so the root of every
+        # device op's scope path (``jit(<name>)/stencil.apply/...``)
+        self.__name__ = name
         self.axis_sizes = dict(axis_sizes)
         self.distributed = distributed
         self.backend = backend
@@ -184,14 +196,6 @@ class StencilInterpreter:
         for op in func.body.ops:
             if isinstance(op, stencil.StoreOp) and op.field not in self.output_fields:
                 self.output_fields.append(op.field)
-        # obs: one track is traced for every rank (SPMD), tagged with the
-        # rank count so the exporter can replicate spans honestly
-        self._n_ranks = 1
-        for n in self.axis_sizes.values():
-            self._n_ranks *= int(n)
-        # open exchange windows: ExchangeStartOp result -> obs token,
-        # closed by the WaitOp consuming that patch (reset per call)
-        self._open_exchanges: dict = {}
 
     # -- public --------------------------------------------------------
     def __call__(self, *arrays):
@@ -202,7 +206,6 @@ class StencilInterpreter:
         )
         env: dict[ir.SSAValue, Any] = {}
         field_state: dict[ir.SSAValue, Any] = {}
-        self._open_exchanges = {}
         for arg, arr in zip(fields, arrays):
             expect = arg.type.bounds.shape
             assert tuple(arr.shape) == tuple(expect), (
@@ -217,20 +220,17 @@ class StencilInterpreter:
 
     # -- op execution ---------------------------------------------------
     def _exec(self, op: ir.Operation, env, field_state) -> None:
+        with jax.named_scope(scope(op)):
+            self._exec_op(op, env, field_state)
+
+    def _exec_op(self, op: ir.Operation, env, field_state) -> None:
         if isinstance(op, stencil.LoadOp):
             env[op.results[0]] = field_state[op.field]
         elif isinstance(op, stencil.ApplyOp):
             rb = op.result_bounds
             arrays = [env[o] for o in op.operands]
             origins = [o.type.bounds.lb for o in op.operands]
-            if _obs.enabled():
-                part = op.attributes.get("part")
-                name = f"apply:{part.value if part is not None else 'full'}"
-                with _obs.span(name, cat="compute", rank=None,
-                               ranks=self._n_ranks, shape=list(rb.shape)):
-                    outs = self._apply_backend(op, arrays, origins, rb)
-            else:
-                outs = self._apply_backend(op, arrays, origins, rb)
+            outs = self._apply_backend(op, arrays, origins, rb)
             for res, arr in zip(op.results, outs):
                 env[res] = arr
         elif isinstance(op, stencil.CombineOp):
@@ -256,28 +256,12 @@ class StencilInterpreter:
             env[op.results[0]] = _exec_halo_pad(op, env[op.operands[0]])
         elif isinstance(op, comm.ExchangeStartOp):
             env[op.results[0]] = self._exec_comm_start(op, env[op.temp])
-            if _obs.enabled():
-                # the exchange window closes at the wait consuming this
-                # patch; putting it on the comm lane lets Perfetto show
-                # it overlapping the interior apply that hides it
-                self._open_exchanges[op.results[0]] = _obs.begin_window(
-                    "comm.exchange", cat="comm", rank=None,
-                    ranks=self._n_ranks, size=list(op.size),
-                )
         elif isinstance(op, comm.WaitOp):
             self._exec_comm_wait(op, env)
-            if _obs.enabled():
-                for p in op.patches:
-                    _obs.end_window(self._open_exchanges.pop(p, None))
         elif isinstance(op, comm.BoundaryMaskOp):
             env[op.results[0]] = self._exec_boundary_mask(op, env[op.temp])
         elif isinstance(op, stencil.FusedEpochOp):
-            if _obs.enabled():
-                with _obs.span("fused_epoch", cat="compute", rank=None,
-                               ranks=self._n_ranks, backend=self.backend):
-                    self._exec_fused_epoch(op, env)
-            else:
-                self._exec_fused_epoch(op, env)
+            self._exec_fused_epoch(op, env)
         elif isinstance(op, comm.AllReduceOp):
             v = env[op.operands[0]]
             red = {"sum": lax.psum, "max": lax.pmax, "min": lax.pmin}[op.op]
@@ -344,6 +328,7 @@ class StencilInterpreter:
                 rb,
                 tile=self._apply_tile(op),
                 interpret=self.pallas_interpret,
+                name=scope(op),
             )
         return eval_apply_body(op, arrays, origins, rb)
 
@@ -417,6 +402,7 @@ class StencilInterpreter:
             boundary_keep,
             tile=self.pallas_tile,
             interpret=self.pallas_interpret,
+            name=scope(op),
         )
         for res, arr in zip(op.results, outs):
             env[res] = arr

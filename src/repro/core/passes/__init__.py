@@ -89,14 +89,10 @@ class PassManager(metaclass=_PassManagerMeta):
         after_each: Optional[Callable[[str, ir.FuncOp], None]] = None,
     ) -> ir.FuncOp:
         global _RUNS_COMPLETED, _LAST_TIMINGS
-        traced = _obs.enabled()
         for p in self.passes:
             name = getattr(p, "__name__", repr(p))
             t0 = time.perf_counter()
-            if traced:
-                with _obs.span(f"pass:{name}", cat="compile"):
-                    out = p(func)
-            else:
+            with _obs.span(f"pass:{name}"):
                 out = p(func)
             if isinstance(out, ir.FuncOp):
                 func = out

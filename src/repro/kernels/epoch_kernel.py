@@ -51,7 +51,6 @@ from repro.kernels.stencil_apply import (
     window_source,
     window_spec,
 )
-from repro.obs import trace as _obs
 
 
 def _region_values(fused_op: stencil.FusedEpochOp) -> list:
@@ -152,8 +151,10 @@ def build_epoch_kernel(
     tile: Optional[tuple],
     *,
     interpret: bool,
+    name: Optional[str] = None,
 ):
-    """Code-generate one pallas_call for a whole fused epoch.
+    """Code-generate one pallas_call for a whole fused epoch, named
+    ``name``.
 
     Returns a callable taking ``(coords, *sources)``: the rank's int32
     grid coordinate per dim, then one array per op operand (re-based with
@@ -189,6 +190,7 @@ def build_epoch_kernel(
             out_shape=out_shape if n_out > 1 else out_shape[0],
             compiler_params=compiler_params(0),
             interpret=interpret,
+            name=name,
         )
 
     # -- tiled mode: grid over core, aligned epoch-halo windows -------------
@@ -229,6 +231,7 @@ def build_epoch_kernel(
         out_shape=out_shape if n_out > 1 else out_shape[0],
         compiler_params=compiler_params(rank),
         interpret=interpret,
+        name=name,
     )
 
 
@@ -240,27 +243,27 @@ def run_epoch_pallas(
     tile: Optional[tuple] = None,
     *,
     interpret: bool,
+    name: Optional[str] = None,
 ) -> list:
     """Entry point used by the lowering's pallas backend: one traced
-    pallas_call per fused epoch (counted in ``kernels.dispatch_stats``).
-    ``arrays[k]`` covers operand ``k``'s bounds; ``coords`` is the rank's
-    int32 grid coordinate per dim."""
+    pallas_call per fused epoch (counted in ``kernels.dispatch_stats``),
+    named ``name``.  ``arrays[k]`` covers operand ``k``'s bounds;
+    ``coords`` is the rank's int32 grid coordinate per dim."""
     if not fused_op.results:
         return []
-    with _obs.span("pallas:fused_epoch", cat="kernel", rank=None,
-                   interpret=interpret):
-        tile = plan_epoch(fused_op, tile)
-        sources = [a.astype(jnp.float32) for a in arrays]
-        if tile is not None:
-            core = fused_op.results[0].type.bounds
-            sources = [
-                window_source(
-                    s, (0,) * core.rank, core.shape, tile,
-                    window_shape(tile, _span(a.type.bounds, core)),
-                )
-                for s, a in zip(sources, fused_op.body.args)
-            ]
-        call = build_epoch_kernel(fused_op, keep_fn, tile, interpret=interpret)
-        _DISPATCH.fused_epoch_calls += 1
-        out = call(jnp.asarray(coords, jnp.int32), *sources)
+    tile = plan_epoch(fused_op, tile)
+    sources = [a.astype(jnp.float32) for a in arrays]
+    if tile is not None:
+        core = fused_op.results[0].type.bounds
+        sources = [
+            window_source(
+                s, (0,) * core.rank, core.shape, tile,
+                window_shape(tile, _span(a.type.bounds, core)),
+            )
+            for s, a in zip(sources, fused_op.body.args)
+        ]
+    call = build_epoch_kernel(fused_op, keep_fn, tile, interpret=interpret,
+                              name=name)
+    _DISPATCH.fused_epoch_calls += 1
+    out = call(jnp.asarray(coords, jnp.int32), *sources)
     return list(out) if isinstance(out, (tuple, list)) else [out]

@@ -39,7 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dialects import stencil
 from repro.kernels import _DISPATCH, KernelPlanError
-from repro.obs import trace as _obs
 
 SUBLANES, LANES = 8, 128  # f32 (sublane, lane) tile of the last two dims
 # Whole-kernel working set the tile chooser targets, and the scoped VMEM
@@ -253,6 +252,7 @@ def build_apply_kernel(
     tile: tuple,
     *,
     interpret: bool,
+    name: Optional[str] = None,
 ):
     """Code-generate a pallas_call for one stencil.apply over ``tile``.
 
@@ -292,6 +292,7 @@ def build_apply_kernel(
         out_shape=out_shape if n_out > 1 else out_shape[0],
         compiler_params=compiler_params(rank),
         interpret=interpret,
+        name=name,
     )
 
 
@@ -303,28 +304,28 @@ def run_apply_pallas(
     tile: Optional[tuple] = None,
     *,
     interpret: bool,
+    name: Optional[str] = None,
 ) -> list:
     """Entry point used by the lowering's pallas backend: ``arrays[k]``
     holds logical points from ``origins[k]`` on.  Each call is one traced
-    pallas_call (counted in ``kernels.dispatch_stats``)."""
-    with _obs.span("pallas:apply", cat="kernel", rank=None,
-                   interpret=interpret):
-        tile = plan_apply(apply_op, result_bounds, tile)
-        rb = result_bounds
-        sources = []
-        for arr, og, (lo, hi) in zip(arrays, origins, apply_spans(apply_op)):
-            base = tuple(r + l - o for r, l, o in zip(rb.lb, lo, og))
-            if any(b < 0 for b in base):
-                raise ValueError(
-                    f"operand window starts at {base} before the array "
-                    "origin (halo missing — run the decompose pass first)"
-                )
-            window = window_shape(tile, [h - l for l, h in zip(lo, hi)])
-            sources.append(
-                window_source(arr.astype(jnp.float32), base, rb.shape, tile,
-                              window)
+    pallas_call (counted in ``kernels.dispatch_stats``), named ``name``."""
+    tile = plan_apply(apply_op, result_bounds, tile)
+    rb = result_bounds
+    sources = []
+    for arr, og, (lo, hi) in zip(arrays, origins, apply_spans(apply_op)):
+        base = tuple(r + l - o for r, l, o in zip(rb.lb, lo, og))
+        if any(b < 0 for b in base):
+            raise ValueError(
+                f"operand window starts at {base} before the array "
+                "origin (halo missing — run the decompose pass first)"
             )
-        call = build_apply_kernel(apply_op, rb, tile, interpret=interpret)
-        _DISPATCH.apply_calls += 1
-        out = call(*sources)
+        window = window_shape(tile, [h - l for l, h in zip(lo, hi)])
+        sources.append(
+            window_source(arr.astype(jnp.float32), base, rb.shape, tile,
+                          window)
+        )
+    call = build_apply_kernel(apply_op, rb, tile, interpret=interpret,
+                              name=name)
+    _DISPATCH.apply_calls += 1
+    out = call(*sources)
     return list(out) if isinstance(out, (tuple, list)) else [out]

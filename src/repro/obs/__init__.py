@@ -1,76 +1,32 @@
-"""``repro.obs`` — unified observability: span tracing, metrics
-registry, and roofline-drift detection (DESIGN.md §12).
+"""``repro.obs`` — observability: host spans on the profiler's clock and
+one snapshot of the stack's counters (DESIGN.md §12).
 
 Quickstart::
 
+    import jax
     from repro import api, obs
 
     obs.enable()                       # or REPRO_TRACE=1 in the env
-    step = api.compile(prog, api.Target(exchange_every=4))
-    out = step.time_loop((u0,), 32)    # traced: one span per epoch,
-                                       # exchange windows on the comm lane
-    obs.write_chrome("trace.json")     # open in https://ui.perfetto.dev
-    print(obs.drift_report(terms=step.cost()))   # model vs measured
+    with jax.profiler.trace("/tmp/trace"):   # open in Perfetto/XProf
+        step = api.compile(prog, api.Target())
+        out = step.time_loop((u0,), 32)
     print(obs.snapshot())              # every subsystem's counters
 
-Tracing is off by default and the disabled path costs one attribute
-check per instrumented site — see ``repro.obs.trace``.  Summarize a
-saved trace offline with ``python -m repro.obs trace.json``.
+Device ops carry the IR op that emitted them as a ``jax.named_scope``
+(``stencil.apply``, ``comm.halo_pad``, ...) whether or not obs is
+enabled; obs adds the host spans (``api.compile``, ``time_loop``,
+``engine.step``, ...).  Tracing is off by default and a disabled span
+costs one check — see ``repro.obs.trace``.
 """
-from repro.obs.drift import DriftReport, drift_report
-from repro.obs.export import (
-    load_spans,
-    merge_traces,
-    to_chrome,
-    write_chrome,
-    write_jsonl,
-    write_rank_traces,
-)
 from repro.obs.registry import NAMESPACES, snapshot
-from repro.obs.trace import (
-    LANE_COMM,
-    LANE_EXECUTE,
-    Span,
-    Tracer,
-    begin_window,
-    clear,
-    disable,
-    enable,
-    enabled,
-    end_window,
-    instant,
-    set_rank,
-    span,
-    spans,
-    traced,
-    tracer,
-)
+from repro.obs.trace import disable, enable, enabled, span, traced
 
 __all__ = [
-    "DriftReport",
-    "drift_report",
-    "load_spans",
-    "merge_traces",
-    "to_chrome",
-    "write_chrome",
-    "write_jsonl",
-    "write_rank_traces",
     "NAMESPACES",
     "snapshot",
-    "LANE_COMM",
-    "LANE_EXECUTE",
-    "Span",
-    "Tracer",
-    "begin_window",
-    "clear",
     "disable",
     "enable",
     "enabled",
-    "end_window",
-    "instant",
-    "set_rank",
     "span",
-    "spans",
     "traced",
-    "tracer",
 ]

@@ -2,7 +2,8 @@
 
 Before ``repro.obs`` each subsystem kept truthful but *disjoint*
 counters: the compile cache (``api.cache_stats``), the pass pipeline
-(``PassManager.runs_completed``), kernel dispatch
+(``PassManager.runs_completed``), step traces (``api.step_traces``),
+kernel dispatch
 (``kernels.dispatch_stats``), the serve engine (per-instance
 ``EngineMetrics``), checkpointing (per-``Checkpointer``
 ``CheckpointStats``) and the tune cache (``tune.cache.cache_stats``).
@@ -40,12 +41,13 @@ def snapshot(flat: bool = False) -> dict:
             **api.cache_stats().as_dict(),
             "cache_capacity": api.cache_capacity(),
             "pipeline_runs": int(PassManager.runs_completed),
+            "step_traces": api.step_traces(),
         },
         "kernel": kernels.dispatch_stats().as_dict(),
         "serve": _serve_metrics.global_counters(),
         "checkpoint": _ckpt.global_stats().as_dict(),
         "tune": _tune_cache.cache_stats().as_dict(),
-        "trace": _trace.tracer().counters(),
+        "trace": {"enabled": _trace.enabled()},
     }
     if not flat:
         return out

@@ -166,9 +166,8 @@ class ResilientLoop:
         if self._epoch_fn is None:
             self._epoch_fn = self.compiled.step()
         if _obs.enabled():
-            with _obs.span("epoch", cat="dispatch", rank=None,
-                           program=self.program.name, epoch=e, step_begin=step,
-                           k=self.k, ranks=self.compiled._n_ranks):
+            with _obs.span("epoch", program=self.program.name, epoch=e,
+                           step_begin=step, k=self.k):
                 outs = self._epoch_fn(*self.state)
                 outs = outs if isinstance(outs, tuple) else (outs,)
                 jax.block_until_ready(outs)
@@ -205,8 +204,8 @@ class ResilientLoop:
             "target_fingerprint": self.compiled.target.fingerprint,
         }
         t0 = time.perf_counter()
-        with _obs.span("checkpoint.save", cat="checkpoint",
-                       step=self.step_count, blocking=not self.async_saves):
+        with _obs.span("checkpoint.save", step=self.step_count,
+                       blocking=not self.async_saves):
             self.checkpointer.save(
                 self.step_count, tree, blocking=not self.async_saves,
                 extra=extra,
@@ -291,7 +290,7 @@ def resume(
     tree_like = {
         "state": {f"b{i}": np.zeros(()) for i in range(n_bufs)}
     }
-    with _obs.span("checkpoint.restore", cat="checkpoint", step=saved_step,
+    with _obs.span("checkpoint.restore", step=saved_step,
                    program=program.name):
         restored = ckpt.restore(tree_like, step=saved_step)
     state = tuple(restored["state"][f"b{i}"] for i in range(n_bufs))

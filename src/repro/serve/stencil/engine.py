@@ -207,8 +207,7 @@ class StencilEngine:
         a solo fallback), stream frames, reclaim + refill finished slots,
         retire idle buckets."""
         self.engine_step_count += 1
-        with _obs.span("engine.step", cat="serve",
-                       step=self.engine_step_count):
+        with _obs.span("engine.step", step=self.engine_step_count):
             return self._step_inner()
 
     def _step_inner(self) -> StepMetrics:
@@ -232,8 +231,8 @@ class StencilEngine:
             else:
                 pooled_fn = self._pool_fn(group)
             if pooled_fn is not None:
-                with _obs.span("dispatch:pooled", cat="serve",
-                               bucket=bucket, live=len(live)):
+                with _obs.span("dispatch:pooled", bucket=bucket,
+                               live=len(live)):
                     t0 = time.perf_counter()
                     outs = pooled_fn(*group.state)
                     outs = outs if isinstance(outs, tuple) else (outs,)
@@ -251,8 +250,8 @@ class StencilEngine:
                 # buffered and committed in ONE batched write per buffer
                 rows = {}
                 for slot, _ in live:
-                    with _obs.span("dispatch:solo", cat="serve",
-                                   bucket=bucket, slot=slot):
+                    with _obs.span("dispatch:solo", bucket=bucket,
+                                   slot=slot):
                         t0 = time.perf_counter()
                         outs = group.compiled.step()(*group.read_slot(slot))
                         outs = outs if isinstance(outs, tuple) else (outs,)
@@ -319,11 +318,10 @@ class StencilEngine:
         final state stays bitwise-equal to an unmigrated run."""
         from repro.resilience.migrate import evacuate as _evacuate
 
-        with _obs.span("engine.evacuate", cat="serve",
-                       program=program_fingerprint):
+        with _obs.span("engine.evacuate",
+                       program=program_fingerprint) as span:
             evacuated = _evacuate(self, program_fingerprint, directory)
-        if evacuated:
-            _obs.instant("evacuated", cat="serve", count=len(evacuated))
+            span.set_metadata(count=len(evacuated))
         return evacuated
 
     def admit_evacuated(self, directory: str, programs, target=None) -> list:
@@ -333,10 +331,9 @@ class StencilEngine:
         request (e.g. onto this engine's mesh).  Returns new handles."""
         from repro.resilience.migrate import admit as _admit
 
-        with _obs.span("engine.admit_evacuated", cat="serve"):
+        with _obs.span("engine.admit_evacuated") as span:
             admitted = _admit(self, directory, programs, target=target)
-        if admitted:
-            _obs.instant("admitted", cat="serve", count=len(admitted))
+            span.set_metadata(count=len(admitted))
         return admitted
 
     @property
@@ -376,7 +373,7 @@ class StencilEngine:
                 continue
             new_capacity, provenance = decision
             bucket = f"{group.key[0]}/{group.key[1]}"
-            with _obs.span("pool.resize", cat="serve", bucket=bucket,
+            with _obs.span("pool.resize", bucket=bucket,
                            action=provenance.get("action"),
                            to_capacity=int(new_capacity)):
                 self.resize_bucket(group, new_capacity)

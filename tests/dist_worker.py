@@ -657,102 +657,37 @@ def scenario_serve_autoscale():
           f"shrinks={auto['shrinks']}")
 
 
-def scenario_obs_trace():
-    """ISSUE 10 acceptance: a traced 2-rank ``exchange_every=4`` heat run
-    exports a merged Chrome trace with exactly ONE exchange span pair per
-    epoch on each rank's track, and the exchange window overlaps the
-    interior apply that hides it (comm/compute overlap, measured)."""
-    import json
-    import shutil
-    import tempfile
+def scenario_scopes_2x2(k):
+    """Every collective-permute of the compiled 2x2 step lies under the
+    ``comm.exchange_start`` scope, and every halo write (the step's
+    dynamic-update-slices) under ``comm.wait``: the device trace names
+    the exchange by IR op, not by XLA's generated op names."""
+    import re
 
-    from repro import obs
-    from repro.core.dialects import comm as comm_dialect
-
-    shape = (64, 32)
-    k, steps = 4, 8  # two epochs
-    boundary = "periodic"
-    u0, _ = run_single(_jacobi, shape, boundary)
-    prog = _jacobi(shape).finish(boundary=boundary)
-
-    # untraced 2-rank run: the fori_loop reference the traced path must match
-    mesh = _mesh((2,), ("x",))
-    target = Target(mesh=mesh, strategy=make_strategy_1d(2),
-                    exchange_every=k, overlap=True)
-    step = api_compile(prog, target)
-    want = step.time_loop((u0,), steps)
-    want = np.asarray(want[0] if isinstance(want, tuple) else want)
-
-    # one deep exchange VOLLEY per epoch: a pair of directional
-    # exchange_starts (up + down the 1-D mesh) closed by a single wait —
-    # so each epoch's track shows exactly one exchange span pair
-    n_starts = sum(
-        1 for op in step.local_ir.body.ops
-        if isinstance(op, comm_dialect.ExchangeStartOp)
-    )
-    assert n_starts == 2, f"expected one exchange pair per epoch, IR has {n_starts} starts"
-
-    obs.enable()
-    obs.clear()
-    got = step.time_loop((u0,), steps)
-    got = np.asarray(got[0] if isinstance(got, tuple) else got)
-    obs.disable()
-    check("obs-trace-2rank-bitwise", got, want)
-
-    spans = obs.spans()
-    epochs = sorted((s for s in spans if s.name == "epoch"),
-                    key=lambda s: s.ts)
-    assert len(epochs) == steps // k, f"{len(epochs)} epoch spans"
-    comm_spans = [s for s in spans if s.cat == "comm"]
-    assert len(comm_spans) == len(epochs) * n_starts, (
-        f"{len(comm_spans)} exchange windows for {len(epochs)} epochs"
-    )
-    interior = [s for s in spans if s.name == "apply:interior"]
-    assert interior, "overlap target produced no interior apply spans"
-    for e in epochs:
-        inside = [c for c in comm_spans if e.ts <= c.ts and c.end <= e.end]
-        assert len(inside) == n_starts, (
-            f"epoch {e.args.get('epoch')}: {len(inside)} exchange windows"
-        )
-        # the exchange window must overlap an interior apply span
-        c = inside[0]
-        hidden = [a for a in interior if a.ts < c.end and c.ts < a.end]
-        assert hidden, "exchange window overlaps no interior apply"
-
-    rep = obs.drift_report(exchange_every=k)
-    assert rep.epochs == len(epochs) and rep.achieved_overlap > 0.0, (
-        rep.as_dict()
-    )
-
-    # per-rank trace files -> merged Chrome trace, one track per rank
-    tmp = tempfile.mkdtemp(prefix="repro-obs-trace-")
-    try:
-        paths = obs.write_rank_traces(tmp, spans)
-        assert len(paths) == 2, paths
-        merged = obs.merge_traces(tmp, out=os.path.join(tmp, "merged.json"))
-        with open(os.path.join(tmp, "merged.json")) as f:
-            merged = json.load(f)
-        events = [e for e in merged["traceEvents"] if e.get("ph") == "X"]
-        for r in (0, 1):
-            track_comm = [e for e in events
-                          if e["pid"] == r and e["cat"] == "comm"]
-            assert len(track_comm) == len(epochs) * n_starts, (
-                f"rank {r}: {len(track_comm)} exchange events"
-            )
-            track_interior = [e for e in events if e["pid"] == r
-                              and e["name"] == "apply:interior"]
-            for c in track_comm:
-                c0, c1 = c["ts"], c["ts"] + c["dur"]
-                assert any(a["ts"] < c1 and c0 < a["ts"] + a["dur"]
-                           for a in track_interior), (
-                    f"rank {r}: exchange window hides no interior apply"
-                )
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    obs.clear()
-    print(f"ok: obs-trace-2rank ({len(epochs)} epochs, "
-          f"one exchange pair ({n_starts} spans)/epoch, overlap "
-          f"{rep.achieved_overlap:.0%})")
+    grid = Grid(shape=(64, 64))
+    u = TimeFunction(name="u", grid=grid, space_order=8)
+    prog = Operator(Eq(u.dt, u.laplace), dt=0.1, boundary="zero").program
+    mesh = _mesh((2, 2), ("x", "y"))
+    step = api_compile(prog, Target(mesh=mesh, strategy=make_strategy_2d((2, 2)),
+                                    exchange_every=k))
+    by_opcode = {}
+    for line in step.lower().compile().as_text().splitlines():
+        m = re.search(r"= \S+ ([a-z-]+)\(", line)
+        if m:
+            name = re.search(r'op_name="([^"]*)"', line)
+            by_opcode.setdefault(m.group(1), []).append(
+                name.group(1) if name else None)
+    permutes = by_opcode.get("collective-permute", []) + by_opcode.get(
+        "collective-permute-start", [])
+    writes = by_opcode.get("dynamic-update-slice", [])
+    assert permutes and writes, sorted(by_opcode)
+    for name in permutes:
+        assert name and "/comm.exchange_start/" in name, name
+    for name in writes:
+        assert name and "/comm.wait/" in name, name
+    assert all(n.startswith(f"jit({prog.name}.step)/") for n in permutes + writes)
+    print(f"ok: scopes-2x2-k{k} ({len(permutes)} permutes, "
+          f"{len(writes)} halo writes)")
 
 
 SCENARIOS = {
@@ -804,9 +739,9 @@ SCENARIOS = {
     "slot-axis": scenario_slot_axis,
     "serve-pooled": scenario_serve_pooled,
     "serve-autoscale": scenario_serve_autoscale,
-    # ISSUE 10 — repro.obs: merged 2-rank trace with one exchange span
-    # pair per epoch and measured comm/compute overlap
-    "obs-trace-2rank": scenario_obs_trace,
+    # device ops named by the IR op that emitted them (named scopes)
+    "scopes-2x2-k1": lambda: scenario_scopes_2x2(1),
+    "scopes-2x2-k4": lambda: scenario_scopes_2x2(4),
 }
 
 

@@ -47,6 +47,8 @@ SCENARIOS = [
     "slot-axis",
     "serve-pooled",
     "serve-autoscale",
+    "scopes-2x2-k1",
+    "scopes-2x2-k4",
 ]
 
 

@@ -1,192 +1,230 @@
-"""repro.obs: span tracer, Chrome export, unified registry, drift.
+"""repro.obs: host spans on the profiler's clock, IR scopes on the device
+ops, the step-trace counter and the unified registry.
 
-The tracer itself is tested synthetically (hand-built spans, no jax);
-the end-to-end acceptance — a traced 2-rank ``exchange_every=4`` heat
-run whose merged Chrome trace shows one exchange span pair per epoch
-overlapping the interior apply — runs in a subprocess through
-``tests/dist_worker.py obs-trace-2rank`` so the 8-device XLA flag never
+Spans are read back the way an operator reads them: from a
+``jax.profiler.trace`` of a tiny CPU run, through ``ProfileData``.  The
+2x2 exchange scopes are checked on virtual CPU devices in
+``tests/dist_worker.py scopes-2x2-k*`` so the device-count flag never
 leaks into this process.
 """
-import json
-import os
-import subprocess
-import sys
+import glob
+import re
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
-from repro import obs
-from repro.obs.trace import LANE_COMM, LANE_EXECUTE, Span, Tracer
-
-WORKER = os.path.join(os.path.dirname(__file__), "dist_worker.py")
+from repro import api, obs
+from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
 
 
 @pytest.fixture(autouse=True)
-def _clean_tracer():
-    """Every test starts and ends with the singleton disabled + empty."""
+def _obs_off():
+    """Every test starts and ends with obs disabled."""
     obs.disable()
-    obs.clear()
     yield
     obs.disable()
-    obs.clear()
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under ``jax.profiler.trace``; return the host-plane
+    events as ``[(name, stats, start ns, end ns)]``."""
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [
+        (e.name, dict(e.stats), e.start_ns, e.start_ns + e.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    ]
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _heat(n=32, so=8):
+    grid = Grid(shape=(n, n))
+    u = TimeFunction(name="u", grid=grid, space_order=so)
+    return Operator(Eq(u.dt, u.laplace), dt=0.1, boundary="zero").program
+
+
+def _op_names(hlo: str) -> set:
+    return set(re.findall(r'op_name="([^"]*)"', hlo))
 
 
 # --------------------------------------------------------------------------
-# tracer core
+# spans
 # --------------------------------------------------------------------------
 
 
-def test_disabled_tracing_is_a_shared_noop():
+def test_disabled_tracing_is_a_shared_noop(tmp_path):
     assert not obs.enabled()
-    h1 = obs.span("work", cat="compute", big="payload")
+    h1 = obs.span("work", big="payload")
     h2 = obs.span("other")
     # one shared null object — nothing allocated per disabled call site
     assert h1 is h2
-    with h1:
-        h1.args["ignored"] = True  # writes to a disabled span go nowhere
-    assert obs.spans() == []
-    obs.instant("event")
-    assert obs.end_window(obs.begin_window("w")) is None
-    assert obs.spans() == []
+
+    def run():
+        with h1:
+            h1.set_metadata(ignored=True)  # goes nowhere
+
+    events = _profile(tmp_path, run)
+    assert not _named(events, "work") and not _named(events, "other")
 
 
-def test_span_records_nesting_and_args():
+def test_span_records_nesting_and_args(tmp_path):
     obs.enable()
-    with obs.span("outer", cat="compile", phase="a"):
-        with obs.span("inner", cat="compile"):
-            pass
-        with obs.span("inner2", cat="compute"):
-            pass
-    got = obs.spans()
-    assert [s.name for s in got] == ["inner", "inner2", "outer"]
-    by = {s.name: s for s in got}
-    assert by["outer"].depth == 0
-    assert by["inner"].depth == by["inner2"].depth == 1
-    assert by["outer"].args == {"phase": "a"}
-    # children are contained in the parent's window
-    assert by["outer"].ts <= by["inner"].ts
-    assert by["inner"].end <= by["outer"].end + 1e-6
-    assert by["inner"].end <= by["inner2"].ts + by["inner2"].dur + 1e-6
+
+    def run():
+        with obs.span("outer", phase="a"):
+            with obs.span("inner", k=4):
+                pass
+            with obs.span("inner2") as sp:
+                sp.set_metadata(count=3)
+
+    events = _profile(tmp_path, run)
+    (outer,), (inner,), (inner2,) = (
+        _named(events, n) for n in ("outer", "inner", "inner2"))
+    assert outer[1] == {"phase": "a"}
+    assert inner[1] == {"k": 4}
+    assert inner2[1] == {"count": 3}
+    # children lie inside the parent on the profiler's clock
+    for child in (inner, inner2):
+        assert outer[2] <= child[2] and child[3] <= outer[3]
+    assert inner[3] <= inner2[2]
 
 
-def test_traced_decorator_bare_and_named():
+def test_traced_decorator_bare_and_named(tmp_path):
     @obs.traced
     def f(x):
         return x + 1
 
-    @obs.traced("custom.name", cat="serve")
+    @obs.traced("custom.name")
     def g(x):
         return x * 2
 
     assert f(1) == 2 and g(2) == 4  # disabled: plain passthrough
-    assert obs.spans() == []
+    events = _profile(tmp_path / "off", lambda: (f(1), g(2)))
+    assert not _named(events, "custom.name")
     obs.enable()
-    assert f(1) == 2 and g(2) == 4
-    names = [s.name for s in obs.spans()]
-    assert any("f" in n for n in names) and "custom.name" in names
-    assert {s.cat for s in obs.spans() if s.name == "custom.name"} == {"serve"}
+    events = _profile(tmp_path / "on", lambda: (f(1), g(2)))
+    assert _named(events, "custom.name")
+    assert any(e[0].endswith("f") for e in events)
 
 
-def test_async_windows_live_on_the_comm_lane():
+def test_enabled_spans_carry_args_on_the_host_plane(tmp_path):
     obs.enable()
-    tok = obs.begin_window("comm.exchange", size=[1, 4])
-    with obs.span("apply:interior", cat="compute"):
-        pass
-    obs.end_window(tok, rounds=1)
-    comm = [s for s in obs.spans() if s.cat == "comm"]
-    assert len(comm) == 1
-    assert comm[0].tid == LANE_COMM
-    assert comm[0].args == {"size": [1, 4], "rounds": 1}
-    # the window opened before the apply and closed after it: overlap
-    apply = next(s for s in obs.spans() if s.name == "apply:interior")
-    assert apply.tid == LANE_EXECUTE
-    assert comm[0].ts <= apply.ts and apply.end <= comm[0].end + 1e-6
+    prog = _heat(24, 4)
+    u0 = jnp.ones((24, 24), jnp.float32)
+
+    def run():
+        c = api.compile(prog, api.Target())
+        jax.block_until_ready(c.time_loop((u0,), 3))
+
+    events = _profile(tmp_path, run)
+    (compile_span,) = _named(events, "api.compile")
+    assert compile_span[1]["program"] == prog.name
+    assert compile_span[1]["cache"] in ("hit", "miss")
+    (loop,) = _named(events, "time_loop")
+    assert loop[1] == {"program": prog.name, "n_steps": 3, "k": 1}
 
 
-def test_ring_buffer_bounds_and_counts_drops():
-    t = Tracer(capacity=4)
-    t.enable()
-    for i in range(7):
-        with t.span(f"s{i}"):
-            pass
-    kept = [s.name for s in t.spans()]
-    assert kept == ["s3", "s4", "s5", "s6"]
-    assert t.dropped == 3
-    assert t.counters()["dropped"] == 3
-    t.clear()
-    assert t.spans() == [] and t.dropped == 0
+def test_disabled_spans_leave_no_host_events(tmp_path):
+    prog = _heat(24, 4)
+    u0 = jnp.ones((24, 24), jnp.float32)
 
+    def run():
+        c = api.compile(prog, api.Target())
+        jax.block_until_ready(c.time_loop((u0,), 3))
 
-def test_span_dict_roundtrip():
-    s = Span(name="epoch", cat="dispatch", ts=10.0, dur=0.5, rank=1,
-             tid=LANE_EXECUTE, depth=2, args={"k": 4})
-    assert Span.from_dict(s.as_dict()) == s
+    events = _profile(tmp_path, run)
+    names = {e[0] for e in events}
+    assert not names & {"api.compile", "api.build", "time_loop"}
+    assert not any(n.startswith("pass:") for n in names)
 
 
 # --------------------------------------------------------------------------
-# export
+# scopes: every IR op names the device ops it emits
+# --------------------------------------------------------------------------
+
+_SCOPE_CASES = {
+    "jnp": (api.Target(), {"comm.halo_pad", "comm.wait", "stencil.apply"}),
+    "pallas": (
+        api.Target(backend="pallas"),
+        {"comm.halo_pad", "comm.wait", "stencil.apply"},
+    ),
+    "fused-epoch": (
+        api.Target(backend="pallas", exchange_every=4, fused_epoch=True),
+        {"comm.halo_pad", "stencil.fused_epoch"},
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SCOPE_CASES))
+def test_compiled_step_carries_ir_scopes(case):
+    target, want = _SCOPE_CASES[case]
+    prog = _heat(32, 8)
+    c = api.compile(prog, target)
+    names = _op_names(c.lower().compile().as_text())
+    root = f"jit({prog.name}.step)/"
+    scopes = set()
+    for name in names:
+        parts = name.split("/")
+        scopes |= {p for p in parts if p.startswith(("stencil.", "comm."))}
+    assert want <= scopes, (case, sorted(names))
+    assert all(n.startswith(root) for n in names if "/" in n), sorted(names)
+    assert not any(":" in s for s in scopes)
+
+
+def test_step_jit_is_named_after_the_program():
+    prog = _heat(24, 4)
+    c = api.compile(prog, api.Target())
+    u0 = jnp.ones((24, 24), jnp.float32)
+    looped = jax.jit(lambda s: c.time_loop(s, 2)).lower((u0,)).compile()
+    names = _op_names(looped.as_text())
+    assert any(f"/jit({prog.name}.step)/" in n for n in names), sorted(names)
+    assert not any("<unknown>" in n for n in names), sorted(names)
+
+
+# --------------------------------------------------------------------------
+# the step-trace counter, and obs changing nothing
 # --------------------------------------------------------------------------
 
 
-def _synthetic_spans():
-    """Two ranks, one SPMD span, one comm window overlapping an apply."""
-    return [
-        Span("epoch", "dispatch", ts=1.0, dur=1.0, rank=None,
-             args={"ranks": 2, "k": 4}),
-        Span("comm.exchange", "comm", ts=1.1, dur=0.5, rank=None,
-             tid=LANE_COMM, args={"ranks": 2}),
-        Span("apply:interior", "compute", ts=1.2, dur=0.3, rank=None,
-             args={"ranks": 2}),
-        Span("engine.step", "serve", ts=2.0, dur=0.1, rank=0),
-    ]
+def test_step_traces_count_eager_calls_and_one_jit():
+    prog = _heat(24, 4)
+    c = api.compile(prog, api.Target())
+    u0 = jnp.ones((24, 24), jnp.float32)
+
+    def traces():
+        return obs.snapshot()["compile"]["step_traces"]
+
+    t0 = traces()
+    for _ in range(3):
+        c.time_loop((u0,), 2)
+    assert traces() == t0 + 3  # an eager call traces (and compiles) anew
+    looped = jax.jit(lambda s: c.time_loop(s, 2))
+    for _ in range(3):
+        jax.block_until_ready(looped((u0,)))
+    assert traces() == t0 + 4
+    c.advance((u0,))  # a concrete call dispatches the cached step
+    assert traces() == t0 + 4
 
 
-def test_chrome_export_schema(tmp_path):
-    path = obs.write_chrome(str(tmp_path / "t.json"), _synthetic_spans())
-    with open(path) as f:
-        doc = json.load(f)
-    assert doc["displayTimeUnit"] == "ms"
-    events = doc["traceEvents"]
-    meta = [e for e in events if e["ph"] == "M"]
-    xs = [e for e in events if e["ph"] == "X"]
-    # two ranks discovered from args.ranks -> two process-name records
-    assert {e["args"]["name"] for e in meta if e["name"] == "process_name"} \
-        == {"rank 0", "rank 1"}
-    # SPMD spans replicate onto both pids; rank-0 span stays on pid 0
-    epochs = [e for e in xs if e["name"] == "epoch"]
-    assert sorted(e["pid"] for e in epochs) == [0, 1]
-    assert all(e["args"]["spmd"] for e in epochs)
-    steps = [e for e in xs if e["name"] == "engine.step"]
-    assert [e["pid"] for e in steps] == [0]
-    # microseconds, comm lane separated
-    ep = epochs[0]
-    assert ep["ts"] == pytest.approx(1.0 * 1e6) and \
-        ep["dur"] == pytest.approx(1.0 * 1e6)
-    assert {e["tid"] for e in xs if e["cat"] == "comm"} == {LANE_COMM}
-
-
-def test_rank_traces_merge_and_reload(tmp_path):
-    spans = _synthetic_spans()
-    paths = obs.write_rank_traces(str(tmp_path), spans)
-    assert len(paths) == 2
-    merged_path = str(tmp_path / "merged.json")
-    merged = obs.merge_traces(str(tmp_path), out=merged_path)
-    xs = [e for e in merged["traceEvents"] if e["ph"] == "X"]
-    # 3 SPMD spans x 2 ranks + 1 rank-0 span
-    assert len(xs) == 7
-    meta = [e for e in merged["traceEvents"] if e["ph"] == "M"]
-    names = [(e["name"], e["pid"], e["tid"]) for e in meta]
-    assert len(names) == len(set(names)), "merge must dedupe metadata"
-    # a merged chrome file loads back into Span objects (rank = pid)
-    loaded = obs.load_spans(merged_path)
-    assert len(loaded) == 7
-    assert {s.rank for s in loaded} == {0, 1}
-
-
-def test_jsonl_roundtrip(tmp_path):
-    spans = _synthetic_spans()
-    path = obs.write_jsonl(str(tmp_path / "t.jsonl"), spans)
-    loaded = obs.load_spans(path)
-    assert loaded == spans
+def test_time_loop_is_bitwise_the_same_with_obs_enabled():
+    prog = _heat(32, 8)
+    c = api.compile(prog, api.Target())
+    u0 = jnp.asarray(
+        np.random.default_rng(0).standard_normal((32, 32)), jnp.float32)
+    want = np.asarray(c.time_loop((u0,), 6)[0])
+    obs.enable()
+    got = np.asarray(c.time_loop((u0,), 6)[0])
+    np.testing.assert_array_equal(got, want)
 
 
 # --------------------------------------------------------------------------
@@ -199,7 +237,8 @@ def test_snapshot_unifies_five_counter_islands():
     for ns in ("compile", "kernel", "serve", "checkpoint", "tune"):
         assert ns in snap, f"missing namespace {ns}"
         assert isinstance(snap[ns], dict) and snap[ns], snap[ns]
-    assert {"hits", "misses", "pipeline_runs"} <= set(snap["compile"])
+    assert {"hits", "misses", "pipeline_runs", "step_traces"} <= set(
+        snap["compile"])
     assert {"apply_calls", "pallas_calls"} <= set(snap["kernel"])
     assert "engines" in snap["serve"]
     assert {"saves", "restores"} <= set(snap["checkpoint"])
@@ -210,9 +249,7 @@ def test_snapshot_unifies_five_counter_islands():
 
 
 def test_snapshot_sees_live_traffic():
-    import numpy as np
-
-    from repro.api import Target, cache_stats, compile as api_compile
+    from repro.api import Target, compile as api_compile
     from repro.frontends.oec_like import ProgramBuilder
 
     p = ProgramBuilder("obs_snap", (8, 8))
@@ -228,87 +265,3 @@ def test_snapshot_sees_live_traffic():
     assert after["compile"]["pipeline_runs"] > before["compile"]["pipeline_runs"]
     total = after["compile"]["hits"] + after["compile"]["misses"]
     assert total > before["compile"]["hits"] + before["compile"]["misses"]
-
-
-# --------------------------------------------------------------------------
-# drift
-# --------------------------------------------------------------------------
-
-
-class _FixedTerms:
-    """RooflineTerms stand-in with a known modeled step time."""
-
-    def __init__(self, step_s):
-        self._s = step_s
-
-    def step_time(self, k):
-        return self._s
-
-
-def _drift_spans(epoch_dur=0.8, k=4):
-    spans = []
-    for e in range(2):
-        t0 = float(e)
-        spans.append(Span("epoch", "dispatch", ts=t0, dur=epoch_dur,
-                          args={"k": k, "epoch": e}))
-        # exchange window 0.2 wide; interior apply covers half of it
-        spans.append(Span("comm.exchange", "comm", ts=t0 + 0.1, dur=0.2,
-                          tid=LANE_COMM))
-        spans.append(Span("apply:interior", "compute", ts=t0 + 0.2, dur=0.3))
-    return spans
-
-
-def test_drift_report_synthetic():
-    rep = obs.drift_report(spans=_drift_spans(), terms=_FixedTerms(0.1))
-    assert rep.epochs == 2
-    assert rep.exchange_every == 4  # inferred from the epoch span's k tag
-    assert rep.measured_step_s == pytest.approx(0.8 / 4)
-    assert rep.modeled_step_s == pytest.approx(0.1)
-    assert rep.drift_ratio == pytest.approx(2.0)
-    assert rep.error_pct == pytest.approx(100.0)
-    # window [0.1, 0.3], apply covers [0.2, 0.3] -> half hidden
-    assert rep.overlap_windows == 2
-    assert rep.achieved_overlap == pytest.approx(0.5)
-    assert rep.per_phase_s["comm"] == pytest.approx(0.4)
-    text = str(rep)
-    assert "drift ratio" in text and "achieved overlap" in text
-    d = rep.as_dict()
-    assert d["drift_ratio"] == pytest.approx(2.0)
-
-
-def test_drift_report_without_model_or_epochs():
-    rep = obs.drift_report(spans=[])
-    assert rep.epochs == 0 and rep.measured_step_s is None
-    assert rep.drift_ratio is None and rep.achieved_overlap is None
-    rep = obs.drift_report(spans=_drift_spans())  # measured-only
-    assert rep.modeled_step_s is None and rep.drift_ratio is None
-    assert rep.achieved_overlap == pytest.approx(0.5)
-
-
-def test_obs_cli_summarizes_a_trace(tmp_path):
-    path = obs.write_chrome(str(tmp_path / "t.json"), _drift_spans())
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.obs", path, "--modeled-step", "0.1"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ,
-             "PYTHONPATH": os.path.join(os.path.dirname(WORKER), "..", "src")},
-    )
-    assert proc.returncode == 0, proc.stderr
-    assert "epoch" in proc.stdout and "drift" in proc.stdout
-
-
-# --------------------------------------------------------------------------
-# acceptance: traced 2-rank deep-halo run (subprocess, 8 virtual devices)
-# --------------------------------------------------------------------------
-
-
-def test_traced_two_rank_exchange_windows():
-    proc = subprocess.run(
-        [sys.executable, WORKER, "obs-trace-2rank"],
-        capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, (
-        f"obs-trace-2rank failed:\nSTDOUT:\n{proc.stdout}\n"
-        f"STDERR:\n{proc.stderr[-3000:]}"
-    )
-    assert "ok: obs-trace-2rank" in proc.stdout

@@ -84,6 +84,26 @@ def test_pallas_step(one_chip, n, order, knobs):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize(
+    "knobs,scope",
+    [({}, "stencil.apply"),
+     ({"exchange_every": 4, "fused_epoch": True}, "stencil.fused_epoch")],
+    ids=["per-apply", "fused-epoch-k4"],
+)
+def test_pallas_kernel_is_named_by_its_ir_op(one_chip, knobs, scope):
+    """A Pallas kernel takes its IR op's scope as its name: the TPU
+    custom call, and so the op on the device trace, is ``%<scope>.<n>``
+    under ``jit(<program>.step)/<scope>``, not an XLA-generated name."""
+    prog = _heat(2048, 2)
+    target = Target(backend="pallas", pallas_interpret=False, **knobs)
+    text = _compile_one_chip(prog, target, one_chip, 2048).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls
+    for line in calls:
+        assert f"%{scope}." in line.split(" = ", 1)[0]
+        assert f'jit({prog.name}.step)/{scope}/' in line
+
+
 def test_whole_shard_epoch_step(one_chip):
     """Wave's carried escape keeps a fused epoch untiled: at 1024² its
     whole shard fits the VMEM budget and compiles as one block."""
