@@ -27,8 +27,9 @@ ORDERS = (2, 4, 8)
 def run(fast: bool = False, tune: bool = False,
         fused_epoch: bool = False) -> dict:
     """``fused_epoch=True`` times the pallas epoch-megakernel target
-    (k=4, one kernel dispatch per epoch) instead of the default jnp
-    path; the recorded ``target`` dict carries the axes either way."""
+    (k=4, one kernel dispatch per epoch) instead of ``Target()``'s
+    resolved backend (Pallas on a TPU, jnp elsewhere); the recorded
+    ``target`` dict carries the axes either way."""
     cases = CASES if not fast else [(2, (256, 256), 4)]
     rows, record = [], {}
     for ndim, shape, steps in cases:

@@ -10,8 +10,11 @@ and ``serve.stencil.StencilEngine``):
 1. device check: the default device is a TPU and nothing asks for Pallas
    interpret mode;
 2. jnp path: 2-D heat, 16384^2 f32, space order 8, zero boundary,
-   ``Target()``, 64 steps, against a plain jitted f32 reference;
-3. per-apply Pallas: the same with ``Target(backend="pallas")``;
+   ``Target(backend="jnp")``, 64 steps, against a plain jitted f32
+   reference;
+3. per-apply Pallas: the same with ``Target()``, which resolves to
+   ``backend="pallas"`` on a TPU, reading its windows from the one
+   padded copy of the level (no ``window_source`` copy);
 4. fused-epoch Pallas: ``exchange_every=4, fused_epoch=True``, one kernel
    per epoch, bitwise against the unfused Pallas run at the same depth
    (DESIGN.md §10) and within tolerance of the reference;
@@ -126,22 +129,28 @@ class Smoke:
     def jnp_path(self) -> None:
         from repro import api
 
-        compiled = api.compile(self.op.program, api.Target())
+        compiled = api.compile(self.op.program, api.Target(backend="jnp"))
         self.check(timed_loop(compiled, (self.u0,), STEPS, "jnp"), "jnp")
 
     def pallas_apply(self) -> None:
         from repro import api, kernels
 
-        target = api.Target(backend="pallas")
-        if target.pallas_interpret is not False:
-            raise AssertionError("Target(backend='pallas') resolved to interpret mode")
+        target = api.Target()
+        if target.backend != "pallas" or target.pallas_interpret is not False:
+            raise AssertionError(
+                f"Target() resolved to backend={target.backend!r}, "
+                f"pallas_interpret={target.pallas_interpret!r} on a TPU"
+            )
         compiled = api.compile(self.op.program, target)
         kernels.reset_dispatch_stats()
         got = timed_loop(compiled, (self.u0,), STEPS, "pallas per-apply")
-        calls = kernels.dispatch_stats().apply_calls
-        print(f"pallas per-apply: {calls} apply kernel(s) traced", flush=True)
-        if calls <= 0:
+        stats = kernels.dispatch_stats()
+        print(f"pallas per-apply: {stats.apply_calls} apply kernel(s) traced, "
+              f"{stats.window_copies} window copies", flush=True)
+        if stats.apply_calls <= 0:
             raise AssertionError("no per-apply Pallas kernel was traced")
+        if stats.window_copies:
+            raise AssertionError("the kernel copied the padded level again")
         self.check(got, "pallas per-apply")
 
     def pallas_fused(self) -> None:

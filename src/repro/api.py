@@ -45,7 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import ir
 from repro.core.dialects import stencil
-from repro.core.lowering import StencilInterpreter
+from repro.core.lowering import StencilInterpreter, runs_pallas
 from repro.obs import trace as _obs
 from repro.core.passes import (
     PassManager,
@@ -143,7 +143,8 @@ class Target:
     """Frozen bundle of everything 'backend' about a compile.
 
     ``mesh``/``strategy`` describe the decomposition (both ``None`` =
-    single device); ``backend`` picks the compute lowering; ``pipeline``
+    single device); ``backend`` picks the compute lowering (``None``
+    resolves to the Pallas kernels on a TPU, jnp elsewhere); ``pipeline``
     is an explicit pass spec (DESIGN.md §2 grammar) overriding the
     ``fuse``/``cse``/``diagonal``/``overlap`` flags; the remaining knobs
     control pallas codegen and jit wrapping.  Validation happens here, at
@@ -153,7 +154,9 @@ class Target:
 
     mesh: Optional[Mesh] = None
     strategy: Optional[SlicingStrategy] = None
-    backend: str = "jnp"  # "jnp" | "pallas"
+    # "jnp" | "pallas"; None resolves via kernels.default_backend(): the
+    # Pallas kernels on a TPU, the jnp lowering (XLA fusions) elsewhere
+    backend: Optional[str] = None
     pipeline: Optional[str] = None
     fuse: bool = True
     cse: bool = True
@@ -196,6 +199,10 @@ class Target:
     jit: bool = True
 
     def __post_init__(self) -> None:
+        if self.backend is None:
+            from repro.kernels import default_backend
+
+            object.__setattr__(self, "backend", default_backend())
         if self.backend not in ("jnp", "pallas"):
             raise TargetError(
                 f"unknown backend {self.backend!r}; expected 'jnp' or 'pallas'"
@@ -602,24 +609,28 @@ class CompiledStencil:
     def kernel_dispatches(self) -> dict:
         """Static kernel-op census of one epoch of the compiled program:
         how many fused-epoch megakernels and how many standalone applies
-        the local IR executes per call.  With ``Target(fused_epoch=True)``
-        an epoched program reads ``{"fused_epoch": 1, "apply": 0, ...}`` —
-        one kernel dispatch per epoch (cross-checked at trace time by
+        the local IR executes per call, and how many of those applies
+        lower to the Pallas window kernel (``pallas_apply``; a split's
+        frames run jnp).  With ``Target(fused_epoch=True)`` an epoched
+        program reads ``{"fused_epoch": 1, "apply": 0, ...}`` — one kernel
+        dispatch per epoch (cross-checked at trace time by
         ``repro.kernels.dispatch_stats``)."""
         fused = sum(
             1
             for op in self.local_ir.body.ops
             if isinstance(op, stencil.FusedEpochOp)
         )
-        applies = sum(
-            1
-            for op in self.local_ir.body.ops
+        applies = [
+            op for op in self.local_ir.body.ops
             if isinstance(op, stencil.ApplyOp)
-        )
+        ]
         return {
             "fused_epoch": fused,
-            "apply": applies,
-            "total": fused + applies,
+            "apply": len(applies),
+            "pallas_apply": sum(
+                runs_pallas(op, self.target.backend) for op in applies
+            ),
+            "total": fused + len(applies),
         }
 
     def lower(self, dtype=jnp.float32):
@@ -1062,22 +1073,20 @@ def _build_inner(program: Program, target: Target) -> CompiledStencil:
         if target.mesh is not None
         else {}
     )
-    interp = StencilInterpreter(
-        local,
-        axis_sizes=axis_sizes,
-        distributed=distributed,
-        backend=target.backend,
-        pallas_interpret=target.pallas_interpret,
-        pallas_tile=target.pallas_tile,
-        name=f"{program.name}.step",
-    )
-    if target.backend == "pallas":
-        from repro.kernels import KernelPlanError
+    from repro.kernels import KernelPlanError
 
-        try:
-            interp.plan_kernels()
-        except KernelPlanError as e:
-            raise TargetError(f"program {program.name!r}: {e}") from e
+    try:  # a pallas interpreter plans its kernels as it is built
+        interp = StencilInterpreter(
+            local,
+            axis_sizes=axis_sizes,
+            distributed=distributed,
+            backend=target.backend,
+            pallas_interpret=target.pallas_interpret,
+            pallas_tile=target.pallas_tile,
+            name=f"{program.name}.step",
+        )
+    except KernelPlanError as e:
+        raise TargetError(f"program {program.name!r}: {e}") from e
     specs = partition_specs(program, strategy)
     # return arity/order comes from the LOCAL IR (first-store order):
     # an epoched carried-state program (wave, p > q) stores — and returns

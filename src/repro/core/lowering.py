@@ -17,7 +17,9 @@ Halo-exchange execution model (DESIGN.md §2) — one op-dispatch level,
 one path:
 
 - ``comm.halo_pad``       → boundary-condition pad (zeros, or wrap for
-                            periodic dims that are not decomposed);
+                            periodic dims that are not decomposed), plus
+                            the zero high-side slack the Pallas windows
+                            reading it need (``plan_kernels``);
 - ``comm.exchange_start`` → extract the send rectangle, ``lax.ppermute``
                             it toward ``-shift`` (pairs built by the
                             shared ``comm.permute_pairs``);
@@ -114,12 +116,15 @@ def eval_apply_body(
 # --------------------------------------------------------------------------
 
 
-def _pad_with_bc(x, lo: tuple, hi: tuple, grid: dmp.GridAttr, boundary: str):
+def _pad_with_bc(x, lo: tuple, hi: tuple, grid: dmp.GridAttr, boundary: str,
+                 slack: tuple = ()):
     """Grow ``x`` by halo widths; wrap-fill periodic *undecomposed* dims
     locally, everything else zeros (decomposed dims are filled by
     exchanges; zero-BC edges stay zero because non-cyclic permutes leave
-    non-receivers untouched)."""
+    non-receivers untouched).  ``slack[d]`` more zeros go past the high
+    halo of dim ``d``, in the same zero pad."""
     rank = x.ndim
+    slack = slack or (0,) * rank
     if boundary == "periodic":
         wrap_dims = [
             d
@@ -132,12 +137,13 @@ def _pad_with_bc(x, lo: tuple, hi: tuple, grid: dmp.GridAttr, boundary: str):
             ]
             x = jnp.pad(x, pad_widths, mode="wrap")
         zero_widths = [
-            (0, 0) if d in wrap_dims else (lo[d], hi[d]) for d in range(rank)
+            (0, slack[d]) if d in wrap_dims else (lo[d], hi[d] + slack[d])
+            for d in range(rank)
         ]
         if any(w != (0, 0) for w in zero_widths):
             x = jnp.pad(x, zero_widths)
         return x
-    pad_widths = [(lo[d], hi[d]) for d in range(rank)]
+    pad_widths = [(lo[d], hi[d] + slack[d]) for d in range(rank)]
     if any(w != (0, 0) for w in pad_widths):
         x = jnp.pad(x, pad_widths)
     return x
@@ -155,6 +161,39 @@ def scope(op: ir.Operation) -> str:
     stat; no ``:``, which ``tf_op`` uses as a separator."""
     part = op.attributes.get("part")
     return op.name if part is None else f"{op.name}.{part.value}"
+
+
+def runs_pallas(op: stencil.ApplyOp, backend: str) -> bool:
+    """Whether an apply lowers to the Pallas kernel: every apply of the
+    pallas backend but a split's thin boundary frames, which go through
+    the jnp evaluator (identical elementwise arithmetic, no per-slab
+    kernel launch)."""
+    part = op.attributes.get("part")
+    return backend == "pallas" and (part is None or part.value == "interior")
+
+
+def padded_level(value: ir.SSAValue) -> Optional[ir.SSAValue]:
+    """The ``comm.halo_pad`` result an apply operand reads, through any
+    ``comm.wait``, if every reader of it (and of the waits on it) indexes
+    it from its low bound: exchanges, waits and applies.  Such a value
+    may be allocated longer than its type on the high side; ``None``
+    otherwise."""
+    while isinstance(value, ir.OpResult) and isinstance(value.op, comm.WaitOp):
+        value = value.op.temp
+    if not (isinstance(value, ir.OpResult)
+            and isinstance(value.op, comm.HaloPadOp)):
+        return None
+    pending = [value]
+    while pending:
+        for use in pending.pop().uses:
+            reader = use.operation
+            if isinstance(reader, comm.WaitOp) and use.index == 0:
+                pending.append(reader.results[0])
+            elif not isinstance(
+                reader, (comm.WaitOp, comm.ExchangeStartOp, stencil.ApplyOp)
+            ):
+                return None
+    return value
 
 
 class StencilInterpreter:
@@ -192,6 +231,9 @@ class StencilInterpreter:
         self.backend = backend
         self.pallas_interpret = pallas_interpret
         self.pallas_tile = pallas_tile
+        # halo_pad result -> zero points its Pallas readers' windows need
+        # past its high bound, per dim
+        self.pad_slack = self.plan_kernels() if backend == "pallas" else {}
         self.output_fields: list[ir.SSAValue] = []
         for op in func.body.ops:
             if isinstance(op, stencil.StoreOp) and op.field not in self.output_fields:
@@ -253,7 +295,9 @@ class StencilInterpreter:
                     field_arr, patch, dst
                 )
         elif isinstance(op, comm.HaloPadOp):
-            env[op.results[0]] = _exec_halo_pad(op, env[op.operands[0]])
+            env[op.results[0]] = _exec_halo_pad(
+                op, env[op.operands[0]], self.pad_slack.get(op.results[0], ())
+            )
         elif isinstance(op, comm.ExchangeStartOp):
             env[op.results[0]] = self._exec_comm_start(op, env[op.temp])
         elif isinstance(op, comm.WaitOp):
@@ -279,14 +323,6 @@ class StencilInterpreter:
             raise NotImplementedError(f"function-level op {op.name}")
 
     # -- apply backends -------------------------------------------------
-    def _runs_pallas(self, op: stencil.ApplyOp) -> bool:
-        # thin boundary frames go through the jnp evaluator: identical
-        # elementwise arithmetic, no per-slab kernel launch
-        part = op.attributes.get("part")
-        return self.backend == "pallas" and (
-            part is None or part.value == "interior"
-        )
-
     def _apply_tile(self, op: stencil.ApplyOp) -> Optional[tuple]:
         """The user tile for one pallas apply, or ``None`` (auto-tile): a
         split interior (or an epoch-tiled apply, whose grown frame changes
@@ -304,21 +340,33 @@ class StencilInterpreter:
             return None
         return tile
 
-    def plan_kernels(self) -> None:
+    def plan_kernels(self) -> dict:
         """Lay out every Pallas kernel of the function without tracing:
         raises ``KernelPlanError`` (naming the sizes) for a tile or VMEM
-        budget the TPU cannot take — at compile time, not first call."""
+        budget the TPU cannot take — at construction, not first call.
+        Returns how far past its high bound each ``comm.halo_pad`` must
+        reach for the apply windows that read it to lie inside it, so
+        the kernels read the padded level in place (``padded_level``,
+        ``stencil_apply.high_slack``)."""
         from repro.kernels.epoch_kernel import plan_epoch
-        from repro.kernels.stencil_apply import plan_apply
+        from repro.kernels.stencil_apply import high_slack, plan_apply
 
+        slack: dict = {}
         for op in self.func.body.ops:
-            if isinstance(op, stencil.ApplyOp) and self._runs_pallas(op):
-                plan_apply(op, op.result_bounds, self._apply_tile(op))
+            if isinstance(op, stencil.ApplyOp) and runs_pallas(op, self.backend):
+                rb = op.result_bounds
+                tile = plan_apply(op, rb, self._apply_tile(op))
+                for v, extra in zip(op.operands, high_slack(op, rb, tile)):
+                    pad = padded_level(v)
+                    if pad is not None and extra is not None and any(extra):
+                        old = slack.get(pad, extra)
+                        slack[pad] = tuple(map(max, old, extra))
             elif isinstance(op, stencil.FusedEpochOp) and op.results:
                 plan_epoch(op, self.pallas_tile)
+        return slack
 
     def _apply_backend(self, op, arrays, origins, rb):
-        if self._runs_pallas(op):
+        if runs_pallas(op, self.backend):
             from repro.kernels.stencil_apply import run_apply_pallas
 
             return run_apply_pallas(
@@ -444,13 +492,14 @@ def boundary_keep(op: comm.BoundaryMaskOp, shape: tuple, coords, shift):
     return keep
 
 
-def _exec_halo_pad(op: comm.HaloPadOp, x):
+def _exec_halo_pad(op: comm.HaloPadOp, x, slack: tuple = ()):
     ib: stencil.Bounds = op.operands[0].type.bounds
     ob: stencil.Bounds = op.results[0].type.bounds
     lo = tuple(i - o for i, o in zip(ib.lb, ob.lb))
     hi = tuple(o - i for o, i in zip(ob.ub, ib.ub))
     return _pad_with_bc(
-        x, lo, hi, op.attributes["grid"], op.attributes["boundary"].value
+        x, lo, hi, op.attributes["grid"], op.attributes["boundary"].value,
+        slack,
     )
 
 

@@ -17,10 +17,14 @@ output tile grown by the operand's access extent and rounded up to whole
 at a multiple of the tile, so each element-indexed block (``pl.Element``)
 has an aligned shape and offset.  A tile need not divide the result: the
 grid rounds up and Pallas drops the last tile's overhang (epoch-grown
-applies have extents like 16384 + 24 that no aligned tile divides).  The
-operand is sliced and zero-padded in XLA beforehand (``window_source``)
-so every window lies inside the array; the padding feeds only the
-dropped overhang or rounding slack that is never read.  The tile is chosen
+applies have extents like 16384 + 24 that no aligned tile divides).
+Every window must lie inside the operand array, so the last one needs
+``high_slack`` points past the operand's high bound: rounding slack and
+the last tile's dropped overhang, never read into a kept output.  An
+operand that comes from a ``comm.halo_pad`` gets that slack from the pad
+itself (the lowering plans it at compile time) and is read in place;
+any other is re-based and zero-padded in XLA first (``window_source``,
+counted in ``dispatch_stats().window_copies``).  The tile is chosen
 for the fewest HBM bytes fetched per output point whose *whole-kernel*
 VMEM footprint — every window and output block double-buffered, plus
 value temporaries — fits ``VMEM_BUDGET_BYTES``.
@@ -178,24 +182,36 @@ def choose_tile(
     return best
 
 
-def window_source(arr, base, shape, tile, window):
-    """``arr`` re-based for element-indexed windows: window ``i`` along
-    each dim starts at ``i * tile`` and the last one (for an output of
-    ``shape``) ends inside the array.  Slices off ``base`` leading points,
-    then trims or zero-pads the high end; the padding feeds only rounding
-    slack and the last tile's dropped overhang."""
-    need = tuple(
+def window_reach(shape, tile, window) -> tuple:
+    """Points from the first window's origin to the last window's end
+    along each dim, over an output of ``shape``."""
+    return tuple(
         (g - 1) * t + w
         for g, t, w in zip(grid_shape(shape, tile), tile, window)
     )
-    avail = tuple(min(s - b, m) for s, b, m in zip(arr.shape, base, need))
-    if any(base) or avail != tuple(arr.shape):
+
+
+def window_source(arr, base, shape, tile, window):
+    """``arr`` re-based for element-indexed windows: window ``i`` along
+    each dim starts at ``i * tile`` and the last one (for an output of
+    ``shape``) ends inside the array.  Read in place when ``base`` is 0
+    and the array reaches far enough; otherwise slices off ``base``
+    leading points and zero-pads the high end (a copy, counted in
+    ``dispatch_stats().window_copies``)."""
+    need = window_reach(shape, tile, window)
+    copied = False
+    if any(base):
+        avail = tuple(min(s - b, m) for s, b, m in zip(arr.shape, base, need))
         arr = lax.slice(
             arr, tuple(base), tuple(b + a for b, a in zip(base, avail))
         )
-    pad = [(0, m - a) for m, a in zip(need, avail)]
+        copied = True
+    pad = [(0, max(0, m - s)) for m, s in zip(need, arr.shape)]
     if any(hi for _, hi in pad):
         arr = jnp.pad(arr, pad)
+        copied = True
+    if copied:
+        _DISPATCH.window_copies += 1
     return arr
 
 
@@ -244,6 +260,29 @@ def plan_apply(
     if tile is not None:
         return check_tile(shape, tile)
     return choose_tile(shape, spans, n_out=len(apply_op.results))
+
+
+def high_slack(
+    apply_op: stencil.ApplyOp, result_bounds: stencil.Bounds, tile: tuple
+) -> list:
+    """Per operand, the points its last window reaches past the high
+    bound of the operand's type along each dim (0 where it ends inside):
+    how much longer an array must be for ``window_source`` to read it in
+    place.  ``None`` for an operand whose first window starts past its low
+    bound: ``window_source`` re-bases (copies) it whatever its length."""
+    rb = result_bounds
+    out = []
+    for v, (lo, hi) in zip(apply_op.operands, apply_spans(apply_op)):
+        ob = v.type.bounds
+        if any(r + l != o for r, l, o in zip(rb.lb, lo, ob.lb)):
+            out.append(None)
+            continue
+        window = window_shape(tile, [h - l for l, h in zip(lo, hi)])
+        reach = window_reach(rb.shape, tile, window)
+        out.append(tuple(
+            max(0, o + m - u) for o, m, u in zip(ob.lb, reach, ob.ub)
+        ))
+    return out
 
 
 def build_apply_kernel(
@@ -307,7 +346,8 @@ def run_apply_pallas(
     name: Optional[str] = None,
 ) -> list:
     """Entry point used by the lowering's pallas backend: ``arrays[k]``
-    holds logical points from ``origins[k]`` on.  Each call is one traced
+    holds logical points from ``origins[k]`` on (and may run past the
+    operand's high bound: see ``high_slack``).  Each call is one traced
     pallas_call (counted in ``kernels.dispatch_stats``), named ``name``."""
     tile = plan_apply(apply_op, result_bounds, tile)
     rb = result_bounds

@@ -7,6 +7,8 @@ acceptance property that all three frontends compile through
 ``repro.api.compile`` with one shared Target, and the persistent
 compile-cache location.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -179,6 +181,61 @@ def test_target_fingerprint_distinguishes_knobs():
         Target(pipeline="decompose,swap-elim,lower-comm").fingerprint
         != Target().fingerprint
     )
+
+
+# -------------------------------------------------------------------------
+# the compute backend: resolved from the platform unless given
+# -------------------------------------------------------------------------
+
+
+def _on_platform(monkeypatch, platform: str) -> None:
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+
+
+def test_target_backend_resolves_to_jnp_off_a_tpu():
+    import jax
+
+    assert jax.default_backend() != "tpu"
+    t = Target()
+    assert t.backend == "jnp"
+    assert t.fingerprint == Target(backend="jnp").fingerprint
+
+
+def test_target_backend_resolves_to_pallas_on_a_tpu(monkeypatch):
+    _on_platform(monkeypatch, "tpu")
+    t = Target()
+    assert t.backend == "pallas"
+    assert t.pallas_interpret is False
+    assert t.fingerprint == Target(backend="pallas").fingerprint
+    # the epoch megakernel needs no backend named on a TPU
+    assert Target(exchange_every=2, fused_epoch=True).backend == "pallas"
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_target_backend_given_is_kept(monkeypatch, platform, backend):
+    _on_platform(monkeypatch, platform)
+    t = Target(backend=backend)
+    assert t.backend == backend
+    assert dataclasses.replace(t, overlap=True).backend == backend
+
+
+def test_target_fused_epoch_without_backend_errors_off_a_tpu():
+    with pytest.raises(TargetError, match="backend='pallas'"):
+        Target(exchange_every=2, fused_epoch=True)
+
+
+@pytest.mark.parametrize("backend,kernels", [("pallas", 1), ("jnp", 0)])
+def test_kernel_dispatches_counts_pallas_applies(backend, kernels):
+    from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+
+    u = TimeFunction(name="u", grid=Grid(shape=(32, 32)), space_order=8)
+    prog = Operator(Eq(u.dt, u.laplace), dt=0.1, boundary="zero").program
+    compiled = api.compile(prog, Target(backend=backend, exchange_every=1))
+    assert compiled.kernel_dispatches["apply"] == 1
+    assert compiled.kernel_dispatches["pallas_apply"] == kernels
 
 
 # -------------------------------------------------------------------------

@@ -102,7 +102,9 @@ def test_fused_epoch_is_one_dispatch():
     the static IR census (kernel_dispatches) agrees."""
     prog = _heat()
     fused = api.compile(prog, _fused(4))
-    assert fused.kernel_dispatches == {"fused_epoch": 1, "apply": 0, "total": 1}
+    assert fused.kernel_dispatches == {
+        "fused_epoch": 1, "apply": 0, "pallas_apply": 0, "total": 1
+    }
     u0 = np.random.default_rng(0).standard_normal((16, 16)).astype(np.float32)
     kernels.reset_dispatch_stats()
     fused(u0, np.zeros_like(u0))
@@ -115,7 +117,9 @@ def test_fused_epoch_is_one_dispatch():
 def test_unfused_epoch_is_k_dispatches():
     prog = _heat()
     unfused = api.compile(prog, _unfused(4))
-    assert unfused.kernel_dispatches == {"fused_epoch": 0, "apply": 4, "total": 4}
+    assert unfused.kernel_dispatches == {
+        "fused_epoch": 0, "apply": 4, "pallas_apply": 4, "total": 4
+    }
     u0 = np.random.default_rng(0).standard_normal((16, 16)).astype(np.float32)
     kernels.reset_dispatch_stats()
     unfused(u0, np.zeros_like(u0))

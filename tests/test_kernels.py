@@ -186,3 +186,50 @@ def test_kernel_backend_equals_jnp_backend_end_to_end():
     np.testing.assert_allclose(
         np.asarray(r_jnp[0]), np.asarray(r_pal[0]), rtol=1e-5, atol=1e-6
     )
+
+
+# -------------------------------------------------------------------------
+# window slack: the halo pad allocates what the last window overhangs
+# -------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "n,order,tile,boundary",
+    [
+        (1000, 8, (64, 256), "zero"),
+        (200, 8, None, "zero"),
+        (300, 4, (64, 128), "zero"),
+        (96, 2, None, "periodic"),
+    ],
+)
+def test_windows_read_the_padded_level_in_place(n, order, tile, boundary):
+    """Shapes whose last window overhangs the halo-padded operand: the
+    ``comm.halo_pad`` allocates the slack, the kernel reads it in place
+    (no ``window_source`` copy), and 4 jitted steps are bitwise equal to
+    the jnp lowering's.  A window clamped at the array's end instead
+    (the pad left out, nothing allocated) differs at 1000² so8."""
+    from repro import api, kernels
+    from repro.api import Target
+    from repro.core.dialects import stencil
+    from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+    from repro.kernels.stencil_apply import high_slack, plan_apply
+
+    u = TimeFunction(name="u", grid=Grid(shape=(n, n)), space_order=order)
+    prog = Operator(Eq(u.dt, u.laplace), dt=0.1, boundary=boundary).program
+    fast = api.compile(prog, Target(backend="pallas", pallas_tile=tile,
+                                    pallas_interpret=True))
+    (apply_op,) = [
+        op for op in fast.local_ir.body.ops if isinstance(op, stencil.ApplyOp)
+    ]
+    rb = apply_op.result_bounds
+    (slack,) = high_slack(apply_op, rb, plan_apply(apply_op, rb, tile))
+    assert any(slack)
+    u0 = (jnp.asarray(_rand((n, n), seed=n)),)
+    want = jax.jit(
+        lambda s: api.compile(prog, Target(backend="jnp")).time_loop(s, 4)
+    )(u0)
+    kernels.reset_dispatch_stats()
+    got = jax.jit(lambda s: fast.time_loop(s, 4))(u0)
+    assert kernels.dispatch_stats().apply_calls == 1
+    assert kernels.dispatch_stats().window_copies == 0
+    np.testing.assert_array_equal(np.asarray(got[-1]), np.asarray(want[-1]))

@@ -104,6 +104,18 @@ def test_pallas_kernel_is_named_by_its_ir_op(one_chip, knobs, scope):
         assert f'jit({prog.name}.step)/{scope}/' in line
 
 
+def test_pallas_step_16384_so8_pads_once(one_chip):
+    """The Pallas kernel reads its windows from the step's one padded
+    copy of the level: the halo pad allocates the 120 lanes the last
+    window overhangs, and no second full-size pad feeds the kernel."""
+    target = Target(backend="pallas", pallas_interpret=False)
+    text = _compile_one_chip(_heat(16384, 8), target, one_chip, 16384).as_text()
+    pads = [line for line in text.splitlines() if " pad(" in line]
+    assert len(pads) == 1
+    assert "f32[16392,16512]" in pads[0]
+    assert "comm.halo_pad" in pads[0]
+
+
 def test_whole_shard_epoch_step(one_chip):
     """Wave's carried escape keeps a fused epoch untiled: at 1024² its
     whole shard fits the VMEM budget and compiles as one block."""
@@ -130,3 +142,25 @@ def test_2x2_jnp_step_k4(topo):
     compiled = jax.jit(step).lower(x).compile()
     assert "collective-permute" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _peak_2x2_loop(topo, backend: str) -> int:
+    """``memory_analysis()`` peak of the 2x2 cell's program: 65536²
+    so8 heat decomposed 2x2, a jitted 16-step ``time_loop``."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("x", "y"))
+    target = Target(mesh=mesh, strategy=make_strategy_2d((2, 2)),
+                    backend=backend, pallas_interpret=False)
+    compiled = api.compile(_heat(65536, 8), target)
+    x = jax.ShapeDtypeStruct(
+        (65536, 65536), jnp.float32, sharding=NamedSharding(mesh, P("x", "y"))
+    )
+    exe = jax.jit(lambda s: compiled.time_loop(s, 16)).lower((x,)).compile()
+    return exe.memory_analysis().peak_memory_in_bytes
+
+
+def test_2x2_pallas_loop_fits_like_jnp(topo):
+    """The Pallas loop on 32768² shards holds no second padded copy: its
+    peak stays within 1% of the jnp loop's, inside one chip's HBM."""
+    pallas = _peak_2x2_loop(topo, "pallas")
+    assert pallas <= 1.01 * _peak_2x2_loop(topo, "jnp")
+    assert pallas < HBM_BYTES
