@@ -13,8 +13,9 @@ and ``serve.stencil.StencilEngine``):
    ``Target(backend="jnp")``, 64 steps, against a plain jitted f32
    reference;
 3. per-apply Pallas: the same with ``Target()``, which resolves to
-   ``backend="pallas"`` on a TPU, reading its windows from the one
-   padded copy of the level (no ``window_source`` copy);
+   ``backend="pallas"`` on a TPU, reading its windows from the unpadded
+   level and building the zero halo itself (no ``comm.halo_pad`` copy,
+   no ``window_source`` copy);
 4. fused-epoch Pallas: ``exchange_every=4, fused_epoch=True``, one kernel
    per epoch, bitwise against the unfused Pallas run at the same depth
    (DESIGN.md §10) and within tolerance of the reference;
@@ -142,15 +143,19 @@ class Smoke:
                 f"pallas_interpret={target.pallas_interpret!r} on a TPU"
             )
         compiled = api.compile(self.op.program, target)
+        copies = compiled.kernel_dispatches["halo_pad_copies"]
         kernels.reset_dispatch_stats()
         got = timed_loop(compiled, (self.u0,), STEPS, "pallas per-apply")
         stats = kernels.dispatch_stats()
         print(f"pallas per-apply: {stats.apply_calls} apply kernel(s) traced, "
-              f"{stats.window_copies} window copies", flush=True)
+              f"{stats.window_copies} window copies, {copies} halo pad "
+              "copies", flush=True)
         if stats.apply_calls <= 0:
             raise AssertionError("no per-apply Pallas kernel was traced")
         if stats.window_copies:
-            raise AssertionError("the kernel copied the padded level again")
+            raise AssertionError("the kernel copied an operand window")
+        if copies:
+            raise AssertionError("the zero halo pad still copies the level")
         self.check(got, "pallas per-apply")
 
     def pallas_fused(self) -> None:

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import math
 import os
 import threading
 from collections import OrderedDict
@@ -45,7 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import ir
 from repro.core.dialects import comm, stencil
-from repro.core.lowering import StencilInterpreter, runs_pallas
+from repro.core.lowering import StencilInterpreter, pad_folds, runs_pallas
 from repro.obs import trace as _obs
 from repro.core.passes import (
     PassManager,
@@ -610,9 +611,13 @@ class CompiledStencil:
         the fori-loop driver share one rotation rule (``rotate``)."""
         return rotate(state, self.step()(*state), len(self.static_indices))
 
-    def time_loop(self, state: Sequence[Any], n_steps: int, unroll: int = 1):
+    def time_loop(
+        self, state: Sequence[Any], n_steps: int, unroll: Optional[int] = None
+    ):
         """Iterate ``n_steps`` *time steps* with time-buffer rotation
-        (``state`` ordered oldest→newest) under one ``lax.fori_loop``.
+        (``state`` ordered oldest→newest) under one ``lax.fori_loop``
+        that runs ``unroll`` epochs an iteration (``loop_unroll`` by
+        default).
 
         ``n_steps`` always counts single time steps regardless of the
         target's ``exchange_every``: the loop runs ``self.epochs(n_steps)``
@@ -627,8 +632,35 @@ class CompiledStencil:
                        static=static):
             return time_loop(
                 self.step(), tuple(state), self.epochs(n_steps),
-                unroll=unroll, static=static,
+                unroll=unroll or self.loop_unroll, static=static,
             )
+
+    @property
+    def loop_unroll(self) -> int:
+        """Epochs an iteration of ``time_loop``'s loop runs.  Where a
+        Pallas kernel reads a time level in place (directly, or through a
+        pad ``lowering.pad_folds`` folds), the epoch's new level cannot
+        take that level's buffer, and with one epoch an iteration XLA
+        copies a level every epoch to make room.  Running as many epochs
+        as it takes the ``n + k`` buffers of ``n`` levels and ``k`` new
+        ones, rotating by ``k`` an epoch, to come back round lets XLA
+        alternate them and copy none (2 for one level)."""
+        backend = self.target.backend
+        fields = [a for a in self.local_ir.body.args
+                  if isinstance(a.type, stencil.FieldType)]
+        static = set(self.static_indices)
+        levels = {fields[i] for i in self.input_indices if i not in static}
+        in_place = any(
+            (isinstance(r, comm.HaloPadOp) and pad_folds(r, backend))
+            or (isinstance(r, stencil.ApplyOp) and runs_pallas(r, backend))
+            for op in self.local_ir.body.ops
+            if isinstance(op, stencil.LoadOp) and op.field in levels
+            for r in (use.operation for use in op.results[0].uses)
+        )
+        if not in_place:
+            return 1
+        n, k = len(levels), len(self.ret_indices)
+        return (n + k) // math.gcd(n + k, k)
 
     # -- inspection ------------------------------------------------------
     @property
@@ -637,8 +669,10 @@ class CompiledStencil:
         how many fused-epoch megakernels and how many standalone applies
         the local IR executes per call, how many of those applies lower
         to the Pallas window kernel (``pallas_apply``; a split's frames
-        run jnp), and how many ``comm.halo_pad`` copies it makes
-        (``halo_pad``).  With ``Target(fused_epoch=True)`` an epoched
+        run jnp), how many ``comm.halo_pad`` ops the IR holds
+        (``halo_pad``), and how many of those still copy their operand
+        (``halo_pad_copies``: the pads ``lowering.pad_folds`` leaves).
+        With ``Target(fused_epoch=True)`` an epoched
         program reads ``{"fused_epoch": 1, "apply": 0, ...}`` — one kernel
         dispatch per epoch (cross-checked at trace time by
         ``repro.kernels.dispatch_stats``)."""
@@ -651,14 +685,19 @@ class CompiledStencil:
             op for op in self.local_ir.body.ops
             if isinstance(op, stencil.ApplyOp)
         ]
+        pads = [
+            op for op in self.local_ir.body.ops
+            if isinstance(op, comm.HaloPadOp)
+        ]
         return {
             "fused_epoch": fused,
             "apply": len(applies),
             "pallas_apply": sum(
                 runs_pallas(op, self.target.backend) for op in applies
             ),
-            "halo_pad": sum(
-                isinstance(op, comm.HaloPadOp) for op in self.local_ir.body.ops
+            "halo_pad": len(pads),
+            "halo_pad_copies": sum(
+                not pad_folds(op, self.target.backend) for op in pads
             ),
             "total": fused + len(applies),
         }

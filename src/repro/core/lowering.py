@@ -17,9 +17,10 @@ Halo-exchange execution model (DESIGN.md §2) — one op-dispatch level,
 one path:
 
 - ``comm.halo_pad``       → boundary-condition pad (zeros, or wrap for
-                            periodic dims that are not decomposed), plus
-                            the zero high-side slack the Pallas windows
-                            reading it need (``plan_kernels``);
+                            periodic dims that are not decomposed); a pad
+                            whose halo can only hold zeros and that only
+                            Pallas kernels read lowers to nothing
+                            (``pad_folds``): the kernels build the halo;
 - ``comm.exchange_start`` → extract the send rectangle, ``lax.ppermute``
                             it toward ``-shift`` (pairs built by the
                             shared ``comm.permute_pairs``);
@@ -121,15 +122,12 @@ def eval_apply_body(
 # --------------------------------------------------------------------------
 
 
-def _pad_with_bc(x, lo: tuple, hi: tuple, grid: dmp.GridAttr, boundary: str,
-                 slack: tuple = ()):
+def _pad_with_bc(x, lo: tuple, hi: tuple, grid: dmp.GridAttr, boundary: str):
     """Grow ``x`` by halo widths; wrap-fill periodic *undecomposed* dims
     locally, everything else zeros (decomposed dims are filled by
     exchanges; zero-BC edges stay zero because non-cyclic permutes leave
-    non-receivers untouched).  ``slack[d]`` more zeros go past the high
-    halo of dim ``d``, in the same zero pad."""
+    non-receivers untouched)."""
     rank = x.ndim
-    slack = slack or (0,) * rank
     if boundary == "periodic":
         wrap_dims = [
             d
@@ -142,13 +140,12 @@ def _pad_with_bc(x, lo: tuple, hi: tuple, grid: dmp.GridAttr, boundary: str,
             ]
             x = jnp.pad(x, pad_widths, mode="wrap")
         zero_widths = [
-            (0, slack[d]) if d in wrap_dims else (lo[d], hi[d] + slack[d])
-            for d in range(rank)
+            (0, 0) if d in wrap_dims else (lo[d], hi[d]) for d in range(rank)
         ]
         if any(w != (0, 0) for w in zero_widths):
             x = jnp.pad(x, zero_widths)
         return x
-    pad_widths = [(lo[d], hi[d] + slack[d]) for d in range(rank)]
+    pad_widths = [(lo[d], hi[d]) for d in range(rank)]
     if any(w != (0, 0) for w in pad_widths):
         x = jnp.pad(x, pad_widths)
     return x
@@ -177,28 +174,46 @@ def runs_pallas(op: stencil.ApplyOp, backend: str) -> bool:
     return backend == "pallas" and (part is None or part.value == "interior")
 
 
-def padded_level(value: ir.SSAValue) -> Optional[ir.SSAValue]:
-    """The ``comm.halo_pad`` result an apply operand reads, through any
-    ``comm.wait``, if every reader of it (and of the waits on it) indexes
-    it from its low bound: exchanges, waits and applies.  Such a value
-    may be allocated longer than its type on the high side; ``None``
-    otherwise."""
+def halo_source(value: ir.SSAValue) -> Optional[ir.SSAValue]:
+    """The ``comm.halo_pad`` result ``value`` is, through any
+    ``comm.wait``s, or ``None``."""
     while isinstance(value, ir.OpResult) and isinstance(value.op, comm.WaitOp):
         value = value.op.temp
-    if not (isinstance(value, ir.OpResult)
-            and isinstance(value.op, comm.HaloPadOp)):
-        return None
-    pending = [value]
+    if isinstance(value, ir.OpResult) and isinstance(value.op, comm.HaloPadOp):
+        return value
+    return None
+
+
+def pad_folds(op: comm.HaloPadOp, backend: str) -> bool:
+    """Whether a ``comm.halo_pad`` lowers to nothing, its halo built by
+    the kernels that read it: the boundary is zero; every dim it pads is
+    undecomposed or on a grid axis of size 1, so its exchanges deliver
+    only zeros; and every reader of it, through ``comm.wait``s, is a
+    Pallas apply, a wait or an exchange start.  A periodic halo holds
+    wrapped data, and a decomposed dim's halo received rows: those keep
+    the copy.  ``CompiledStencil.kernel_dispatches["halo_pad_copies"]``
+    counts the pads this leaves."""
+    if op.boundary != "zero":
+        return False
+    grid: dmp.GridAttr = op.attributes["grid"]
+    ib, ob = op.temp.type.bounds, op.results[0].type.bounds
+    for d in range(ib.rank):
+        gax = grid.axis_of_dim(d)
+        padded = ib.lb[d] != ob.lb[d] or ib.ub[d] != ob.ub[d]
+        if padded and gax is not None and grid.shape[gax] > 1:
+            return False
+    pending = [op.results[0]]
     while pending:
         for use in pending.pop().uses:
             reader = use.operation
-            if isinstance(reader, comm.WaitOp) and use.index == 0:
+            if isinstance(reader, comm.WaitOp):
                 pending.append(reader.results[0])
-            elif not isinstance(
-                reader, (comm.WaitOp, comm.ExchangeStartOp, stencil.ApplyOp)
-            ):
-                return None
-    return value
+            elif isinstance(reader, stencil.ApplyOp):
+                if not runs_pallas(reader, backend):
+                    return False
+            elif not isinstance(reader, comm.ExchangeStartOp):
+                return False
+    return True
 
 
 class StencilInterpreter:
@@ -236,9 +251,13 @@ class StencilInterpreter:
         self.backend = backend
         self.pallas_interpret = pallas_interpret
         self.pallas_tile = pallas_tile
-        # halo_pad result -> zero points its Pallas readers' windows need
-        # past its high bound, per dim
-        self.pad_slack = self.plan_kernels() if backend == "pallas" else {}
+        # halo_pad results that lower to nothing (``pad_folds``)
+        self.folded = {
+            op.results[0] for op in func.body.ops
+            if isinstance(op, comm.HaloPadOp) and pad_folds(op, backend)
+        }
+        if backend == "pallas":
+            self.plan_kernels()
         self.output_fields: list[ir.SSAValue] = []
         for op in func.body.ops:
             if isinstance(op, stencil.StoreOp) and op.field not in self.output_fields:
@@ -276,8 +295,7 @@ class StencilInterpreter:
         elif isinstance(op, stencil.ApplyOp):
             rb = op.result_bounds
             arrays = [env[o] for o in op.operands]
-            origins = [o.type.bounds.lb for o in op.operands]
-            outs = self._apply_backend(op, arrays, origins, rb)
+            outs = self._apply_backend(op, arrays, rb)
             for res, arr in zip(op.results, outs):
                 env[res] = arr
         elif isinstance(op, stencil.CombineOp):
@@ -300,9 +318,15 @@ class StencilInterpreter:
                     field_arr, patch, dst
                 )
         elif isinstance(op, comm.HaloPadOp):
-            env[op.results[0]] = _exec_halo_pad(
-                op, env[op.operands[0]], self.pad_slack.get(op.results[0], ())
+            x = env[op.operands[0]]
+            env[op.results[0]] = (
+                x if op.results[0] in self.folded else _exec_halo_pad(op, x)
             )
+        elif (isinstance(op, (comm.ExchangeStartOp, comm.WaitOp))
+              and halo_source(op.operands[0]) in self.folded):
+            # an exchange or wait on a folded pad: its halo is all zeros
+            if isinstance(op, comm.WaitOp):
+                env[op.results[0]] = env[op.temp]
         elif isinstance(op, comm.ExchangeStartOp):
             env[op.results[0]] = self._exec_comm_start(op, env[op.temp])
         elif isinstance(op, comm.WaitOp):
@@ -345,45 +369,49 @@ class StencilInterpreter:
             return None
         return tile
 
-    def plan_kernels(self) -> dict:
+    def plan_kernels(self) -> None:
         """Lay out every Pallas kernel of the function without tracing:
         raises ``KernelPlanError`` (naming the sizes) for a tile or VMEM
-        budget the TPU cannot take — at construction, not first call.
-        Returns how far past its high bound each ``comm.halo_pad`` must
-        reach for the apply windows that read it to lie inside it, so
-        the kernels read the padded level in place (``padded_level``,
-        ``stencil_apply.high_slack``)."""
+        budget the TPU cannot take — at construction, not first call."""
         from repro.kernels.epoch_kernel import plan_epoch
-        from repro.kernels.stencil_apply import high_slack, plan_apply
+        from repro.kernels.stencil_apply import plan_apply
 
-        slack: dict = {}
         for op in self.func.body.ops:
             if isinstance(op, stencil.ApplyOp) and runs_pallas(op, self.backend):
-                rb = op.result_bounds
-                tile = plan_apply(op, rb, self._apply_tile(op))
-                for v, extra in zip(op.operands, high_slack(op, rb, tile)):
-                    pad = padded_level(v)
-                    if pad is not None and extra is not None and any(extra):
-                        old = slack.get(pad, extra)
-                        slack[pad] = tuple(map(max, old, extra))
+                plan_apply(op, op.result_bounds, self._apply_tile(op),
+                           self._sources(op))
             elif isinstance(op, stencil.FusedEpochOp) and op.results:
                 plan_epoch(op, self.pallas_tile)
-        return slack
 
-    def _apply_backend(self, op, arrays, origins, rb):
+    def _sources(self, op: stencil.ApplyOp) -> list:
+        """Per operand, the bounds of the array it is read from and
+        whether it reads as zero outside them: a folded pad's operand
+        (``pad_folds``) is passed unpadded, with a zero halo."""
+        out = []
+        for v in op.operands:
+            pad = halo_source(v)
+            if pad in self.folded:
+                out.append((pad.op.temp.type.bounds, True))
+            else:
+                out.append((v.type.bounds, False))
+        return out
+
+    def _apply_backend(self, op, arrays, rb):
+        sources = self._sources(op)
         if runs_pallas(op, self.backend):
             from repro.kernels.stencil_apply import run_apply_pallas
 
             return run_apply_pallas(
                 op,
                 arrays,
-                origins,
+                [b.lb for b, _ in sources],
                 rb,
                 tile=self._apply_tile(op),
                 interpret=self.pallas_interpret,
                 name=scope(op),
+                zero=[z for _, z in sources],
             )
-        return eval_apply_body(op, arrays, origins, rb)
+        return eval_apply_body(op, arrays, [b.lb for b, _ in sources], rb)
 
     def _exec_combine(self, op: stencil.CombineOp, env):
         rb = op.result_bounds
@@ -497,14 +525,13 @@ def boundary_keep(op: comm.BoundaryMaskOp, shape: tuple, coords, shift):
     return keep
 
 
-def _exec_halo_pad(op: comm.HaloPadOp, x, slack: tuple = ()):
+def _exec_halo_pad(op: comm.HaloPadOp, x):
     ib: stencil.Bounds = op.operands[0].type.bounds
     ob: stencil.Bounds = op.results[0].type.bounds
     lo = tuple(i - o for i, o in zip(ib.lb, ob.lb))
     hi = tuple(o - i for o, i in zip(ob.ub, ib.ub))
     return _pad_with_bc(
-        x, lo, hi, op.attributes["grid"], op.attributes["boundary"].value,
-        slack,
+        x, lo, hi, op.attributes["grid"], op.attributes["boundary"].value
     )
 
 

@@ -690,6 +690,29 @@ def scenario_scopes_2x2(k):
           f"{len(writes)} halo writes)")
 
 
+def scenario_pallas_2x2_received_halo():
+    """The 2x2 heat cell's path at a small grid: every shard receives a
+    halo, so its ``comm.halo_pad`` keeps the copy (``halo_pad_copies``
+    1) while the Pallas kernel reads that copy with its last windows'
+    overhang as Mosaic high padding; 4 jitted steps equal the jnp
+    lowering's on the same mesh, bitwise."""
+    grid = Grid(shape=(256, 512))
+    u = TimeFunction(name="u", grid=grid, space_order=8)
+    prog = Operator(Eq(u.dt, u.laplace), dt=0.1, boundary="zero").program
+    mesh = _mesh((2, 2), ("x", "y"))
+    strategy = make_strategy_2d((2, 2))
+    fast = api_compile(prog, Target(mesh=mesh, strategy=strategy,
+                                    backend="pallas", pallas_tile=(64, 128)))
+    slow = api_compile(prog, Target(mesh=mesh, strategy=strategy, backend="jnp"))
+    n = fast.kernel_dispatches
+    assert n["halo_pad"] == 1 and n["halo_pad_copies"] == 1, n
+    assert fast.loop_unroll == 1
+    u0 = (np.random.default_rng(5).standard_normal((256, 512)).astype(np.float32),)
+    got = jax.jit(lambda s: fast.time_loop(s, 4))(u0)
+    want = jax.jit(lambda s: slow.time_loop(s, 4))(u0)
+    check("pallas-2x2-received-halo", got[0], want[0])
+
+
 SCENARIOS = {
     "1d-zero": lambda: scenario_1d("zero"),
     "1d-periodic": lambda: scenario_1d("periodic"),
@@ -742,6 +765,8 @@ SCENARIOS = {
     # device ops named by the IR op that emitted them (named scopes)
     "scopes-2x2-k1": lambda: scenario_scopes_2x2(1),
     "scopes-2x2-k4": lambda: scenario_scopes_2x2(4),
+    # a halo received from neighbours keeps its pad's copy
+    "pallas-2x2-received-halo": scenario_pallas_2x2_received_halo,
 }
 
 

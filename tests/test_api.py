@@ -547,3 +547,41 @@ def test_index_is_refused_under_a_distributed_target():
 
 def test_halo_pad_census():
     assert api.compile(_jacobi_prog()).kernel_dispatches["halo_pad"] == 1
+
+
+@pytest.mark.parametrize(
+    "backend,boundary,mesh,copies,unroll",
+    [
+        ("jnp", "zero", False, 1, 1),
+        ("pallas", "zero", False, 0, 2),
+        ("pallas", "zero", True, 0, 2),
+        ("pallas", "periodic", False, 1, 1),
+    ],
+    ids=["jnp", "pallas-zero", "pallas-zero-1x1-mesh", "pallas-periodic"],
+)
+def test_halo_pad_copies_census(backend, boundary, mesh, copies, unroll):
+    """``halo_pad_copies`` counts the pads that still copy: every pad on
+    the jnp path, the wrapped copy at a periodic boundary, and none where
+    only Pallas kernels read a zero halo on one device (a mesh whose
+    axes have size 1 exchanges only zeros).  Where a kernel then reads
+    the level in place, ``time_loop`` runs two epochs an iteration."""
+    target = api.Target(backend=backend,
+                        mesh=_one_device_mesh() if mesh else None)
+    c = api.compile(_jacobi_prog(boundary=boundary), target)
+    assert c.kernel_dispatches["halo_pad"] == 1
+    assert c.kernel_dispatches["halo_pad_copies"] == copies
+    assert c.loop_unroll == unroll
+
+
+def test_time_loop_unroll_keeps_the_answer():
+    """Two epochs an iteration (``loop_unroll``) over an odd number of
+    steps gives the one-epoch loop's answer bitwise."""
+    import jax
+
+    c = api.compile(_jacobi_prog(boundary="zero"),
+                    api.Target(backend="pallas", pallas_interpret=True))
+    assert c.loop_unroll == 2
+    u0 = (np.random.default_rng(3).standard_normal((16, 16)).astype(np.float32),)
+    got = jax.jit(lambda s: c.time_loop(s, 5))(u0)
+    want = jax.jit(lambda s: c.time_loop(s, 5, unroll=1))(u0)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))

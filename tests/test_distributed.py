@@ -49,6 +49,7 @@ SCENARIOS = [
     "serve-autoscale",
     "scopes-2x2-k1",
     "scopes-2x2-k4",
+    "pallas-2x2-received-halo",
 ]
 
 

@@ -104,6 +104,7 @@ def test_fused_epoch_is_one_dispatch():
     fused = api.compile(prog, _fused(4))
     assert fused.kernel_dispatches == {
         "fused_epoch": 1, "apply": 0, "pallas_apply": 0, "halo_pad": 1,
+        "halo_pad_copies": 1,
         "total": 1,
     }
     u0 = np.random.default_rng(0).standard_normal((16, 16)).astype(np.float32)
@@ -120,6 +121,7 @@ def test_unfused_epoch_is_k_dispatches():
     unfused = api.compile(prog, _unfused(4))
     assert unfused.kernel_dispatches == {
         "fused_epoch": 0, "apply": 4, "pallas_apply": 4, "halo_pad": 1,
+        "halo_pad_copies": 1,
         "total": 4,
     }
     u0 = np.random.default_rng(0).standard_normal((16, 16)).astype(np.float32)

@@ -189,8 +189,53 @@ def test_kernel_backend_equals_jnp_backend_end_to_end():
 
 
 # -------------------------------------------------------------------------
-# window slack: the halo pad allocates what the last window overhangs
+# windows over the unpadded operand: Mosaic high padding for the last
+# window's overhang, and the zero halo built in the kernel
 # -------------------------------------------------------------------------
+
+
+def _heat_program(shape, order, boundary="zero"):
+    from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+
+    u = TimeFunction(name="u", grid=Grid(shape=shape), space_order=order)
+    return Operator(Eq(u.dt, u.laplace), dt=0.1, boundary=boundary).program
+
+
+def _wave_program(shape, order):
+    from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+
+    w = TimeFunction(name="w", grid=Grid(shape=shape), space_order=order,
+                     time_order=2)
+    return Operator(Eq(w.dt2, w.laplace), dt=1e-3, boundary="zero").program
+
+
+def _pallas_matches_jnp(prog, state, tile, steps=4):
+    """4 jitted steps of the Pallas kernels (interpreted) against the jnp
+    lowering's, bitwise; returns the Pallas artifact's census.  The
+    dispatch counters count traces, and ``api.compile`` shares artifacts
+    between equal programs, so JAX's caches are cleared on both sides:
+    this test traces its kernels, and leaves none traced for the next."""
+    from repro import api, kernels
+    from repro.api import Target
+
+    jax.clear_caches()
+    try:
+        fast = api.compile(prog, Target(backend="pallas", pallas_tile=tile,
+                                        pallas_interpret=True))
+        want = jax.jit(
+            lambda s: api.compile(prog, Target(backend="jnp")).time_loop(s, steps)
+        )(state)
+        kernels.reset_dispatch_stats()
+        got = jax.jit(lambda s: fast.time_loop(s, steps))(state)
+        n = fast.kernel_dispatches
+        assert kernels.dispatch_stats().apply_calls == n["pallas_apply"]
+        assert n["pallas_apply"] == n["apply"]
+        assert kernels.dispatch_stats().window_copies == 0
+    finally:
+        jax.clear_caches()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    return n
 
 
 @pytest.mark.parametrize(
@@ -203,36 +248,93 @@ def test_kernel_backend_equals_jnp_backend_end_to_end():
     ],
 )
 def test_windows_read_the_padded_level_in_place(n, order, tile, boundary):
-    """Shapes whose last window overhangs the halo-padded operand: the
-    ``comm.halo_pad`` allocates the slack, the kernel reads it in place
-    (no ``window_source`` copy), and 4 jitted steps are bitwise equal to
-    the jnp lowering's.  A window clamped at the array's end instead
-    (the pad left out, nothing allocated) differs at 1000² so8."""
-    from repro import api, kernels
+    """Shapes whose last window overhangs the operand array: the overhang
+    is Mosaic high padding of the window (``Window.pad``), not slack a
+    pad allocates, so the kernel reads its operand in place (no
+    ``window_source`` copy) — the unpadded level at a zero boundary,
+    whose pad lowers to nothing, and the one wrapped copy at a periodic
+    one — and 4 jitted steps are bitwise equal to the jnp lowering's.
+    The interpreter fills the padding with NaN, so a halo point read from
+    it would show; a window clamped at the array's end instead differs
+    at 1000² so8."""
+    from repro import api
     from repro.api import Target
     from repro.core.dialects import stencil
-    from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
-    from repro.kernels.stencil_apply import high_slack, plan_apply
+    from repro.core.lowering import halo_source, pad_folds
+    from repro.kernels.stencil_apply import plan_apply, plan_windows
 
-    u = TimeFunction(name="u", grid=Grid(shape=(n, n)), space_order=order)
-    prog = Operator(Eq(u.dt, u.laplace), dt=0.1, boundary=boundary).program
+    prog = _heat_program((n, n), order, boundary)
     fast = api.compile(prog, Target(backend="pallas", pallas_tile=tile,
                                     pallas_interpret=True))
     (apply_op,) = [
         op for op in fast.local_ir.body.ops if isinstance(op, stencil.ApplyOp)
     ]
+    pad = halo_source(apply_op.operands[0])
+    folds = pad_folds(pad.op, "pallas")
+    assert folds == (boundary == "zero")
+    sources = [(pad.op.temp.type.bounds if folds else pad.type.bounds, folds)]
     rb = apply_op.result_bounds
-    (slack,) = high_slack(apply_op, rb, plan_apply(apply_op, rb, tile))
-    assert any(slack)
-    u0 = (jnp.asarray(_rand((n, n), seed=n)),)
-    want = jax.jit(
-        lambda s: api.compile(prog, Target(backend="jnp")).time_loop(s, 4)
-    )(u0)
-    kernels.reset_dispatch_stats()
-    got = jax.jit(lambda s: fast.time_loop(s, 4))(u0)
-    assert kernels.dispatch_stats().apply_calls == 1
-    assert kernels.dispatch_stats().window_copies == 0
-    np.testing.assert_array_equal(np.asarray(got[-1]), np.asarray(want[-1]))
+    (window,) = plan_windows(apply_op, rb, plan_apply(apply_op, rb, tile, sources),
+                             sources)
+    assert any(window.pad)
+    census = _pallas_matches_jnp(prog, (jnp.asarray(_rand((n, n), seed=n)),), tile)
+    assert census["halo_pad_copies"] == (0 if folds else 1)
+
+
+def _nemo_case(tile):
+    from repro.frontends import nemo_traadv as nt
+
+    shape = (6, 20, 260)
+    return nt.program(shape), nt.make_state(jax.random.key(7), shape), tile
+
+
+_ZERO_HALO_CASES = {
+    "wave-2d-so4": lambda: (
+        _wave_program((200, 300), 4),
+        tuple(jnp.asarray(_rand((200, 300), seed=s)) for s in (1, 2)), (64, 128)),
+    "heat-3d-so4-one-tile": lambda: (
+        _heat_program((24, 24, 24), 4),
+        (jnp.asarray(_rand((24, 24, 24), seed=3)),), None),
+    "heat-3d-so4-tiled": lambda: (
+        _heat_program((40, 48, 64), 4),
+        (jnp.asarray(_rand((40, 48, 64), seed=4)),), (8, 16, 64)),
+    "nemo-chooser": lambda: _nemo_case(None),
+    "nemo-2x8x128": lambda: _nemo_case((2, 8, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ZERO_HALO_CASES))
+def test_zero_halo_is_built_in_the_kernel(case):
+    """Every ``comm.halo_pad`` of a zero-boundary program on one device
+    lowers to nothing (``halo_pad_copies`` 0): the Pallas kernels read
+    the unpadded operands, build the zero halo in VMEM at edge tiles, and
+    4 jitted steps are bitwise equal to the jnp lowering's — for a
+    second time level (wave), split and unsplit leading dims (3-D), and
+    NEMO's chain of applies over temporaries, with the chooser's tiles
+    and with (2, 8, 128)."""
+    prog, state, tile = _ZERO_HALO_CASES[case]()
+    census = _pallas_matches_jnp(prog, state, tile)
+    assert census["halo_pad"] >= 1
+    assert census["halo_pad_copies"] == 0
+
+
+def test_folded_apply_under_vmap_matches_jnp():
+    """``jax.vmap`` of a step whose pad folds (the serve engine's slot
+    pools batch it so) adds a grid dim to the kernel; the edge tiles
+    still read the spatial program ids, and every member matches jnp."""
+    from repro import api
+    from repro.api import Target
+
+    prog = _heat_program((200, 300), 4)
+    fast = api.compile(prog, Target(backend="pallas", pallas_tile=(64, 128),
+                                    pallas_interpret=True))
+    assert fast.kernel_dispatches["halo_pad_copies"] == 0
+    ref = api.compile(prog, Target(backend="jnp"))
+    u = jnp.stack([jnp.asarray(_rand((200, 300), seed=s)) for s in (5, 6, 7)])
+    got = jax.jit(jax.vmap(fast.step()))(u)
+    want = jax.jit(jax.vmap(ref.step()))(u)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # -------------------------------------------------------------------------
