@@ -3,7 +3,7 @@ the production mesh — 512 virtual devices, 2 pods × (16×16).
 
 Lowers a 3-D so8 acoustic-wave stencil decomposed 8×8×8 over 512 ranks,
 compiles it (proving the halo-exchange collectives schedule), and prints
-the memory/cost/collective analysis — the stencil-side §Dry-run.
+the memory/cost/collective analysis.
 
     PYTHONPATH=src python examples/multipod_stencil.py
 """

@@ -24,9 +24,9 @@ Operator-as-cached-artifact design:
 - ``compile(program, target) -> CompiledStencil`` — runs the shared
   pass pipeline and wraps the interpreter in ``shard_map``/``jit``.
   Results are cached process-wide on ``(program.fingerprint,
-  target.fingerprint)``, so sweep loops (benchmarks), the serve engine
-  and ``repro.dist`` never re-run passes or re-trace for a program +
-  target they have already compiled.  ``cache_stats()`` reports
+  target.fingerprint)``, so sweep loops, the tuner and the serve engine
+  never re-run passes or re-trace for a program + target they have
+  already compiled.  ``cache_stats()`` reports
   hits/misses; ``clear_cache()`` resets.
 
 """
@@ -851,8 +851,8 @@ def step_traces() -> int:
 
 
 def cache_stats() -> CacheStats:
-    """Process-wide compile-cache counters (shared by ``compile``,
-    ``lower_ir`` and ``cached_callable``) — truthful hit/miss/eviction
+    """Process-wide compile-cache counters (shared by ``compile`` and
+    ``cached_callable``) — truthful hit/miss/eviction
     counts of the LRU-bounded cache."""
     return _STATS
 
@@ -1306,40 +1306,10 @@ def _build_inner(program: Program, target: Target) -> CompiledStencil:
 # --------------------------------------------------------------------------
 
 
-def lower_ir(
-    func: ir.FuncOp,
-    pipeline: str,
-    strategy: Optional[SlicingStrategy] = None,
-    boundary: str = "zero",
-) -> ir.FuncOp:
-    """Run a pass-pipeline spec over generated IR through the process-wide
-    cache (keyed on the IR fingerprint + spec) — how ``repro.dist``'s
-    sequence-halo exchanges skip re-lowering (`dist/context_parallel`)."""
-    s = strategy
-    strat_desc = (
-        "none" if s is None
-        else f"{tuple(s.grid_shape)}{tuple(s.axis_names)}{tuple(s.dims)}"
-    )
-    key = (
-        "lower_ir",
-        ir.fingerprint(func, f"boundary={boundary}"),
-        pipeline,
-        strat_desc,
-    )
-
-    def build() -> ir.FuncOp:
-        pm = PassManager(
-            build_pipeline(pipeline, PipelineContext(strategy=s, boundary=boundary))
-        )
-        return pm.run(_clone_func(func))
-
-    return _cached(key, build)
-
-
 def cached_callable(key: tuple, build: Callable[[], Callable]) -> Callable:
     """Process-wide cache for compiled callables keyed by explicit
-    fingerprints — the serve engine keys its prefill/decode executables on
-    (model-config repr, bucket) so engine restarts skip re-tracing."""
+    fingerprints — the stencil serve engine keys its vmapped pool step on
+    (program, target, pool capacity) so engine restarts skip re-tracing."""
     return _cached(("callable",) + tuple(key), build)
 
 

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, kept at one fixed place.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``, the examples) call
+Entry points (``chip_smoke.py``, ``bench/run.py``, the examples) call
 :func:`enable` once, before their first compile.  Importing ``repro``
 never does: a library must not choose a process's cache.
 

@@ -1,3 +1,3 @@
-# The paper's primary contribution — implement the SYSTEM here
-# (scheduler, optimizer, data path, serving loop, etc.) in the
-# host framework. Add sibling subpackages for substrates.
+"""The shared stencil compiler core: the SSA IR (``ir``), its dialects
+(``stencil``, ``dmp``, ``comm``), the passes over them and the lowering
+to JAX."""

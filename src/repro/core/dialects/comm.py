@@ -44,8 +44,8 @@ def permute_pairs(
     physical-edge ranks simply receive nothing.
 
     Returns ``(axis_arg, pairs)`` ready for ``lax.ppermute`` — the single
-    shared pair construction used by every exchange execution path
-    (stencil interpreter and ``repro.dist.context_parallel``).
+    shared pair construction used by every exchange execution path of
+    the stencil interpreter.
     """
     names = tuple(a for a, _ in axis_shifts)
     steps = [s for _, s in axis_shifts]

@@ -1,30 +1,11 @@
-"""``repro.dist`` — the shared distributed-memory substrate for the
-LM/serving layers.
+"""``repro.dist`` — mesh helpers shared by the stencil stack.
 
-The paper's thesis is that distributed-memory abstractions should be
-*shared infrastructure*, not re-derived per compiler: the stencil stack
-expresses decomposition declaratively (``dmp`` dialect), lowers it once
-(``comm`` dialect → ``lax.ppermute`` under ``shard_map``) and every DSL
-frontend reuses it.  This package is the same argument applied to the
-model half of the codebase:
+The stencil stack expresses decomposition declaratively (``dmp``
+dialect) and lowers it once (``comm`` dialect → ``lax.ppermute`` under
+``shard_map``).  What is left here is mesh plumbing around that path:
 
-- ``sharding``        — mesh context + logical→physical axis rules (the
-                        model-layer analogue of ``dmp.GridAttr``);
-- ``param_specs``     — PartitionSpec assignment for parameter/optimizer
-                        trees (the analogue of the decomposition pass);
-- ``compression``     — gradient compressors for bandwidth-bound meshes;
-- ``context_parallel``— sequence-dimension halo exchange for Mamba /
-                        sliding-window attention, built ON the stencil
-                        ``dmp``/``comm`` machinery (a 1-D ``GridAttr``
-                        over the sequence axis) rather than a bespoke
-                        parallel path — see DESIGN.md §7.
+- ``sharding.factor_slot_mesh`` — a spatial mesh extended with a slot
+  axis (``api.pooled_target``, ``tune.space``);
+- ``sharding.reshard`` — state placed under a new mesh on an elastic
+  restore (``resilience.driver``).
 """
-from repro.dist.sharding import (  # noqa: F401
-    ShardingRules,
-    active_mesh,
-    active_rules,
-    default_rules,
-    kv_cache_layout,
-    shard,
-    use_mesh,
-)

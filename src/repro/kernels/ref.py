@@ -119,25 +119,3 @@ def traadv_step_ref(state):
         zwx = zwx.at[hi].set(_muscl_flux(pwn[hi], t[hi], t[lo], zind[lo],
                                          zslpx[hi], zslpx[lo], upward=True))
         return t.at[lo].set(t[lo] - (zwx[lo] - zwx[hi]))
-
-
-def sliding_window_attention_ref(q, k, v, window: int, causal: bool = True):
-    """O(S·W) oracle via explicit masking of full attention (small shapes).
-
-    q,k,v: [heads, seq, dim] (kv may have fewer heads — GQA broadcast).
-    Token i attends to [i-window+1, i] (causal sliding window).
-    """
-    hq, s, d = q.shape
-    hk = k.shape[0]
-    rep = hq // hk
-    k = jnp.repeat(k, rep, axis=0)
-    v = jnp.repeat(v, rep, axis=0)
-    scores = jnp.einsum("hsd,htd->hst", q, k) / np.sqrt(d)
-    i = jnp.arange(s)[:, None]
-    j = jnp.arange(s)[None, :]
-    mask = (j > i) if causal else jnp.zeros((s, s), bool)
-    mask = mask | (j <= i - window)
-    scores = jnp.where(mask[None], -jnp.inf, scores)
-    p = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("hst,htd->hsd", p, v)
-

@@ -1,9 +1,8 @@
 """Deterministic fault injection for the resilient time-loop driver.
 
 Production long runs die in three characteristic ways; a ``FaultPlan``
-reproduces each one *deterministically* so tests and the soak benchmark
-(``benchmarks/resilience_soak.py``) can assert recovery instead of
-hoping for it:
+reproduces each one *deterministically* so tests can assert recovery
+instead of hoping for it:
 
 - **kill-at-epoch** — the process is preempted at an epoch boundary:
   ``before_epoch`` raises ``SimulatedFault`` right before epoch

@@ -1,4 +1,3 @@
-from repro.serve.engine import Engine, EngineConfig, Request  # noqa: F401
 from repro.serve.stencil import (  # noqa: F401
     Frame,
     RequestHandle,

@@ -3,8 +3,8 @@
 The ROADMAP's "millions of users" direction: many tenants submit
 ``(Program, initial state, n_steps, Target)`` jobs against ONE running
 service, and throughput under concurrent mixed traffic — not single-run
-latency — is the figure of merit.  The design generalizes the vLLM-style
-slot pool of ``serve/engine.py`` onto the PR 3 compile surface:
+latency — is the figure of merit.  The design is a vLLM-style slot
+pool over the ``repro.api`` compile surface:
 
 - **fingerprint batching** — live requests are grouped by
   ``(program.fingerprint, target.fingerprint)``; each group's engine step

@@ -1,7 +1,7 @@
 """Fingerprint-bucketed slot pools and admission for the stencil engine.
 
-The vLLM-style slot-pool ideas from ``serve/engine.py`` (fixed pool,
-shape-stable executables, continuous admission) applied to stencil jobs:
+vLLM-style slot-pool ideas (fixed pool, shape-stable executables,
+continuous admission) applied to stencil jobs:
 
 - live requests are grouped by **compile fingerprint**
   ``(program.fingerprint, target.fingerprint)`` — the same key the

@@ -3,7 +3,7 @@
 NOTE: no --xla_force_host_platform_device_count here — unit/smoke tests
 run on the 1 real CPU device.  Multi-device distribution tests spawn
 subprocesses (tests/dist_worker.py) that set the flag before importing
-jax, mirroring launch/dryrun.py.
+jax.
 """
 import os
 import sys

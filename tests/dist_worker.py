@@ -713,9 +713,47 @@ def scenario_pallas_2x2_received_halo():
     check("pallas-2x2-received-halo", got[0], want[0])
 
 
+def scenario_1d_backward(boundary):
+    """A 1-D program that reads only backwards, ``out[i] = u[i] + 0.5 u[i-1]
+    - 0.25 u[i-3]``, on 8 ranks: each shard receives a 3-point halo from
+    its lower neighbour and sends none the other way (one exchange, where
+    a centred stencil needs two).  At a zero boundary rank 0 receives
+    zeros; at a periodic one it receives the last rank's tail.  One step
+    and a 6-step ``time_loop`` equal one device's, bitwise."""
+    shape = (256,)
+
+    def build():
+        p = ProgramBuilder("backward", shape)
+        u = p.input("u")
+        out = p.output("out")
+        r = p.apply([p.load(u)],
+                    lambda b, u: u.at(0) + u.at(-1) * 0.5 - u.at(-3) * 0.25)
+        p.store(r, out)
+        return p.finish(boundary=boundary)
+
+    u0 = np.random.default_rng(6).standard_normal(shape).astype(np.float32)
+    single = api_compile(build())
+    want = np.asarray(single(u0, np.zeros_like(u0))[0])
+    rolled = u0 + np.roll(u0, 1) * 0.5 - np.roll(u0, 3) * 0.25
+    if boundary == "zero":
+        rolled[0] = u0[0]
+        rolled[1:3] = u0[1:3] + u0[0:2] * 0.5
+    assert np.allclose(want, rolled, rtol=1e-6, atol=1e-6)
+    step = api_compile(build(), Target(mesh=_mesh((8,), ("x",)),
+                                       strategy=make_strategy_1d(8)))
+    starts = [op for op in step.local_ir.body.ops
+              if op.name == "comm.exchange_start"]
+    assert len(starts) == 1, [op.name for op in step.local_ir.body.ops]
+    check(f"1d-backward-{boundary}", step(u0, np.zeros_like(u0))[0], want)
+    check(f"1d-backward-{boundary}-loop",
+          step.time_loop((u0,), 6)[0], single.time_loop((u0,), 6)[0])
+
+
 SCENARIOS = {
     "1d-zero": lambda: scenario_1d("zero"),
     "1d-periodic": lambda: scenario_1d("periodic"),
+    "1d-backward-zero": lambda: scenario_1d_backward("zero"),
+    "1d-backward-periodic": lambda: scenario_1d_backward("periodic"),
     "2d-zero": lambda: scenario_2d("zero"),
     "2d-periodic": lambda: scenario_2d("periodic"),
     "3d": scenario_3d,

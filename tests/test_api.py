@@ -35,11 +35,11 @@ def _jacobi_prog(shape=(16, 16), boundary="periodic", name="jacobi"):
     return p.finish(boundary=boundary)
 
 
-def _one_device_mesh():
+def _one_device_mesh(axis="x"):
     import jax
     from jax.sharding import Mesh
 
-    return Mesh(np.array(jax.devices()[:1]), ("x",))
+    return Mesh(np.array(jax.devices()[:1]), (axis,))
 
 
 # -------------------------------------------------------------------------
@@ -171,6 +171,44 @@ def test_target_auto_single_device():
     assert not t.distributed
     with pytest.raises(TargetError, match="devices"):
         Target.auto(ranks=64)
+
+
+@pytest.mark.parametrize(
+    "exc,make,match",
+    [
+        (ValueError, lambda: Program(_jacobi_prog().func, boundary="mirror"),
+         "unknown boundary condition 'mirror'"),
+        (ValueError, lambda: Program(_jacobi_prog().func, field_names=("u",)),
+         "1 field names for 2 field arguments"),
+        (TargetError,
+         lambda: Target(pipeline="decompose{grid=1},temporal-tile{k=two}",
+                        exchange_every=2),
+         "temporal-tile: k must be an integer, got 'two'"),
+        (TargetError,
+         lambda: Target(mesh=_one_device_mesh("slot"), slot_axis=3),
+         "slot_axis must be a mesh axis name, got 3"),
+        (TargetError, lambda: Target(slot_axis="slot"),
+         "needs a mesh carrying that axis"),
+        (TargetError, lambda: Target(mesh=_one_device_mesh(), slot_axis="slot"),
+         r"slot_axis 'slot' not in mesh axes \('x',\)"),
+        (TargetError, lambda: api.pooled_target(Target()),
+         "pooled_target needs a distributed target"),
+        (TargetError,
+         lambda: api.pooled_target(
+             Target(mesh=_one_device_mesh("slot"), slot_axis="slot")),
+         "target already carries slot axis 'slot'"),
+    ],
+    ids=["program-boundary", "program-field-names", "pipeline-k-not-int",
+         "slot-axis-not-a-name", "slot-axis-without-mesh",
+         "slot-axis-not-in-mesh", "pooled-single-device", "pooled-twice"],
+)
+def test_api_refuses_bad_input(exc, make, match):
+    """What a caller hands ``Program``, ``Target`` and ``pooled_target``
+    is refused at construction, with a message naming the mismatch: a
+    ``ValueError`` for a ``Program``, a ``TargetError`` for a target."""
+    with pytest.raises(exc, match=match) as e:
+        make()
+    assert e.type is exc
 
 
 def test_target_fingerprint_distinguishes_knobs():
@@ -387,23 +425,6 @@ def test_time_loop_on_artifact():
     np.testing.assert_allclose(
         np.asarray(via_loop), np.asarray(twice), rtol=1e-6
     )
-
-
-def test_lower_ir_cache_for_generated_exchanges():
-    """dist/context_parallel's entry point: same exchange shape → cached
-    (lru memo on top, fingerprint-keyed api cache underneath)."""
-    from repro.dist.context_parallel import SeqHaloSpec, _comm_func
-
-    spec = SeqHaloSpec(axis="x", n_shards=4, halo_lo=3)
-    f1 = _comm_func((2, 8, 4), spec)
-    # the thin lru memo short-circuits repeat calls entirely
-    assert _comm_func((2, 8, 4), spec) is f1
-    # the process-wide api cache underneath hits when the memo is bypassed
-    # (fresh IR build, same fingerprint)
-    stats0 = api.cache_stats().as_dict()
-    f2 = _comm_func.__wrapped__((2, 8, 4), spec)
-    assert f2 is f1
-    assert api.cache_stats().hits == stats0["hits"] + 1
 
 
 # -------------------------------------------------------------------------

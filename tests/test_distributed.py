@@ -16,6 +16,8 @@ WORKER = os.path.join(os.path.dirname(__file__), "dist_worker.py")
 SCENARIOS = [
     "1d-zero",
     "1d-periodic",
+    "1d-backward-zero",
+    "1d-backward-periodic",
     "2d-zero",
     "2d-periodic",
     "3d",

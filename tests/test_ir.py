@@ -53,6 +53,101 @@ def test_verifier_rejects_store_out_of_bounds():
         ir.verify_module(func)
 
 
+_CORE = stencil.Bounds.from_shape((8, 8))
+_BIG = stencil.Bounds.from_shape((16, 16))
+_INNER = stencil.Bounds((1, 1), (7, 7))
+
+
+def _field_func(*bounds):
+    return ir.FuncOp("probe", [stencil.FieldType(b) for b in bounds])
+
+
+def _load(bounds=None, field=_CORE):
+    func = _field_func(field)
+    return func.body.add_op(stencil.LoadOp(func.body.args[0], bounds))
+
+
+def _centre(b, u):
+    return u.at(0, 0)
+
+
+def _load_outside_field():
+    return _load(bounds=_BIG)
+
+
+def _store_outside_temp():
+    func = _field_func(_BIG)
+    load = func.body.add_op(stencil.LoadOp(func.body.args[0], _CORE))
+    return stencil.StoreOp(load.results[0], func.body.args[0], _BIG)
+
+
+def _apply_arg_count():
+    op = stencil.ApplyOp([_load().results[0]], _CORE)
+    op.set_operands([])
+    return op
+
+
+def _apply_arg_type():
+    op = build_apply(ir.Block([]), [_load().results[0]], _CORE, _centre)
+    op.replace_operand(0, _load(field=_BIG).results[0])
+    return op
+
+
+def _apply_without_return():
+    return stencil.ApplyOp([_load().results[0]], _CORE)
+
+
+def _apply_return_arity():
+    return build_apply(ir.Block([]), [_load().results[0]], _CORE,
+                       _centre, n_results=2)
+
+
+def _apply_reads_past_halo():
+    return build_apply(ir.Block([]), [_load(bounds=_INNER).results[0]],
+                       stencil.Bounds((2, 2), (6, 6)),
+                       lambda b, u: u.at(-2, 0))
+
+
+def _combine_part_outside():
+    return stencil.CombineOp([_load().results[0]], _INNER)
+
+
+@pytest.mark.parametrize(
+    "make,match",
+    [
+        (_load_outside_field, "stencil.load reads .* outside field bounds"),
+        (_store_outside_temp, "stencil.store range .* outside temp bounds"),
+        (_apply_arg_count, "region arg count != operand count"),
+        (_apply_arg_type, "region arg type .* != operand type"),
+        (_apply_without_return, "must end in stencil.return"),
+        (_apply_return_arity,
+         "stencil.return arity != stencil.apply result arity"),
+        (_apply_reads_past_halo,
+         r"accesses .* of operand 0 .*\(halo missing\?\)"),
+        (_combine_part_outside, "stencil.combine part .* outside result bounds"),
+    ],
+    ids=["load-bounds", "store-temp-bounds", "apply-arg-count",
+         "apply-arg-type", "apply-return", "apply-return-arity",
+         "apply-halo", "combine-part"],
+)
+def test_verifier_rejects_malformed_stencil_op(make, match):
+    """Each stencil-dialect invariant a frontend or a pass could break is
+    refused by the op's verifier with a message naming it."""
+    with pytest.raises(ir.VerificationError, match=match):
+        make().verify()
+
+
+def test_verifier_rejects_use_before_definition():
+    func = _field_func(_CORE, _CORE)
+    load = stencil.LoadOp(func.body.args[0])
+    build_apply(func.body, [load.results[0]], _CORE, _centre)
+    func.body.add_op(load)
+    func.body.add_op(ir.ReturnOp([]))
+    with pytest.raises(ir.VerificationError,
+                       match="of stencil.apply used before definition"):
+        ir.verify_module(func)
+
+
 def test_access_extents_reflect_offsets():
     _, apply_op = _jacobi_func()
     exts = apply_op.access_extents()

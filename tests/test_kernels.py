@@ -161,6 +161,26 @@ def test_choose_tile_respects_budget_and_divisibility():
         choose_tile(shape, spans, budget=16 * 1024)
 
 
+@pytest.mark.parametrize(
+    "tile,match",
+    [
+        ((64,), r"tile \(64,\) has 1 dims, shape \(512, 1024\) has 2"),
+        ((60, 128), "dim 0 extent 60 must be a multiple of 8 below 512"),
+        ((64, 100), "dim 1 extent 100 must be a multiple of 128 below 1024"),
+    ],
+    ids=["rank", "sublane", "lane"],
+)
+def test_check_tile_refuses_illegal_tiles(tile, match):
+    """An explicit tile that breaks the (8, 128) block rule, or has the
+    wrong rank, is refused before any kernel is built."""
+    from repro.kernels import KernelPlanError
+    from repro.kernels.stencil_apply import check_tile
+
+    assert check_tile((512, 1024), (64, 128)) == (64, 128)
+    with pytest.raises(KernelPlanError, match=match):
+        check_tile((512, 1024), tile)
+
+
 def test_kernel_backend_equals_jnp_backend_end_to_end():
     """Same stencil program through lowering w/ jnp vs pallas backends."""
     from repro.api import Target, compile as api_compile

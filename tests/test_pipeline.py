@@ -88,6 +88,27 @@ def test_pipeline_rejects_unknown_options():
         build_pipeline("decompose{grid=2x2,boundary=mirror}")
 
 
+@pytest.mark.parametrize(
+    "spec,match",
+    [
+        ("fuse},cse", "unbalanced '}'"),
+        ("fuse cse", "cannot parse pipeline stage 'fuse cse'"),
+        ("decompose{grid=2by2}", "cannot parse grid spec '2by2'"),
+        ("decompose{grid=2x2xyz}", "3 axis names for 2 grid dims"),
+        ("decompose", "no grid= option and no strategy in context"),
+        ("temporal-tile{k=two}", "k must be an integer, got 'two'"),
+        ("temporal-tile{k=0}", "k must be >= 1, got 0"),
+    ],
+    ids=["unbalanced-close", "stage-name", "grid-spec", "grid-axis-names",
+         "decompose-without-grid", "temporal-k-not-int", "temporal-k-zero"],
+)
+def test_pipeline_grammar_refusals(spec, match):
+    """A malformed pipeline spec is refused while the pipeline is built,
+    never half-run: the message names the stage or option at fault."""
+    with pytest.raises(PipelineError, match=match):
+        build_pipeline(spec, PipelineContext())
+
+
 def test_grid_spec_with_axis_names():
     stages = build_pipeline("decompose{grid=2x2xy}", PipelineContext())
     func = _jacobi_prog()
@@ -145,6 +166,18 @@ def test_interpreter_rejects_dmp_swap():
     interp = StencilInterpreter(local, axis_sizes={}, distributed=False)
     with pytest.raises(NotImplementedError, match="dmp.swap"):
         interp(np.zeros((16, 16), np.float32), np.zeros((16, 16), np.float32))
+
+
+def test_interpreter_needs_a_resolved_pallas_mode():
+    """The Pallas backend runs natively or interpreted, never a guess:
+    the interpreter refuses ``pallas_interpret=None`` (the ``Target``
+    resolves it from the platform)."""
+    local = lower_dmp_to_comm(
+        decompose_stencil(_jacobi_prog(), make_strategy_2d((1, 1)))
+    )
+    with pytest.raises(ValueError, match="needs pallas_interpret resolved"):
+        StencilInterpreter(local, axis_sizes={}, distributed=False,
+                           backend="pallas")
 
 
 def test_permute_pairs_shared_helper():

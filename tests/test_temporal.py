@@ -212,6 +212,63 @@ def test_rejects_position_dependent_bodies():
         epoch_halo(p.finish().func, 2)
 
 
+def _step_func(build):
+    """The function of a ``ProgramBuilder`` program whose fields are ``u``
+    (input), ``v`` (input) and ``out`` (output), filled in by ``build``."""
+    p = ProgramBuilder("epoch_probe", (16, 16))
+    fields = {n: p.input(n) for n in ("u", "v")}
+    fields["out"] = p.output("out")
+    build(p, fields)
+    return p.build_func()
+
+
+def _avg(b, x, y):
+    return (x.at(0, 0) + y.at(0, 0)) * 0.5
+
+
+def _loads_u_twice(p, f):
+    p.store(p.apply([p.load(f["u"]), p.load(f["u"])], _avg), f["out"])
+
+
+def _stores_out_twice(p, f):
+    r = p.apply([p.load(f["u"]), p.load(f["v"])], _avg)
+    p.store(r, f["out"])
+    p.store(r, f["out"])
+
+
+def _never_loads_v(p, f):
+    p.store(p.apply([p.load(f["u"])], lambda b, u: u.at(0, 0)), f["out"])
+
+
+def _reads_and_writes_out(p, f):
+    r = p.apply([p.load(f["u"]), p.load(f["out"])], _avg)
+    p.load(f["v"])
+    p.store(r, f["out"])
+
+
+@pytest.mark.parametrize(
+    "func,match",
+    [
+        (lambda: _step_func(_loads_u_twice), "loaded twice"),
+        (lambda: _step_func(_stores_out_twice), "stored twice per step"),
+        (lambda: _step_func(_never_loads_v), "is never loaded"),
+        (lambda: _step_func(_reads_and_writes_out),
+         "both loaded and stored"),
+        (lambda: _jacobi().func, "run decompose before temporal-tile"),
+        (lambda: api.compile(_jacobi()).local_ir,
+         "function-level op comm.halo_pad not supported in an epoch"),
+    ],
+    ids=["load-twice", "store-twice", "input-never-loaded",
+         "read-modify-write", "undecomposed", "lowered-comm"],
+)
+def test_temporal_tile_refuses_steps_it_cannot_epoch(func, match):
+    """A step whose state does not rotate cleanly, or a function not at
+    the stage the pass expects (undecomposed, or already lowered to
+    ``comm``), is refused by name instead of being mis-tiled."""
+    with pytest.raises(TemporalTilingError, match=match):
+        temporal_tile(func(), 2)
+
+
 # -------------------------------------------------------------------------
 # Target validation + fingerprints + time_loop epochs
 # -------------------------------------------------------------------------

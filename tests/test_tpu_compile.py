@@ -20,6 +20,8 @@ from repro import api
 from repro.api import Target
 from repro.core.passes.decompose import make_strategy_2d
 from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+from repro.frontends.oec_like import ProgramBuilder
+from repro.frontends.psyclone_like import recognize
 
 HBM_BYTES = 16 * 10**9  # one v5e chip
 
@@ -150,6 +152,70 @@ def test_whole_shard_epoch_step(one_chip):
     x = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=one_chip)
     compiled = jax.jit(step).lower(x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _wave(n: int, order: int):
+    w = TimeFunction(name="w", grid=Grid(shape=(n, n)), space_order=order,
+                     time_order=2)
+    return Operator(Eq(w.dt2, w.laplace), dt=1e-3, boundary="zero").program
+
+
+def _heat3d(n: int, order: int):
+    u = TimeFunction(name="u", grid=Grid(shape=(n, n, n)), space_order=order)
+    return Operator(Eq(u.dt, u.laplace), dt=0.1, boundary="zero").program
+
+
+def _oec_hyperdiffusion(n: int):
+    """The OEC-like frontend's two-apply chain: a Laplacian, then the
+    Laplacian of that temporary (4th-order horizontal diffusion), so the
+    second kernel reads a temporary at offsets."""
+    def lap(b, u):
+        return u.at(-1, 0) + u.at(1, 0) + u.at(0, -1) + u.at(0, 1) - u.at(0, 0) * 4.0
+
+    p = ProgramBuilder("hyperdiffusion", (n, n))
+    u, out = p.input("u"), p.output("out")
+    t = p.load(u)
+    l1 = p.apply([t], lap)
+    r = p.apply([t, p.apply([l1], lap)],
+                lambda b, u, l2: u.at(0, 0) - l2.at(0, 0) * 0.05)
+    p.store(r, out)
+    return p.finish(boundary="zero")
+
+
+def _psyclone_3d(shape):
+    def kern(u, out):
+        out[i, j, k] = (u[i, j, k - 1] + u[i, j, k + 1]) * 0.5
+
+    return recognize(kern, shape=shape)
+
+
+@pytest.mark.parametrize(
+    "build,shape",
+    [
+        (lambda: _wave(16384, 8), (16384, 16384)),
+        (lambda: _heat3d(1024, 8), (1024, 1024, 1024)),
+        (lambda: _oec_hyperdiffusion(8192), (8192, 8192)),
+        (lambda: _psyclone_3d((75, 1021, 1442)), (75, 1021, 1442)),
+    ],
+    ids=["devito-wave2d-so8-16384", "devito-heat3d-so8-1024",
+         "oec-hyperdiffusion-8192", "psyclone-3d-orca025"],
+)
+def test_pallas_step_program_families(one_chip, build, shape):
+    """The program families no cell runs, on ``Target()``'s TPU path:
+    every apply is a Pallas kernel, every zero halo is built inside it
+    (no pad copies a field), and one step compiles and fits a chip."""
+    prog = build()
+    step = api.compile(prog, Target(backend="pallas", pallas_interpret=False))
+    n = step.kernel_dispatches
+    assert n["pallas_apply"] == n["apply"] >= 1
+    assert n["halo_pad_copies"] == 0
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    levels = len(step.input_indices)
+    compiled = jax.jit(step.step()).lower(*(x,) * levels).compile()
+    text = compiled.as_text()
+    assert sum("tpu_custom_call" in line for line in text.splitlines()) == n["apply"]
+    assert not [line for line in text.splitlines() if " pad(" in line]
+    assert _device_bytes(compiled) < HBM_BYTES
 
 
 def test_2x2_jnp_step_k4(topo):
