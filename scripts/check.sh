@@ -226,7 +226,7 @@ fused = api.compile(heat, api.Target(
     pallas_interpret=True))
 assert fused.kernel_dispatches == {
     "fused_epoch": 1, "apply": 0, "pallas_apply": 0, "halo_pad": 1,
-    "halo_pad_copies": 1, "total": 1,
+    "halo_pad_copies": 1, "pinned_send_strips": 0, "total": 1,
 }, fused.kernel_dispatches
 assert unfused.kernel_dispatches["apply"] == 4, unfused.kernel_dispatches
 

@@ -46,7 +46,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import ir
 from repro.core.dialects import comm, stencil
-from repro.core.lowering import StencilInterpreter, pad_folds, runs_pallas
+from repro.core.lowering import (
+    StencilInterpreter, halo_source, pad_folds, pins_send_strip, runs_pallas,
+)
 from repro.obs import trace as _obs
 from repro.core.passes import (
     PassManager,
@@ -671,7 +673,10 @@ class CompiledStencil:
         to the Pallas window kernel (``pallas_apply``; a split's frames
         run jnp), how many ``comm.halo_pad`` ops the IR holds
         (``halo_pad``), and how many of those still copy their operand
-        (``halo_pad_copies``: the pads ``lowering.pad_folds`` leaves).
+        (``halo_pad_copies``: the pads ``lowering.pad_folds`` leaves),
+        and how many of the exchanges that run send a strip pinned to the
+        level's row-major order (``pinned_send_strips``:
+        ``lowering.pins_send_strip``).
         With ``Target(fused_epoch=True)`` an epoched
         program reads ``{"fused_epoch": 1, "apply": 0, ...}`` — one kernel
         dispatch per epoch (cross-checked at trace time by
@@ -689,15 +694,19 @@ class CompiledStencil:
             op for op in self.local_ir.body.ops
             if isinstance(op, comm.HaloPadOp)
         ]
+        backend = self.target.backend
+        folded = {op.results[0] for op in pads if pad_folds(op, backend)}
         return {
             "fused_epoch": fused,
             "apply": len(applies),
-            "pallas_apply": sum(
-                runs_pallas(op, self.target.backend) for op in applies
-            ),
+            "pallas_apply": sum(runs_pallas(op, backend) for op in applies),
             "halo_pad": len(pads),
-            "halo_pad_copies": sum(
-                not pad_folds(op, self.target.backend) for op in pads
+            "halo_pad_copies": len(pads) - len(folded),
+            "pinned_send_strips": sum(
+                self.target.distributed and pins_send_strip(op)
+                for op in self.local_ir.body.ops
+                if isinstance(op, comm.ExchangeStartOp)
+                and halo_source(op.temp) not in folded
             ),
             "total": fused + len(applies),
         }

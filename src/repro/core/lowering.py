@@ -23,7 +23,9 @@ one path:
                             (``pad_folds``): the kernels build the halo;
 - ``comm.exchange_start`` → extract the send rectangle, ``lax.ppermute``
                             it toward ``-shift`` (pairs built by the
-                            shared ``comm.permute_pairs``);
+                            shared ``comm.permute_pairs``); a strip cut
+                            across the minor dim keeps the level's
+                            row-major order (``pins_send_strip``);
 - ``comm.wait``           → insert received patches
                             (``lax.dynamic_update_slice``);
 - ``stencil.combine``     → reassemble split (overlapped) applies.
@@ -41,6 +43,7 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core import ir
 from repro.core.dialects import comm, dmp, stencil
@@ -214,6 +217,23 @@ def pad_folds(op: comm.HaloPadOp, backend: str) -> bool:
             elif not isinstance(reader, comm.ExchangeStartOp):
                 return False
     return True
+
+
+def pins_send_strip(op: comm.ExchangeStartOp) -> bool:
+    """Whether a ``comm.exchange_start`` that crosses devices pins its
+    send rectangle to the row-major order of the level it is cut from:
+    the rectangle is a strip cut across the minor dim, narrower there
+    than the level's interior (the ``y``-edge column strips of a 2-D
+    decomposition, the minor-dim strips of a 3-D one).  Unpinned, XLA
+    lays such a tall, narrow strip out column-major for the
+    collective-permute and gives the slice's operand, the whole level,
+    the same layout: a transpose of the level every step to cut two
+    strips from it.  The pin moves no value.
+    ``CompiledStencil.kernel_dispatches["pinned_send_strips"]`` counts the
+    exchanges it pins."""
+    pad = halo_source(op.temp)
+    level = (pad.op.temp if pad is not None else op.temp).type.bounds
+    return op.size[-1] < level.shape[-1]
 
 
 class StencilInterpreter:
@@ -431,6 +451,10 @@ class StencilInterpreter:
         )
         periodic = bool(op.attributes.get("periodic", ir.IntAttr(0)).value)
         if self.distributed:
+            if pins_send_strip(op):
+                patch = with_layout_constraint(
+                    patch, Layout(major_to_minor=tuple(range(patch.ndim)))
+                )
             axis_arg, pairs = comm.permute_pairs(
                 op.axis_shifts, self.axis_sizes, periodic
             )

@@ -713,6 +713,49 @@ def scenario_pallas_2x2_received_halo():
     check("pallas-2x2-received-halo", got[0], want[0])
 
 
+def scenario_pinned_strips_2x2(boundary, k=1, backend="jnp"):
+    """The 2x2 heat cell's path at a small grid: each shard's two
+    ``y``-edge send strips, cut across the minor dim, go out pinned to
+    the level's row-major order (``pinned_send_strips`` 2, one layout
+    constraint each in the lowered step), and 8 jitted steps equal one
+    device's, bitwise: the pin moves no value."""
+    shape = (256, 512) if backend == "pallas" else (64, 128)
+    u = TimeFunction(name="u", grid=Grid(shape=shape), space_order=8)
+    prog = Operator(Eq(u.dt, u.laplace), dt=0.1, boundary=boundary).program
+    step = api_compile(prog, Target(
+        mesh=_mesh((2, 2), ("x", "y")), strategy=make_strategy_2d((2, 2)),
+        exchange_every=k, backend=backend,
+        pallas_tile=(64, 128) if backend == "pallas" else None))
+    assert step.kernel_dispatches["pinned_send_strips"] == 2
+    assert step.lower().as_text().count("@LayoutConstraint") == 2
+    u0 = (np.random.default_rng(8).standard_normal(shape).astype(np.float32),)
+    single = api_compile(prog)
+    got = jax.jit(lambda s: step.time_loop(s, 8))(u0)
+    want = jax.jit(lambda s: single.time_loop(s, 8))(u0)
+    check(f"pinned-strips-{boundary}-k{k}-{backend}", got[0], want[0])
+
+
+def scenario_pinned_strips_pooled():
+    """The pin under the serve engine's pooled step: a slot axis over a
+    2x2 decomposition (``vmap`` inside ``shard_map``) pins the same two
+    strips, and advances a batch of 4 states bitwise-equal to 4 runs on
+    one device."""
+    from repro.api import pooled_target
+
+    shape = (64, 128)
+    u = TimeFunction(name="u", grid=Grid(shape=shape), space_order=8)
+    prog = Operator(Eq(u.dt, u.laplace), dt=0.1, boundary="periodic").program
+    pooled = api_compile(prog, pooled_target(
+        Target(mesh=_mesh((2, 2), ("x", "y")), strategy=make_strategy_2d((2, 2))),
+        slots=2))
+    assert pooled.kernel_dispatches["pinned_send_strips"] == 2
+    batch = np.random.default_rng(9).standard_normal((4,) + shape).astype(np.float32)
+    got = pooled.time_loop((batch,), 8)[0]
+    single = api_compile(prog)
+    want = np.stack([np.asarray(single.time_loop((b,), 8)[0]) for b in batch])
+    check("pinned-strips-pooled", got, want)
+
+
 def scenario_1d_backward(boundary):
     """A 1-D program that reads only backwards, ``out[i] = u[i] + 0.5 u[i-1]
     - 0.25 u[i-3]``, on 8 ranks: each shard receives a 3-point halo from
@@ -805,6 +848,15 @@ SCENARIOS = {
     "scopes-2x2-k4": lambda: scenario_scopes_2x2(4),
     # a halo received from neighbours keeps its pad's copy
     "pallas-2x2-received-halo": scenario_pallas_2x2_received_halo,
+    # the column send strips keep the level's row-major order
+    "pinned-strips-zero-k1": lambda: scenario_pinned_strips_2x2("zero"),
+    "pinned-strips-periodic-k1": lambda: scenario_pinned_strips_2x2("periodic"),
+    "pinned-strips-zero-k4": lambda: scenario_pinned_strips_2x2("zero", 4),
+    "pinned-strips-periodic-k4": lambda: scenario_pinned_strips_2x2("periodic", 4),
+    "pinned-strips-pallas": lambda: scenario_pinned_strips_2x2(
+        "zero", backend="pallas"
+    ),
+    "pinned-strips-pooled": scenario_pinned_strips_pooled,
 }
 
 

@@ -606,3 +606,52 @@ def test_time_loop_unroll_keeps_the_answer():
     got = jax.jit(lambda s: c.time_loop(s, 5))(u0)
     want = jax.jit(lambda s: c.time_loop(s, 5, unroll=1))(u0)
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+
+
+def _validation_mesh(shape, names):
+    """A mesh of ``prod(shape)`` logical ranks over the one test device:
+    enough to decompose and census a program, never to run it."""
+    import jax
+    from jax.sharding import Mesh
+
+    n = int(np.prod(shape))
+    return Mesh(np.array(jax.devices()[:1] * n).reshape(shape), names)
+
+
+@pytest.mark.parametrize(
+    "case,pinned",
+    [("2x2", 2), ("2x2-periodic", 2), ("2x2-k4", 2), ("1d-rows", 0),
+     ("1d-columns", 2), ("3d-2x2x2", 2), ("one-device", 0),
+     ("one-device-periodic", 0)],
+)
+def test_pinned_send_strips_census(case, pinned):
+    """``pinned_send_strips`` counts the exchanges whose send strip is
+    cut across the minor dim (``lowering.pins_send_strip``): the two
+    ``y``-edge column strips of a 2-D decomposition, whatever the
+    boundary or epoch depth; none where only rows are exchanged; the two
+    minor-dim strips of a 3-D one; and none on one device, where nothing
+    crosses a device."""
+    from repro.core.passes.decompose import make_strategy_2d, make_strategy_3d
+    from repro.frontends.devito_like import Eq, Grid, Operator, TimeFunction
+
+    shape = (32, 32, 32) if case.startswith("3d") else (64, 64)
+    boundary = "periodic" if case.endswith("periodic") else "zero"
+    u = TimeFunction(name="u", grid=Grid(shape=shape), space_order=8)
+    prog = Operator(Eq(u.dt, u.laplace), dt=0.1, boundary=boundary).program
+    targets = {
+        "2x2": lambda: Target(mesh=_validation_mesh((2, 2), ("x", "y")),
+                              strategy=make_strategy_2d((2, 2))),
+        "2x2-k4": lambda: Target(mesh=_validation_mesh((2, 2), ("x", "y")),
+                                 strategy=make_strategy_2d((2, 2)),
+                                 exchange_every=4),
+        "1d-rows": lambda: Target(mesh=_validation_mesh((4,), ("x",)),
+                                  strategy=make_strategy_1d(4)),
+        "1d-columns": lambda: Target(mesh=_validation_mesh((4,), ("x",)),
+                                     strategy=make_strategy_1d(4, dim=1)),
+        "3d-2x2x2": lambda: Target(
+            mesh=_validation_mesh((2, 2, 2), ("x", "y", "z")),
+            strategy=make_strategy_3d((2, 2, 2))),
+        "one-device": lambda: Target(),
+    }
+    target = targets[case.removesuffix("-periodic")]()
+    assert api.compile(prog, target).kernel_dispatches["pinned_send_strips"] == pinned

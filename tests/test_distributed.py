@@ -52,6 +52,12 @@ SCENARIOS = [
     "scopes-2x2-k1",
     "scopes-2x2-k4",
     "pallas-2x2-received-halo",
+    "pinned-strips-zero-k1",
+    "pinned-strips-periodic-k1",
+    "pinned-strips-zero-k4",
+    "pinned-strips-periodic-k4",
+    "pinned-strips-pallas",
+    "pinned-strips-pooled",
 ]
 
 

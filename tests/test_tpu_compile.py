@@ -232,9 +232,9 @@ def test_2x2_jnp_step_k4(topo):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def _peak_2x2_loop(topo, backend: str) -> int:
-    """``memory_analysis()`` peak of the 2x2 cell's program: 65536²
-    so8 heat decomposed 2x2, a jitted 16-step ``time_loop``."""
+def _loop_2x2(topo, backend: str):
+    """The 2x2 cell's program: 65536² so8 heat decomposed 2x2, and its
+    jitted 16-step ``time_loop`` compiled."""
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("x", "y"))
     target = Target(mesh=mesh, strategy=make_strategy_2d((2, 2)),
                     backend=backend, pallas_interpret=False)
@@ -243,7 +243,44 @@ def _peak_2x2_loop(topo, backend: str) -> int:
         (65536, 65536), jnp.float32, sharding=NamedSharding(mesh, P("x", "y"))
     )
     exe = jax.jit(lambda s: compiled.time_loop(s, 16)).lower((x,)).compile()
-    return exe.memory_analysis().peak_memory_in_bytes
+    return compiled, exe
+
+
+def _peak_2x2_loop(topo, backend: str) -> int:
+    """``memory_analysis()`` peak of the 2x2 cell's program."""
+    return _loop_2x2(topo, backend)[1].memory_analysis().peak_memory_in_bytes
+
+
+def test_2x2_pallas_loop_transposes_no_level(topo):
+    """The ``y``-edge send strips, 32768 × 4, are pinned to the level's
+    row-major order (``pinned_send_strips`` 2): XLA cuts them from the
+    level as it is and copies only the strips into the permute's layout.
+    Unpinned, it transposed the whole level into a column-major copy
+    inside the loop, every step, to cut them.  The loop body holds no
+    level-sized copy; the one left is the call's input, in the entry.
+    The kernel and the permutes stay, and the temporaries do not grow."""
+    compiled, exe = _loop_2x2(topo, "pallas")
+    assert compiled.kernel_dispatches["pinned_send_strips"] == 2
+    text = exe.as_text()
+    # the entry computation is printed last, after the loop body
+    copies = _level_copies(text, "f32[32768,32768]")
+    assert len(copies) == 1 and copies[0] in text[text.index("\nENTRY "):]
+    lines = text.splitlines()
+    assert sum("tpu_custom_call" in line for line in lines) == 1
+    assert sum(" collective-permute-start(" in line for line in lines) == 4
+    # 4,317,274,624 bytes before the pin
+    assert exe.memory_analysis().temp_size_in_bytes <= 4_317_274_624
+
+
+def test_one_chip_programs_pin_no_strip():
+    """One chip exchanges nothing: neither the 16384² heat cell's program
+    nor the NEMO cell's pins a send strip."""
+    from repro.frontends import nemo_traadv
+
+    target = Target(backend="pallas", pallas_interpret=False)
+    for prog in (_heat(16384, 8), nemo_traadv.program((75, 1021, 1442))):
+        n = api.compile(prog, target).kernel_dispatches
+        assert n["pinned_send_strips"] == 0
 
 
 def test_2x2_pallas_loop_fits_like_jnp(topo):
